@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import shard_map
-
 
 def matmul_allreduce(x, w, mesh, axis: str = "model"):
     """y = x @ w with w K-sharded over ``axis``; all-reduce fused via
@@ -40,7 +38,7 @@ def matmul_allreduce(x, w, mesh, axis: str = "model"):
         scat = jax.lax.psum_scatter(part, axis, scatter_dimension=1, tiled=True)
         return jax.lax.all_gather(scat, axis, axis=1, tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, None),
@@ -72,7 +70,7 @@ def matmul_ag_pipelined(x, w, mesh, axis: str = "model"):
         (_, acc), _ = jax.lax.scan(step, (x_loc, acc0), jnp.arange(p))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, None)),
         out_specs=P(None, None),

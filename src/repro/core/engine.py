@@ -23,12 +23,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import kv as kvm
 from repro.core import tree as T
 from repro.obs.clock import monotonic
 from repro.obs.trace import NOOP_SPAN, NULL_TRACER
-from repro.sharding import use_mesh
+from repro.sharding import SERVING_RULES, use_mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,6 +151,36 @@ def _effective_depth(depth: int | None, default: int) -> int:
     return d
 
 
+def greedy_decode(model, params, prompt, n: int, S_max: int):
+    """Target-only greedy decoding: the oracle the speculative stream must
+    equal token for token.  prompt: i32 [B, P].  Returns (tokens i32 [B, n],
+    top2 f32 [B, n, 2]): each step's argmax and the two largest logits it was
+    picked from, whose gap says how close to a tie that step was."""
+
+    def pick(logits):
+        last = logits[:, -1, :].astype(jnp.float32)
+        return jnp.argmax(last, -1)[:, None].astype(jnp.int32), jax.lax.top_k(last, 2)[0]
+
+    @jax.jit
+    def prefill_pick(p, t):
+        logits, cache = model.prefill(p, tokens=t, S_max=S_max)
+        return pick(logits), cache
+
+    @jax.jit
+    def decode_pick(p, c, t):
+        logits, cache = model.decode_step(p, c, t, S_max)
+        return pick(logits), cache
+
+    (cur, top), cache = prefill_pick(params, jnp.asarray(prompt))
+    toks, tops = [cur], [top]
+    for _ in range(n - 1):
+        (cur, top), cache = decode_pick(params, cache, cur)
+        toks.append(cur)
+        tops.append(top)
+    toks, tops = jax.device_get((toks, tops))
+    return np.concatenate(toks, axis=1), np.stack(tops, axis=1)
+
+
 def absorb_emitted(out: list, emitted_row, n_emitted: int, max_new: int, eos_id: int):
     """Append one row's verified tokens to ``out`` until EOS or ``max_new``.
 
@@ -164,6 +195,21 @@ def absorb_emitted(out: list, emitted_row, n_emitted: int, max_new: int, eos_id:
         if (eos_id >= 0 and t == eos_id) or len(out) >= max_new:
             return new, True
     return new, False
+
+
+def _serving(mesh):
+    """Mesh context of one serving role: the engine's programs trace under
+    the serving rules (KV cache sharded over kv heads)."""
+    return use_mesh(mesh, SERVING_RULES)
+
+
+def _to(mesh, tree):
+    """Replicate ``tree`` onto ``mesh`` — the explicit hop of small per-round
+    values (plans, verify outcomes) between the draft and target groups, the
+    paper's verified-token exchange.  A no-op without a mesh."""
+    if mesh is None:
+        return tree
+    return jax.device_put(tree, NamedSharding(mesh, PartitionSpec()))
 
 
 class SpecEngine:
@@ -262,9 +308,14 @@ class SpecEngine:
         self._dprefill = jax.jit(lambda p, t, S: draft.prefill(p, tokens=t, S_max=S), static_argnums=(2,))
         self._tprefill = jax.jit(lambda p, t, S: target.prefill(p, tokens=t, S_max=S), static_argnums=(2,))
         # per-slot lifecycle (continuous batching); slot/plen are traced so
-        # one compile covers every slot index and prompt length
-        self._install = jax.jit(kvm.install_slot, donate_argnums=(0,))
-        self._zero_slot = jax.jit(kvm.zero_slot, donate_argnums=(0,))
+        # one compile covers every slot index and prompt length.  The cache
+        # writes go through per-engine closures: jit caches traces by the
+        # wrapped function, and a module function would share one trace (and
+        # one kernel choice) across engines built under different flags.
+        self._install = jax.jit(lambda cache, donor, slot: kvm.install_slot(cache, donor, slot),
+                                donate_argnums=(0,))
+        self._zero_slot = jax.jit(lambda cache, slot: kvm.zero_slot(cache, slot),
+                                  donate_argnums=(0,))
         self._reset_slot = jax.jit(T.reset_slot, donate_argnums=(0,))
         self._seed_slot = jax.jit(
             lambda tr, slot, tok, plen, lg: T.seed_slot(tr, slot, tok, plen, lg, c.c),
@@ -298,25 +349,39 @@ class SpecEngine:
         Parked slots are inert: their plans carry no valid node, so verify
         writes nothing and expand skips them; the runtime discards whatever
         they "emit"."""
-        tcache = self.target.init_cache(B, self.S_max_t)
-        dcache = self.draft.init_cache(B, self.S_max_d)
-        tr = jax.tree.map(lambda x: jnp.stack([x] * B), T.init_tree(self.cfg.n_cap))
-        with use_mesh(self.mesh_draft):
+        tcache = self._new_cache(self.target, B, self.S_max_t, self.mesh_target)
+        dcache = self._new_cache(self.draft, B, self.S_max_d, self.mesh_draft)
+        tr = self._empty_trees(B)
+        with _serving(self.mesh_draft):
             plan = self._select_plan(tr)
         return EngineState(tcache, dcache, tr, plan)
+
+    def _empty_trees(self, B: int):
+        """B stacked empty trees, on the draft group (where trees live)."""
+        return _to(self.mesh_draft,
+                   jax.tree.map(lambda x: jnp.stack([x] * B), T.init_tree(self.cfg.n_cap)))
+
+    @staticmethod
+    def _new_cache(model, B: int, S_max: int, mesh):
+        """Zero cache (every cache starts as zeros) built directly in its
+        serving layout on ``mesh``."""
+        if mesh is None:
+            return model.init_cache(B, S_max)
+        shapes = jax.eval_shape(functools.partial(model.init_cache, B, S_max))
+        return jax.tree.map(lambda s, sh: jnp.zeros(s.shape, s.dtype, device=sh),
+                            shapes, kvm.cache_shardings(mesh, shapes, SERVING_RULES))
 
     def _prefill_state(self, tparams, dparams, prompt) -> EngineState:
         """Whole-batch prefill + tree seed + initial growth (all rows start
         together — the generate() path)."""
-        c = self.cfg
         B, P = prompt.shape
-        with use_mesh(self.mesh_draft):
+        with _serving(self.mesh_draft):
             dlogits, dcache = self._dprefill(dparams, jnp.asarray(prompt), self.S_max_d)
-        with use_mesh(self.mesh_target):
+        with _serving(self.mesh_target):
             _, tcache = self._tprefill(tparams, jnp.asarray(prompt), self.S_max_t)
-        tr = jax.tree.map(lambda x: jnp.stack([x] * B), T.init_tree(c.n_cap))
+        tr = self._empty_trees(B)
         root_tok = jnp.asarray(prompt[:, -1], jnp.int32)
-        with use_mesh(self.mesh_draft):
+        with _serving(self.mesh_draft):
             tr = self._seed(tr, root_tok, P, dlogits[:, -1, :])
             for _ in range(self.grow_per_round):
                 tr, dcache = self._expand(dparams, tr, dcache)
@@ -384,28 +449,26 @@ class SpecEngine:
         target verification (jits warmed first).  Returns ProfileResult."""
         from repro.core.scheduler import ProfileResult
 
-        c = self.cfg
         B, P = prompt.shape
-        with use_mesh(self.mesh_draft):
+        with _serving(self.mesh_draft):
             dlogits, dcache = self._dprefill(dparams, jnp.asarray(prompt), self.S_max_d)
-        with use_mesh(self.mesh_target):
+        with _serving(self.mesh_target):
             _, tcache = self._tprefill(tparams, jnp.asarray(prompt), self.S_max_t)
-        t0tree = T.init_tree(c.n_cap)
-        tr = jax.tree.map(lambda x: jnp.stack([x] * B), t0tree)
-        with use_mesh(self.mesh_draft):
+        tr = self._empty_trees(B)
+        with _serving(self.mesh_draft):
             tr = self._seed(tr, jnp.asarray(prompt[:, -1], jnp.int32), P, dlogits[:, -1, :])
             tr, dcache = self._expand(dparams, tr, dcache)  # warm
-            plan = self._select_plan(tr)
+            plan = _to(self.mesh_target, self._select_plan(tr))
 
         def draft_once():
             nonlocal tr, dcache
-            with use_mesh(self.mesh_draft):
+            with _serving(self.mesh_draft):
                 tr, dcache = self._expand(dparams, tr, dcache)
                 jax.block_until_ready(tr.tokens)
 
         def target_once():
             nonlocal tcache
-            with use_mesh(self.mesh_target):
+            with _serving(self.mesh_target):
                 out = self._verify(tparams, tcache, plan.tokens, plan.positions,
                                    plan.rows, plan.mask, plan.parent_pos, plan.valid)
                 tcache = self._compact(out[5], *out[6])
@@ -484,12 +547,12 @@ class EngineSession:
         eng, state = self.engine, self.state
         prompt = np.asarray(prompt, np.int32).reshape(1, -1)
         P = prompt.shape[1]
-        with use_mesh(eng.mesh_draft):
+        with _serving(eng.mesh_draft):
             dlogits, dcache1 = eng._dprefill(self.dparams, jnp.asarray(prompt), eng.S_max_d)
-        with use_mesh(eng.mesh_target):
+        with _serving(eng.mesh_target):
             _, tcache1 = eng._tprefill(self.tparams, jnp.asarray(prompt), eng.S_max_t)
             tcache = eng._install(state.tcache, tcache1, slot)
-        with use_mesh(eng.mesh_draft):
+        with _serving(eng.mesh_draft):
             dcache = eng._install(state.dcache, dcache1, slot)
             tr = eng._seed_slot(
                 state.tr, slot, jnp.asarray(prompt[0, -1], jnp.int32),
@@ -505,13 +568,28 @@ class EngineSession:
         KV rows in both caches, so no state can leak into the next occupant."""
         self._check_quiescent("release_slot")
         eng, state = self.engine, self.state
-        with use_mesh(eng.mesh_target):
+        with _serving(eng.mesh_target):
             tcache = eng._zero_slot(state.tcache, slot)
-        with use_mesh(eng.mesh_draft):
+        with _serving(eng.mesh_draft):
             dcache = eng._zero_slot(state.dcache, slot)
             tr = eng._reset_slot(state.tr, slot)
             plan = eng._select_plan(tr)
         self.state = EngineState(tcache, dcache, tr, plan)
+
+    def _dispatch(self, plan, tcache):
+        """Enqueue verification of ``plan`` on the target group, then the
+        target cache compaction; returns (acc_pos, n_acc, bonus, emitted,
+        n_emitted, tcache') as target-side device futures."""
+        eng = self.engine
+        plan = _to(eng.mesh_target, plan)
+        with _serving(eng.mesh_target):
+            acc_pos, n_acc, bonus, emitted, n_emitted, tcache, mv = eng._verify(
+                self.tparams, tcache, plan.tokens, plan.positions, plan.rows,
+                plan.mask, plan.parent_pos, plan.valid,
+            )
+            with self.tracer.span("kv_move", self.track):
+                tcache = eng._compact(tcache, *mv)
+        return acc_pos, n_acc, bonus, emitted, n_emitted, tcache
 
     # ------------------------------------------------------------------
     # the round, lockstep
@@ -554,17 +632,11 @@ class EngineSession:
         draft_steps = 0
         # --- dispatch verification on the target group (async) -------------
         with obs.span("verify_dispatch", track):
-            with use_mesh(eng.mesh_target):
-                acc_pos, n_acc, bonus, emitted, n_emitted, tcache, mv = eng._verify(
-                    self.tparams, state.tcache, plan.tokens, plan.positions, plan.rows,
-                    plan.mask, plan.parent_pos, plan.valid,
-                )
-                with obs.span("kv_move", track):
-                    tcache = eng._compact(tcache, *mv)
+            acc_pos, n_acc, bonus, emitted, n_emitted, tcache = self._dispatch(plan, state.tcache)
         # --- concurrently: d tree expansions on the draft group ------------
         if c.mode == "parallel":
             with obs.span("draft_expand", track):
-                with use_mesh(eng.mesh_draft):
+                with _serving(eng.mesh_draft):
                     for _ in range(d_eff):
                         tr, dcache = eng._expand(self.dparams, tr, dcache)
                     draft_steps += d_eff
@@ -575,8 +647,9 @@ class EngineSession:
             emitted_h, n_emitted_h, n_acc_h = jax.device_get((emitted, n_emitted, n_acc))  # repro: disable=HOTSYNC — designated sync point
         # --- re-root, fill, grow, select next batch (draft group) ----------
         with obs.span("reroot_grow", track):
-            with use_mesh(eng.mesh_draft):
-                tr, move, fillp = eng._reroot(tr, plan.node_ids, acc_pos, n_acc, bonus)
+            with _serving(eng.mesh_draft):
+                tr, move, fillp = eng._reroot(
+                    tr, plan.node_ids, *_to(eng.mesh_draft, (acc_pos, n_acc, bonus)))
                 with obs.span("kv_move", track):
                     dcache = eng._kv_move(dcache, move.src, move.dst, move.mask)
                 dcache = eng._fill(self.dparams, dcache, fillp)
@@ -611,13 +684,7 @@ class EngineSession:
         eng, state = self.engine, self.state
         plan = eng._bypass(state.plan) if eng.cfg.draft_bypass else state.plan
         span = self.tracer.begin("verify_dispatch", self.track)
-        with use_mesh(eng.mesh_target):
-            acc_pos, n_acc, bonus, emitted, n_emitted, tcache, mv = eng._verify(
-                self.tparams, state.tcache, plan.tokens, plan.positions, plan.rows,
-                plan.mask, plan.parent_pos, plan.valid,
-            )
-            with self.tracer.span("kv_move", self.track):
-                tcache = eng._compact(tcache, *mv)
+        acc_pos, n_acc, bonus, emitted, n_emitted, tcache = self._dispatch(plan, state.tcache)
         rif = RoundInFlight(
             plan=plan, tcache=tcache,
             verify=(acc_pos, n_acc, bonus, emitted, n_emitted),
@@ -639,7 +706,7 @@ class EngineSession:
         d_eff = _effective_depth(depth, c.d)
         tr, dcache = self.state.tr, self.state.dcache
         with self.tracer.span("draft_lookahead", self.track):
-            with use_mesh(eng.mesh_draft):
+            with _serving(eng.mesh_draft):
                 for _ in range(d_eff):
                     tr, dcache = eng._expand(self.dparams, tr, dcache)
                 rif.draft_steps += d_eff
@@ -693,10 +760,10 @@ class EngineSession:
                 stats.spec_commits += 1
         else:
             with obs.span("reconcile", track):
-                with use_mesh(eng.mesh_draft):
+                with _serving(eng.mesh_draft):
                     tr, dcache = rif.snapshot
                     tr, move, fillp = eng._reroot(
-                        tr, rif.plan.node_ids, acc_pos, n_acc, bonus)
+                        tr, rif.plan.node_ids, *_to(eng.mesh_draft, (acc_pos, n_acc, bonus)))
                     with obs.span("kv_move", track):
                         # actual-path move consumes the snapshot (donating)
                         dcache = eng._kv_move(dcache, move.src, move.dst, move.mask)

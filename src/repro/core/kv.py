@@ -31,18 +31,47 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.kernels import ops
+from repro.models.attention import KV_AXES
+from repro.sharding import spec_for
 
 ROW_KEYS = ("k", "v", "ckv", "krope")
 
 
+def leaf_axes(key, ndim: int) -> tuple:
+    """Logical axes of one stacked cache leaf [U, B, ...] named ``key``:
+    attention K/V [U, B, S, Hkv, hd] as in the model's own constraints, other
+    row leaves (MLA latents) by sequence, everything else replicated."""
+    if key in ("k", "v") and ndim == 5:
+        return ("layers",) + KV_AXES
+    if key in ROW_KEYS:
+        return ("layers", "cache_batch", "kv_seq") + (None,) * (ndim - 3)
+    return (None,) * ndim
+
+
+def _key_of(path):
+    keys = [p.key for p in path if isinstance(p, jax.tree_util.DictKey)]
+    return keys[-1] if keys else None
+
+
+def cache_shardings(mesh, cache, rules=None):
+    """NamedSharding tree for ``cache`` (arrays or shape structs) on ``mesh``:
+    where ``init_state`` places the serving caches, in the layout the jitted
+    round programs keep them in."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: NamedSharding(
+            mesh, spec_for(mesh, leaf_axes(_key_of(path), x.ndim), x.shape, rules)),
+        cache)
+
+
 def map_row_leaves(cache, fn):
-    """Apply ``fn`` to every row-indexed cache leaf [U, B, S, ...]."""
+    """Apply ``fn(leaf, key)`` to every row-indexed cache leaf [U, B, S, ...]."""
 
     def rec(x):
         if isinstance(x, dict):
-            return {k: (fn(v) if k in ROW_KEYS else rec(v)) for k, v in x.items()}
+            return {k: (fn(v, k) if k in ROW_KEYS else rec(v)) for k, v in x.items()}
         if isinstance(x, (list, tuple)):
             return type(x)(rec(v) for v in x)
         return x
@@ -58,8 +87,9 @@ def apply_moves(cache, src, dst, mask, *, donate: bool = False):
     docstring's rollback contract.
     """
 
-    def per_leaf(arr):  # [U, B, S, ...]
-        return ops.kv_move_rows(arr, src, dst, mask, donate=donate)
+    def per_leaf(arr, key):  # [U, B, S, ...]
+        return ops.kv_move_rows(arr, src, dst, mask, donate=donate,
+                                axes=leaf_axes(key, arr.ndim))
 
     return map_row_leaves(cache, per_leaf)
 
@@ -89,9 +119,11 @@ def set_length(cache, new_len):
 def _write_slot_rows(cache, donor, slot, fallback):
     """Shared install/zero body: write donor row 0 into ``slot`` of every
     groups leaf, fused when possible, else via ``fallback(big, one)``."""
-    big_leaves, treedef = jax.tree.flatten(cache["groups"])
+    paths, treedef = jax.tree_util.tree_flatten_with_path(cache["groups"])
+    big_leaves = [x for _, x in paths]
     one_leaves = jax.tree.leaves(donor["groups"])
-    fused = ops.slot_write_rows(big_leaves, one_leaves, slot)
+    axes = [leaf_axes(_key_of(p), x.ndim) for p, x in paths]
+    fused = ops.slot_write_rows(big_leaves, one_leaves, slot, axes)
     if fused is not None:
         return {"len": cache["len"], "groups": jax.tree.unflatten(treedef, fused)}
     return {"len": cache["len"],

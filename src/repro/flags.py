@@ -18,7 +18,6 @@ class Flags:
     use_pallas_swiglu: bool = False  # fused SwiGLU kernel
     use_int4_kernel: bool = False  # AWQ dequant-GEMM kernel
     use_pallas_kv_moves: bool = False  # fused O(moved-rows) KV reorg kernels
-    pallas_interpret: bool = True  # CPU container: interpret mode
     moe_impl: str = "tp"  # "tp" (TP-in-expert) | "ep" (expert-parallel a2a)
     remat: str = "none"  # "none" | "full"
     attn_chunk: int = 512  # q-chunk for full attention

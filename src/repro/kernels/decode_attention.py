@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels import interpret_mode
+
 
 NEG = -1e30
 
@@ -73,7 +74,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, block_k: in
         o_ref[0, 0] = jnp.where(l > 0, out, 0.0).astype(o_ref.dtype)
 
 
-def decode_attention_pallas(q_r, k, v, length, *, scale: float, block_k: int, interpret: bool):
+def decode_attention_pallas(q_r, k, v, length, *, scale: float, block_k: int,
+                            interpret: bool | None = None):
     """q_r: [B, Hkv, G, hd]; k/v: [B, S, Hkv, hd]; length: i32 [B, 1].
 
     Pre-padded shapes (S % block_k == 0).  Returns [B, Hkv, G, hd].
@@ -103,8 +105,8 @@ def decode_attention_pallas(q_r, k, v, length, *, scale: float, block_k: int, in
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(length, q_r, k, v)

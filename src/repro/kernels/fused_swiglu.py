@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, wg_ref, wu_ref, o_ref, g_s, u_s):
@@ -41,7 +41,8 @@ def _kernel(x_ref, wg_ref, wu_ref, o_ref, g_s, u_s):
         o_ref[...] = (g * jax.nn.sigmoid(g) * u_s[...]).astype(o_ref.dtype)
 
 
-def fused_swiglu_pallas(x, wg, wu, *, block_m: int, block_n: int, block_k: int, interpret: bool):
+def fused_swiglu_pallas(x, wg, wu, *, block_m: int, block_n: int, block_k: int,
+                        interpret: bool | None = None):
     """x: [M, K]; wg, wu: [K, N] — pre-padded to block multiples.
 
     Returns silu(x@wg) * (x@wu), [M, N].
@@ -69,8 +70,9 @@ def fused_swiglu_pallas(x, wg, wu, *, block_m: int, block_n: int, block_k: int, 
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, block_n), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="fused_swiglu",
     )(x, wg, wu)

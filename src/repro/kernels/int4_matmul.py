@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels import interpret_mode
 
 
 def _kernel(x_ref, qw_ref, s_ref, z_ref, o_ref, acc_s):
@@ -50,7 +50,7 @@ def _kernel(x_ref, qw_ref, s_ref, z_ref, o_ref, acc_s):
 
 
 def int4_matmul_pallas(x, qweight, scales, zeros, *, group_size: int,
-                       block_m: int, block_n: int, interpret: bool):
+                       block_m: int, block_n: int, interpret: bool | None = None):
     """x: [M, K]; qweight: int8 [K//2, N] packed; scales/zeros: [K//g, N].
 
     block_k is pinned to ``group_size``; shapes pre-padded to block multiples.
@@ -74,8 +74,8 @@ def int4_matmul_pallas(x, qweight, scales, zeros, *, group_size: int,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, qweight, scales, zeros)

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import ANY_SPACE, CompilerParams
+from repro.kernels import interpret_mode
 
 
 # -----------------------------------------------------------------------------
@@ -60,9 +60,12 @@ def _kv_move_kernel(src_ref, dst_ref, act_ref, cache_ref, out_ref,
     """One (u, b) grid cell: move rows src[b, m] -> dst[b, m] where active.
 
     src_ref/dst_ref/act_ref are scalar-prefetch [B, M] i32; cache_ref/out_ref
-    are full-array HBM refs [U, B, S, F] (out aliases cache when the caller
-    donates).  All gathers complete before any scatter starts, so an
-    overlapping move plan behaves as a parallel assignment.
+    are full-array HBM refs [U, B, S, R, L] (out aliases cache when the caller
+    donates).  One row is an [R, L] slab, so every DMA slices the untiled S
+    axis (or the stage's leading axis) and never the tiled (R, L) pair — a
+    one-row slice of a tiled dim is refused by Mosaic.  All gathers complete
+    before any scatter starts, so an overlapping move plan behaves as a
+    parallel assignment.
     """
     u, b = pl.program_id(0), pl.program_id(1)
     M = src_ref.shape[1]
@@ -109,7 +112,8 @@ def _kv_move_kernel(src_ref, dst_ref, act_ref, cache_ref, out_ref,
             scatter(m).wait()
 
 
-def kv_move_rows_pallas(arr, src, dst, active, *, donate: bool, interpret: bool = True):
+def kv_move_rows_pallas(arr, src, dst, active, *, donate: bool,
+                        interpret: bool | None = None):
     """arr: [U, B, S, F]; src/dst/active: i32 [B, M] with active ∈ {0, 1}.
 
     Returns arr with rows moved (active: out[u, b, dst] = arr[u, b, src],
@@ -117,10 +121,15 @@ def kv_move_rows_pallas(arr, src, dst, active, *, donate: bool, interpret: bool 
     (in-place; caller must own the buffer); ``donate=False`` never writes the
     input ref.  HBM traffic per (u, b): M·F gather + M·F scatter (+ one S·F
     pass-through copy for the non-donating variant).
+
+    Inside the kernel a row is the [R, L] slab of a free [U, B, S, R, L]
+    view (L = 128 lanes when F allows it), keeping S out of the tiled dims.
     """
     if arr.ndim != 4:
         raise ValueError(f"arr must be [U, B, S, F], got shape {arr.shape}")
     U, B, S, F = arr.shape
+    L = 128 if F % 128 == 0 else F
+    R = F // L
     M = src.shape[1]
     if src.shape != (B, M) or dst.shape != (B, M) or active.shape != (B, M):
         raise ValueError(
@@ -130,10 +139,10 @@ def kv_move_rows_pallas(arr, src, dst, active, *, donate: bool, interpret: bool 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(U, B),
-        in_specs=[pl.BlockSpec(memory_space=ANY_SPACE)],
-        out_specs=pl.BlockSpec(memory_space=ANY_SPACE),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((M, F), arr.dtype),  # row stage
+            pltpu.VMEM((M, R, L), arr.dtype),  # row stage
             pltpu.SemaphoreType.DMA((M,)),  # gather sems
             pltpu.SemaphoreType.DMA((M,)),  # scatter sems
             pltpu.SemaphoreType.DMA(()),  # pass-through copy sem
@@ -143,14 +152,17 @@ def kv_move_rows_pallas(arr, src, dst, active, *, donate: bool, interpret: bool 
     if donate:
         # alias indices count the scalar-prefetch args: cache is operand 3
         kwargs["input_output_aliases"] = {3: 0}
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kv_move_kernel, copy_through=not donate),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(arr.shape, arr.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((U, B, S, R, L), arr.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret_mode(interpret),
+        name="kv_move_rows",
         **kwargs,
-    )(src.astype(jnp.int32), dst.astype(jnp.int32), active.astype(jnp.int32), arr)
+    )(src.astype(jnp.int32), dst.astype(jnp.int32), active.astype(jnp.int32),
+      arr.reshape(U, B, S, R, L))
+    return out.reshape(arr.shape)
 
 
 # -----------------------------------------------------------------------------
@@ -179,7 +191,8 @@ def _slot_write_kernel(n_leaves, slot_ref, *refs):
         c.wait()
 
 
-def slot_write_rows_pallas(cache_leaves, donor_leaves, slot, *, interpret: bool = True):
+def slot_write_rows_pallas(cache_leaves, donor_leaves, slot, *,
+                           interpret: bool | None = None):
     """Write batch row 0 of every donor leaf into batch row ``slot`` of the
     matching cache leaf, in one launch.
 
@@ -200,8 +213,8 @@ def slot_write_rows_pallas(cache_leaves, donor_leaves, slot, *, interpret: bool 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=ANY_SPACE)] * (2 * L),
-        out_specs=[pl.BlockSpec(memory_space=ANY_SPACE)] * L,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * L),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * L,
         scratch_shapes=[pltpu.SemaphoreType.DMA((L,))],
     )
     return pl.pallas_call(
@@ -211,6 +224,7 @@ def slot_write_rows_pallas(cache_leaves, donor_leaves, slot, *, interpret: bool 
         # operand layout: slot (scalar prefetch), L donors, L caches —
         # cache i is operand 1 + L + i, aliased in place onto output i
         input_output_aliases={1 + L + i: i for i in range(L)},
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(interpret),
+        name="slot_write_rows",
     )(jnp.reshape(jnp.asarray(slot, jnp.int32), (1,)), *donor_leaves, *cache_leaves)

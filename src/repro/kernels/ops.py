@@ -5,9 +5,9 @@ sublanes), lays tensors out for the kernel grid, and un-pads the result.
 Padding is semantics-preserving: padded KV rows are masked False, padded
 matmul K columns are zero, padded query rows are sliced off.
 
-``interpret=True`` (the default through flags.pallas_interpret on this CPU
-container) runs the kernel bodies in Python for correctness validation; on a
-real TPU the same calls compile to Mosaic.
+``interpret`` defaults to None everywhere: the kernels run in the Pallas
+interpreter on the CPU backend and compile to Mosaic on a TPU
+(``repro.kernels.interpret_mode``).
 """
 
 from __future__ import annotations
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.flags import get_flags
+from repro.sharding import shard_local
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.fused_swiglu import fused_swiglu_pallas
 from repro.kernels.int4_matmul import int4_matmul_pallas
@@ -45,7 +46,7 @@ def _pad_dim(x, axis: int, to: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def tree_attention(q, k, v, mask, *, block_k: int = 128, interpret: bool = True):
+def tree_attention(q, k, v, mask, *, block_k: int = 128, interpret: bool | None = None):
     """q: [B, n, Hq, hd]; k, v: [B, S, Hkv, hd]; mask: bool [B, n, S].
 
     The paper's non-square tree-masked attention; returns [B, n, Hq, hd].
@@ -67,6 +68,8 @@ def tree_attention(q, k, v, mask, *, block_k: int = 128, interpret: bool = True)
     # g-major query layout: [B, Hkv, G*n_p, hd]
     q_r = qp.reshape(B, n_p, hkv, g, hd_p).transpose(0, 2, 3, 1, 4).reshape(B, hkv, g * n_p, hd_p)
 
+    # [B, S, Hkv*hd]: a free view that puts head h in lane block h
+    kp, vp = kp.reshape(B, S_p, hkv * hd_p), vp.reshape(B, S_p, hkv * hd_p)
     out = tree_attention_pallas(q_r, kp, vp, mp, scale=scale, block_k=block_k, interpret=interpret)
     out = out.reshape(B, hkv, g, n_p, hd_p).transpose(0, 3, 1, 2, 4).reshape(B, n_p, hq, hd_p)
     return out[:, :n, :, :hd]
@@ -78,7 +81,7 @@ def tree_attention(q, k, v, mask, *, block_k: int = 128, interpret: bool = True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def decode_attention(q, k, v, length, *, block_k: int = 128, interpret: bool = True):
+def decode_attention(q, k, v, length, *, block_k: int = 128, interpret: bool | None = None):
     """q: [B, Hq, hd]; k, v: [B, S, Hkv, hd]; length: i32 [B].
 
     One-position decode against rows [0, length); returns [B, Hq, hd].
@@ -110,7 +113,7 @@ def decode_attention(q, k, v, length, *, block_k: int = 128, interpret: bool = T
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fused_swiglu(x, wg, wu, *, interpret: bool = True):
+def fused_swiglu(x, wg, wu, *, interpret: bool | None = None):
     """x: [T, d]; wg, wu: [d, ff] -> silu(x@wg) * (x@wu), [T, ff]."""
     T, K = x.shape
     N = wg.shape[1]
@@ -131,10 +134,12 @@ def fused_swiglu(x, wg, wu, *, interpret: bool = True):
 # Unlike the kernels above these are NOT separately jitted: they are only
 # ever called inside the engine's already-jitted round programs, and the
 # fused/reference choice is a trace-time flag (use_pallas_kv_moves) exactly
-# like the attention kernel selection in models/attention.py.
+# like the attention kernel selection in models/attention.py.  Under a
+# multi-device mesh the fused kernels run per shard (``shard_local``): each
+# chip moves the rows of its own kv heads.
 
 
-def kv_move_rows(arr, src, dst, mask, *, donate: bool = False):
+def kv_move_rows(arr, src, dst, mask, *, donate: bool = False, axes=None):
     """Move rows of one cache leaf: arr [U, B, S, ...]; src/dst i32 [B, M];
     mask bool [B, M].  Parallel-assignment semantics (sources read before any
     write); entries with mask False, src < 0, or dst < 0 are dropped.
@@ -143,28 +148,35 @@ def kv_move_rows(arr, src, dst, mask, *, donate: bool = False):
     onto the input) — callers must own the buffer, i.e. the wrapping jit
     donates the cache.  ``donate=False`` never mutates the input: the
     speculative-lookahead contract (kv.py) requires the retained pre-reroot
-    snapshot to survive this call.
+    snapshot to survive this call.  ``axes``: the leaf's logical axes, which
+    place the fused kernel on the mesh (replicated when None).
     """
     flags = get_flags()
     M = src.shape[1]
     if M == 0:
         return arr
-    if flags.use_pallas_kv_moves:
-        U, B, S = arr.shape[:3]
-        active = (mask & (src >= 0) & (dst >= 0)).astype(jnp.int32)
-        out = kv_move_rows_pallas(
-            arr.reshape(U, B, S, -1), src, dst, active,
-            donate=donate, interpret=flags.pallas_interpret)
-        return out.reshape(arr.shape)
-    return kv_move_rows_ref(arr, src, dst, mask)
+    if not flags.use_pallas_kv_moves:
+        return kv_move_rows_ref(arr, src, dst, mask)
+    active = (mask & (src >= 0) & (dst >= 0)).astype(jnp.int32)
+
+    def local(a, s, d, act):
+        U, B, S = a.shape[:3]
+        out = kv_move_rows_pallas(a.reshape(U, B, S, -1), s, d, act, donate=donate)
+        return out.reshape(a.shape)
+
+    axes = axes or (None,) * arr.ndim
+    plan = (None, None)
+    return shard_local(local, (arr, src, dst, active), (axes, plan, plan, plan), axes,
+                       local_dims=(tuple(range(3, arr.ndim)), (), (), ()))
 
 
-def slot_write_rows(cache_leaves, donor_leaves, slot):
+def slot_write_rows(cache_leaves, donor_leaves, slot, axes=None):
     """Fused slot lifecycle write: donor[:, 0] -> cache[:, slot] for every
     leaf in ONE kernel launch (vs one XLA update per leaf).  Returns the
     updated leaves, or None when the leaves don't fit the kernel's contract
     (shape/dtype mismatch, empty tree) — callers fall back to the per-leaf
-    XLA path, which is also the flag-off default."""
+    XLA path, which is also the flag-off default.  ``axes``: each leaf's
+    logical axes, which place the kernel on the mesh (replicated when None)."""
     flags = get_flags()
     if not flags.use_pallas_kv_moves or not cache_leaves:
         return None
@@ -175,8 +187,16 @@ def slot_write_rows(cache_leaves, donor_leaves, slot):
             return None
         if big.dtype != one.dtype:
             return None
-    return slot_write_rows_pallas(
-        cache_leaves, donor_leaves, slot, interpret=flags.pallas_interpret)
+    L = len(cache_leaves)
+    axes = list(axes or [(None,) * c.ndim for c in cache_leaves])
+
+    def local(*args):
+        return slot_write_rows_pallas(list(args[L:2 * L]), list(args[:L]), args[-1])
+
+    return shard_local(
+        local, (*donor_leaves, *cache_leaves, jnp.asarray(slot, jnp.int32)),
+        axes + axes + [()], axes,
+        local_dims=[tuple(range(2, c.ndim)) for c in cache_leaves] * 2 + [()])
 
 
 # -----------------------------------------------------------------------------
@@ -185,7 +205,8 @@ def slot_write_rows(cache_leaves, donor_leaves, slot):
 
 
 @functools.partial(jax.jit, static_argnames=("group_size", "interpret"))
-def int4_matmul(x, qweight, scales, zeros, *, group_size: int = 128, interpret: bool = True):
+def int4_matmul(x, qweight, scales, zeros, *, group_size: int = 128,
+                interpret: bool | None = None):
     """x: [T, K]; qweight: int8 [K//2, N] (packed pairs along K);
     scales/zeros: [K//group_size, N].  Returns [T, N] in x.dtype.
 

@@ -12,7 +12,11 @@ no barrier and no second kernel launch at all.
 
 Layout: grid (B, Hkv, S/bk); every (b, h) step streams K/V tiles
 [bk, hd] and the mask tile [n, bk] HBM→VMEM while the [G·n, hd] query block
-stays resident.  All matmul tiles are 128-aligned (ops.py pads).
+stays resident.  K/V arrive as a [B, S, Hkv·hd] view of the cache, so head h
+is the h-th 128-lane column block: every block's last two dims are (bk, hd),
+which the TPU's (8, 128) tiling rule requires (a [1, bk, 1, hd] block of the
+4-D cache is refused by Mosaic).  All matmul tiles are 128-aligned (ops.py
+pads).
 """
 
 from __future__ import annotations
@@ -24,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.kernels import interpret_mode
 
 NEG = -1e30
 
@@ -33,8 +37,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_s, l_s, acc_s, *, g: int, sc
     """Grid step (b, h, s): one KV tile against the resident query block.
 
     q_ref   [1, 1, Gn, hd]  (g-major: row g*n + i is group g of query i)
-    k_ref   [1, bk, 1, hd]
-    v_ref   [1, bk, 1, hd]
+    k_ref   [1, bk, hd]     (column block h of the [B, S, Hkv*hd] view)
+    v_ref   [1, bk, hd]
     mask_ref[1, n, bk]
     o_ref   [1, 1, Gn, hd]
     scratch m_s/l_s [Gn, 128] f32, acc_s [Gn, hd] f32
@@ -48,8 +52,8 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_s, l_s, acc_s, *, g: int, sc
         acc_s[...] = jnp.zeros_like(acc_s)
 
     q = q_ref[0, 0].astype(jnp.float32)  # [Gn, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)  # [bk, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)  # [bk, hd]
+    k = k_ref[0].astype(jnp.float32)  # [bk, hd]
+    v = v_ref[0].astype(jnp.float32)  # [bk, hd]
     n, bk = mask_ref.shape[1], mask_ref.shape[2]
     gn = q.shape[0]
     mask = jnp.broadcast_to(mask_ref[0][None], (g, n, bk)).reshape(gn, bk)
@@ -79,8 +83,9 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_s, l_s, acc_s, *, g: int, sc
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-def tree_attention_pallas(q_r, k, v, mask, *, scale: float, block_k: int, interpret: bool):
-    """q_r: [B, Hkv, Gn, hd] g-major; k/v: [B, S, Hkv, hd]; mask: [B, n, S].
+def tree_attention_pallas(q_r, k, v, mask, *, scale: float, block_k: int,
+                          interpret: bool | None = None):
+    """q_r: [B, Hkv, Gn, hd] g-major; k/v: [B, S, Hkv * hd]; mask: [B, n, S].
 
     Shapes must be pre-padded: S % block_k == 0, hd/Gn MXU-aligned.
     ``scale`` is 1/sqrt(true head_dim) — hd here may be padded.
@@ -89,6 +94,10 @@ def tree_attention_pallas(q_r, k, v, mask, *, scale: float, block_k: int, interp
     B, hkv, gn, hd = q_r.shape
     S = k.shape[1]
     n = mask.shape[1]
+    if k.shape != (B, S, hkv * hd) or v.shape != k.shape:
+        raise ValueError(
+            f"tree_attention: k/v must be the [B, S, Hkv*hd] = {(B, S, hkv * hd)} "
+            f"view of the cache, got {k.shape} / {v.shape}")
     if S % block_k or gn % n:
         raise ValueError(
             f"tree_attention: S={S} must be a multiple of block_k={block_k} "
@@ -102,8 +111,8 @@ def tree_attention_pallas(q_r, k, v, mask, *, scale: float, block_k: int, interp
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, gn, hd), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda b, h, s: (b, s, h, 0)),
+            pl.BlockSpec((1, block_k, hd), lambda b, h, s: (b, s, h)),
+            pl.BlockSpec((1, block_k, hd), lambda b, h, s: (b, s, h)),
             pl.BlockSpec((1, n, block_k), lambda b, h, s: (b, 0, s)),
         ],
         out_specs=pl.BlockSpec((1, 1, gn, hd), lambda b, h, s: (b, h, 0, 0)),
@@ -113,8 +122,9 @@ def tree_attention_pallas(q_r, k, v, mask, *, scale: float, block_k: int, interp
             pltpu.VMEM((gn, 128), jnp.float32),
             pltpu.VMEM((gn, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="tree_attention",
     )(q_r, k, v, mask)

@@ -24,7 +24,7 @@ import jax
 
 from repro.configs import ASSIGNED, SHAPES, cell_applicable, get_config
 from repro.flags import override_flags
-from repro.launch.hlo_parse import analyze, compiled_cost
+from repro.launch.hlo_parse import analyze
 from repro.launch.hlo_stats import model_flops_per_chip, roofline_terms_from_module
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import cell_specs, dryrun_config
@@ -64,7 +64,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, flag_overrides: dict |
         t_compile = monotonic() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = compiled_cost(compiled)
+    cost = compiled.cost_analysis()
     mc = analyze(compiled.as_text())  # loop-aware, trip-scaled accounting
     cfg = dryrun_config(arch, mesh)
     rf = roofline_terms_from_module(mc, model_flops_per_chip(cfg, shape, n_chips))

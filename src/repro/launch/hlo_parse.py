@@ -60,13 +60,6 @@ _OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 
 
-def compiled_cost(compiled) -> dict:
-    """jax-version compat: ``Compiled.cost_analysis()`` returned ``[dict]``
-    before jax unified it to a plain dict.  Single shim for every caller."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def _sizes(type_str: str) -> tuple[float, float]:
     """(raw_bytes, corrected_bytes) over a possibly-tuple type string."""
     raw = corr = 0.0

@@ -12,8 +12,8 @@ the one-batch-at-a-time replay with the continuous-batching runtime
 (repro.serving): a seeded Poisson arrival trace is served through per-slot
 request lifecycles — admissions backfill retiring slots mid-flight, per
 request telemetry (TTFT, tok/s, acceptance, overlapping round lifetimes) is
-printed, and each finished output is checked byte-identical against a solo
-``generate()`` run (--no-verify to skip).  ``--replicas N`` shards the
+printed, and each finished output is checked byte-identical against the same
+request served alone (--no-verify to skip).  ``--replicas N`` shards the
 continuous runtime over N SpecEngine replicas on disjoint device groups
 (one global queue, least-loaded routing, per-replica + fleet telemetry).
 ``--async-rounds`` turns on asynchronous round disaggregation
@@ -29,64 +29,111 @@ attainment report (docs/scheduling.md); outputs stay byte-identical.
 phase spans (draft expand / verify / sync / reroot / absorb — viewable in
 ui.perfetto.dev) and a metrics snapshot with the round-time decomposition
 (repro.obs, docs/observability.md).
-On this CPU container all device groups map to the same device (correctness
-only); on a real slice ``--n-target``/``--n-draft`` select the disaggregated
-split carved once per replica.
+``--n-target``/``--n-draft`` select the disaggregated split carved once per
+replica (by default derived from the device count: on one device target and
+draft share it).  chip_smoke.py builds the published-width pair through the
+same ``serving_configs`` + ``build_engine``.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+from pathlib import Path
 
 import jax
 import numpy as np
 
-from repro.configs import get_config
+from repro.configs import ModelConfig, get_config
 from repro.core.engine import SpecConfig, SpecEngine
 from repro.core.scheduler import candidate_depths, profile_times
 from repro.data import make_request_stream, make_request_trace
 from repro.launch.mesh import make_serving_mesh
 from repro.models.api import make_model
 from repro.obs.clock import monotonic
+from repro.sharding import sharding_for_tree
 
 
-def build_engine(target_arch: str, draft_arch: str, *, smoke=True, mode="parallel",
-                 bs=8, w=4, c=2, d=2, max_new=48, S_max=512, n_target=6, n_draft=2,
-                 peaked=True, replicas=1, async_rounds=False):
-    """Build the serving engine(s).  With ``replicas > 1`` the device slice is
-    carved into that many disjoint (target, draft) mesh pairs and one
-    SpecEngine is built per pair; replicas whose mesh pair falls back to the
-    same devices as replica 0 (the CPU container) REUSE replica 0's engine
-    object — states are per-replica anyway, and sharing skips N-1 recompiles.
-    Returns (engine | [engines], tparams, dparams, cfgT)."""
+def enable_compile_cache() -> None:
+    """Persistent compilation cache for an entry point: JAX's own
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, else a fixed directory in
+    the checkout (a fixed path, so the next run finds what this one wrote)."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return  # JAX reads the variable itself
+    root = Path(__file__).resolve().parents[3]
+    jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+
+
+def serving_configs(target_arch: str, draft_arch: str, *, smoke: bool = True,
+                    target_layers: int = 0, dtype: str | None = None):
+    """(target, draft) ModelConfigs for serving: the smoke shapes by default
+    (CPU), or the published widths with ``smoke=False``.  ``target_layers``
+    cuts only the target's depth (what one chip holds); ``dtype`` sets the
+    compute and parameter dtype of both (e.g. "bfloat16" on a TPU)."""
     cfgT = get_config(target_arch, smoke=smoke)
     cfgD = get_config(draft_arch, smoke=smoke)
+    if target_layers:
+        cfgT = dataclasses.replace(cfgT, n_layers=target_layers)
+    if dtype:
+        cfgT = dataclasses.replace(cfgT, dtype=dtype, param_dtype=dtype)
+        cfgD = dataclasses.replace(cfgD, dtype=dtype, param_dtype=dtype)
+    return cfgT, cfgD
+
+
+def init_params(model, key, mesh, *, peaked: bool = True):
+    """Seeded random parameters, initialised directly in their sharded layout
+    on ``mesh`` (``sharding_for_tree``), so no full copy ever sits on one
+    device.  ``peaked`` scales the lm_head by 4: random-init logits are
+    near-uniform, and peaked greedy chains give realistic acceptance."""
+
+    def make(k):
+        p = model.init(k)
+        if peaked:
+            p["lm_head"].value = p["lm_head"].value * 4.0
+        return p
+
+    shardings = sharding_for_tree(mesh, jax.eval_shape(make, key))
+    return jax.jit(make, out_shardings=shardings)(key)
+
+
+def place_params(params, mesh):
+    """Copy a Param tree onto ``mesh`` in its sharded layout."""
+    return jax.device_put(params, sharding_for_tree(mesh, params))
+
+
+def build_engine(cfgT: ModelConfig, cfgD: ModelConfig, *, mode="parallel",
+                 bs=8, w=4, c=2, d=2, max_new=48, S_max=512, n_target=None, n_draft=None,
+                 peaked=True, replicas=1, async_rounds=False):
+    """Build the serving engine(s) for a (target, draft) config pair.
+
+    The devices are carved by ``make_serving_mesh`` (``n_target``/``n_draft``
+    per replica; None derives the split from the device count, colocating
+    both models on a one-device replica).  Each replica gets its own
+    SpecEngine with parameters placed on its own target and draft meshes.
+    Returns (engine, tparams, dparams, cfgT) for ``replicas == 1`` and
+    ([engines], [tparams], [dparams], cfgT) otherwise."""
     assert cfgT.vocab_size == cfgD.vocab_size, "draft/target must share a vocab"
     T, D = make_model(cfgT), make_model(cfgD)
-    tp = T.init(jax.random.PRNGKey(0))
-    dp = D.init(jax.random.PRNGKey(1))
-    if peaked:
-        # random-init logits are near-uniform; scale the lm_head so greedy
-        # chains are peaked enough for realistic acceptance behaviour
-        tp["lm_head"].value = tp["lm_head"].value * 4.0
-        dp["lm_head"].value = dp["lm_head"].value * 4.0
     cfg = SpecConfig(bs=bs, w=w, c=c, d=d, mode=mode, max_new=max_new,
                      async_rounds=async_rounds)
-
-    def mk(mesh_t, mesh_d):
-        return SpecEngine(T, D, cfg, S_max_t=S_max, S_max_d=S_max,
-                          mesh_target=mesh_t, mesh_draft=mesh_d)
-
-    if replicas == 1:
-        mesh_t, mesh_d = make_serving_mesh(n_target, n_draft)
-        return mk(mesh_t, mesh_d), tp, dp, cfgT
     pairs = make_serving_mesh(n_target, n_draft, replicas=replicas)
-    engines = [mk(*pairs[0])]
-    for mt, md in pairs[1:]:
-        same = (tuple(mt.devices.flat) == tuple(pairs[0][0].devices.flat)
-                and tuple(md.devices.flat) == tuple(pairs[0][1].devices.flat))
-        engines.append(engines[0] if same else mk(mt, md))
-    return engines, tp, dp, cfgT
+    pairs = [pairs] if replicas == 1 else pairs
+    kt, kd = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
+    engines, tps, dps = [], [], []
+    for mesh_t, mesh_d in pairs:
+        if not tps:
+            tp = init_params(T, kt, mesh_t, peaked=peaked)
+            dp = init_params(D, kd, mesh_d, peaked=peaked)
+        else:  # the same weights, copied onto this replica's devices
+            tp, dp = place_params(tps[0], mesh_t), place_params(dps[0], mesh_d)
+        engines.append(SpecEngine(T, D, cfg, S_max_t=S_max, S_max_d=S_max,
+                                  mesh_target=mesh_t, mesh_draft=mesh_d))
+        tps.append(tp)
+        dps.append(dp)
+    if replicas == 1:
+        return engines[0], tps[0], dps[0], cfgT
+    return engines, tps, dps, cfgT
 
 
 def run_continuous(args, engines, tp, dp, cfgT) -> None:
@@ -164,22 +211,40 @@ def run_continuous(args, engines, tp, dp, cfgT) -> None:
             print(f"metrics -> {path}")
 
     if args.verify:
-        ref = engines[0] if isinstance(engines, list) else engines
-        sess = ref.session(tp, dp)
-        mismatches = 0
-        for r in trace:
-            if r.rid not in results:
-                continue
-            solo, _ = sess.generate(r.prompt.reshape(1, -1), max_new=r.max_new)
-            ok = results[r.rid] == solo[0]
-            mismatches += 0 if ok else 1
-            where = ""
-            if isinstance(engines, list):
-                where = f" (replica {rt.replica_of(r.rid)})"
-            print(f"verify req {r.rid}: "
-                  f"{'byte-identical to solo generate()' if ok else 'MISMATCH'}{where}")
+        mismatches = verify_served_alone(rt, engines, tp, dp, trace, results, args.slots)
         if mismatches:
-            raise SystemExit(f"{mismatches} request(s) diverged from solo generate()")
+            raise SystemExit(f"{mismatches} request(s) diverged from their solo run")
+
+
+def verify_served_alone(rt, engines, tp, dp, trace, results, n_slots: int) -> int:
+    """Check each served output byte-identical to the same request served
+    alone: on the replica (engine, meshes and params) that served it, in a
+    runtime of the same slot count, with no other request in flight.  The
+    programs and batch width are the same; only the neighbours and the
+    admission timing differ, and they must never change a request's tokens.
+    (A batch-1 ``generate()`` is no such reference on a TPU: its round
+    programs round bf16 differently from batch-2 ones.)  Prints one line per
+    request and returns the number of mismatches."""
+    from repro.serving import ContinuousBatchingRuntime, Request
+
+    fleet = isinstance(engines, list)
+    mismatches = 0
+    for r in trace:
+        if r.rid not in results:
+            continue
+        i = rt.replica_of(r.rid) if fleet else 0
+        eng, t, d = (engines[i], tp[i], dp[i]) if fleet else (engines, tp, dp)
+        alone = ContinuousBatchingRuntime(eng, t, d, n_slots=n_slots)
+        alone.submit(Request(rid=r.rid, prompt=r.prompt, arrival_s=0.0, max_new=r.max_new))
+        solo, got = alone.run()[r.rid], results[r.rid]
+        where = f" (replica {i})" if fleet else ""
+        if got == solo:
+            print(f"verify req {r.rid}: byte-identical to its solo run{where}")
+            continue
+        mismatches += 1
+        p = next((j for j, (a, b) in enumerate(zip(got, solo)) if a != b), min(len(got), len(solo)))
+        print(f"verify req {r.rid}: MISMATCH at token {p} of {len(got)}/{len(solo)}{where}")
+    return mismatches
 
 
 def main(argv=None):
@@ -193,8 +258,10 @@ def main(argv=None):
     ap.add_argument("--bs", type=int, default=8)
     ap.add_argument("--w", type=int, default=4)
     ap.add_argument("--d", type=int, default=0, help="0 = profile-derived")
-    ap.add_argument("--n-target", type=int, default=6)
-    ap.add_argument("--n-draft", type=int, default=2)
+    ap.add_argument("--n-target", type=int, default=None,
+                    help="target devices per replica (default: from the device count)")
+    ap.add_argument("--n-draft", type=int, default=None,
+                    help="draft devices per replica; 0 colocates with the target")
     ap.add_argument("--continuous", action="store_true",
                     help="serve a Poisson trace through the continuous-batching runtime")
     ap.add_argument("--async-rounds", action="store_true",
@@ -218,7 +285,8 @@ def main(argv=None):
                          "after arrival (0 = best-effort); enables EDF "
                          "queueing, slack-aware routing, and SLO reporting")
     ap.add_argument("--no-verify", dest="verify", action="store_false",
-                    help="continuous: skip byte-identical check vs solo generate()")
+                    help="continuous: skip the byte-identical check against each "
+                         "request served alone")
     ap.add_argument("--trace-out", default=None,
                     help="continuous: write phase spans here (.json = Chrome/"
                          "Perfetto traceEvents, .jsonl = span per line)")
@@ -226,21 +294,21 @@ def main(argv=None):
                     help="continuous: write the metrics snapshot + phase "
                          "breakdown here (.json; .prom = Prometheus text)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     replicas = args.replicas if args.continuous else 1
+    cfgT, cfgD = serving_configs(args.target_arch, args.draft_arch)
     eng, tp, dp, cfgT = build_engine(
-        args.target_arch, args.draft_arch, mode=args.mode, bs=args.bs, w=args.w,
+        cfgT, cfgD, mode=args.mode, bs=args.bs, w=args.w,
         d=args.d or 2, max_new=args.max_new, n_target=args.n_target, n_draft=args.n_draft,
         replicas=replicas, async_rounds=args.async_rounds,
     )
-    eng0 = eng[0] if isinstance(eng, list) else eng
+    eng0, tp0, dp0 = (eng[0], tp[0], dp[0]) if isinstance(eng, list) else (eng, tp, dp)
 
     # profile pass (paper §5.5): pick d from measured draft/target times
     if args.d == 0:
-        import dataclasses
-
         prompt = np.zeros((1, args.prompt_len), np.int32)
-        prof = eng0.profile(tp, dp, prompt)
+        prof = eng0.profile(tp0, dp0, prompt)
         d_lo, d_hi = candidate_depths(prof)
         d_cfg = dataclasses.replace(eng0.cfg, d=d_lo)
         for e in set(eng) if isinstance(eng, list) else {eng}:
@@ -251,10 +319,9 @@ def main(argv=None):
     if args.continuous:
         run_continuous(args, eng, tp, dp, cfgT)
         return
-    eng = eng0
 
     total_toks, total_s = 0, 0.0
-    sess = eng.session(tp, dp)
+    sess = eng0.session(tp0, dp0)
     for i, prompt in enumerate(make_request_stream(cfgT.vocab_size, args.prompt_len, 1, args.requests)):
         t0 = monotonic()
         out, stats = sess.generate(prompt)

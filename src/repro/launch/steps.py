@@ -37,14 +37,14 @@ def make_train_step(cfg, model, *, peak_lr=3e-4, warmup_steps=100, total_steps=1
         loss, grads = jax.value_and_grad(loss_fn)(params)
         if grad_compress_pod:
             from repro.optim.compression import pod_allreduce_compressed
-            from repro.sharding import get_mesh, shard_map
+            from repro.sharding import get_mesh
             from jax.sharding import PartitionSpec as P
 
             mesh = get_mesh()
             if mesh is not None and "pod" in mesh.axis_names:
                 # int8-compressed DCN gradient exchange (optim/compression.py)
                 grads = jax.tree.map(
-                    lambda g: shard_map(
+                    lambda g: jax.shard_map(
                         lambda x: pod_allreduce_compressed(x, "pod"),
                         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
                     )(g),

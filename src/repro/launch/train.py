@@ -21,6 +21,7 @@ from repro.ckpt import CheckpointManager
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMDataset
 from repro.flags import override_flags
+from repro.launch.mesh import host_device_mesh
 from repro.launch.steps import make_train_step
 from repro.models.api import make_model
 from repro.obs.clock import monotonic
@@ -46,7 +47,7 @@ def main(argv=None):
     cfg = get_config(args.arch, smoke=args.smoke)
     model = make_model(cfg)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((max(1, n_dev // args.mesh_model), args.mesh_model), ("data", "model"))
+    mesh = host_device_mesh(model=args.mesh_model, data=max(1, n_dev // args.mesh_model))
 
     ds = SyntheticLMDataset(DataConfig(cfg.vocab_size, args.seq, args.batch, seed=0))
     step_fn = make_train_step(cfg, model, peak_lr=args.lr, warmup_steps=20,

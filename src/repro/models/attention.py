@@ -8,7 +8,8 @@ their tree ancestors.  All cache writes are masked one-hot scatters (never
 dynamic-slice on the sharded sequence dim), so the sequence-sharded KV cache
 ("kv_seq" -> "model") updates without collectives; the softmax over the
 sharded KV axis is XLA's distributed reduction — the mesh-scale analogue of
-the paper's split-KV single-kernel combine.
+the paper's split-KV single-kernel combine.  The serving engine traces under
+``SERVING_RULES`` instead, which shard the cache over its kv heads.
 """
 
 from __future__ import annotations
@@ -18,7 +19,13 @@ import jax.numpy as jnp
 
 from repro.flags import get_flags
 from repro.models.common import apply_rope, dense_init, zeros_init
-from repro.sharding import constrain
+from repro.sharding import constrain, shard_local
+
+# logical axes of one layer's K/V cache leaf [B, S, Hkv, hd]: under the
+# default rules the sequence shards over "model"; under the serving rules
+# (SpecEngine) the kv heads do
+KV_AXES = ("cache_batch", "kv_seq", "kv_heads", None)
+Q_AXES = ("batch", None, "heads", None)
 
 
 def init_attention(cfg, key, *, cross: bool = False):
@@ -217,13 +224,17 @@ def attention_cached(cfg, p, x, cache_k, cache_v, row_idx, positions, attn_mask,
     else:
         ck = scatter_rows(cache_k, k_new, row_idx)
         cv = scatter_rows(cache_v, v_new, row_idx)
-    ck = constrain(ck, "cache_batch", "kv_seq", None, None)
-    cv = constrain(cv, "cache_batch", "kv_seq", None, None)
+    ck = constrain(ck, *KV_AXES)
+    cv = constrain(cv, *KV_AXES)
 
     if flags.use_pallas_attention:
         from repro.kernels import ops as kops
 
-        out = kops.tree_attention(q, ck, cv, attn_mask, interpret=flags.pallas_interpret)
+        # per kv-head shard: each chip attends with its own heads
+        out = shard_local(
+            kops.tree_attention, (q, ck, cv, attn_mask),
+            (Q_AXES, KV_AXES, KV_AXES, ("batch", None, "kv_seq")), Q_AXES,
+            local_dims=((0, 2), (0, 2), (0, 2), (0,)))
     else:
         out = _attend(q, ck, cv, attn_mask[:, None, None, :, :])
     out = jnp.einsum("bnhk,hkd->bnd", out, p["wo"].value)

@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.flags import get_flags
 from repro.models.common import dense_init
-from repro.sharding import get_mesh, shard_map
+from repro.sharding import get_mesh
 
 
 def init_moe(cfg, key):
@@ -141,7 +141,7 @@ def moe_apply(cfg, p, x):
                 y = _combine(h, (dest, tok, w_sorted), x_loc.shape[0])
                 return jax.lax.psum(y, "model")
 
-            out = shard_map(
+            out = jax.shard_map(
                 ep_block,
                 mesh=mesh,
                 in_specs=(tok_spec, P(None, None), P("model", None, None), P("model", None, None), P("model", None, None)),
@@ -154,7 +154,7 @@ def moe_apply(cfg, p, x):
                 y = _moe_local(x_loc, {"router": router, "wg": wg, "wu": wu, "wd": wd}, cfg, cap)
                 return jax.lax.psum(y, "model")
 
-            out = shard_map(
+            out = jax.shard_map(
                 tp_block,
                 mesh=mesh,
                 in_specs=(tok_spec, P(None, None), P(None, None, "model"), P(None, None, "model"), P(None, "model", None)),

@@ -28,9 +28,9 @@ from repro.flags import get_flags
 from repro.models import mamba2 as m2
 from repro.models import mla as mla_mod
 from repro.models import rwkv6 as rk
-from repro.models.attention import attention_cached, attention_full, init_attention
+from repro.models.attention import KV_AXES, attention_cached, attention_full, init_attention
 from repro.models.common import dense_init, ones_init, rms_norm
-from repro.sharding import Param, add_leading_axis, constrain
+from repro.sharding import Param, add_leading_axis, constrain, shard_local
 
 
 # -----------------------------------------------------------------------------
@@ -182,9 +182,12 @@ def _mlp_apply(cfg, p, x):
         from repro.kernels import ops as kops
 
         B, S, d = x.shape
-        out = kops.fused_swiglu(
-            x.reshape(B * S, d), p["wg"].value, p["wu"].value, interpret=flags.pallas_interpret
-        )
+        # per ff-column shard: each chip computes its slice of the hidden
+        w_axes = ("embed", "ff")
+        out = shard_local(
+            kops.fused_swiglu, (x.reshape(B * S, d), p["wg"].value, p["wu"].value),
+            ((None, "act_embed"), w_axes, w_axes), (None, "ff"),
+            local_dims=((), (1,), (1,)))
         return (out @ p["wd"].value).reshape(B, S, d)
     g = x @ p["wg"].value
     u = x @ p["wu"].value
@@ -230,10 +233,8 @@ def _attn_dispatch(cfg, p, h, ctx: Ctx, cache, kind):
         if ctx.make_cache:
             pad = ctx.make_cache - k.shape[1]
             nc = {
-                "k": constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                               "cache_batch", "kv_seq", None, None),
-                "v": constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                               "cache_batch", "kv_seq", None, None),
+                "k": constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))), *KV_AXES),
+                "v": constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))), *KV_AXES),
             }
         return out, nc
     out, ck, cv = attention_cached(
@@ -392,7 +393,10 @@ def axes_tree(stacked):
 
 
 def logits_from_hidden(cfg, params, h):
-    logits = h @ params["lm_head"].value
+    # float32 logits whatever the compute dtype: a bf16 round of the logits
+    # would tie the top two in a large share of greedy steps
+    logits = jnp.einsum("bnd,dv->bnv", h, params["lm_head"].value,
+                        preferred_element_type=jnp.float32)
     return constrain(logits, "batch", "seq", "vocab")
 
 

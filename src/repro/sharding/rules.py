@@ -24,26 +24,6 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax-version compat: shard_map graduated from jax.experimental to
-# jax.shard_map, and its replication-check kwarg was renamed
-# check_rep -> check_vma along the way.  All repo code calls
-# repro.sharding.shard_map with the NEW spelling; this shim routes to
-# whatever the installed jax provides.
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-_SHARD_MAP_PARAMS = frozenset(_inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, *args, **kwargs):
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map_impl(f, *args, **kwargs)
-
 # -----------------------------------------------------------------------------
 # Param: an array boxed with its logical axis names (single source of truth).
 # -----------------------------------------------------------------------------
@@ -122,6 +102,12 @@ DEFAULT_RULES: dict[str, Any] = {
     None: None,
 }
 
+# Serving (SpecEngine on its 1-D "model" TP meshes): the KV cache shards over
+# its kv heads, the layout tensor parallelism computes in, so attention and
+# the row-move kernels run shard-local.  The sequence stays whole on each
+# chip: a tree's rows move within one sequence, never across chips.
+SERVING_RULES: dict[str, Any] = {**DEFAULT_RULES, "kv_seq": None}
+
 
 def _axis_size(mesh: Mesh, names) -> int:
     if names is None:
@@ -176,6 +162,8 @@ def sharding_for_tree(mesh: Mesh, params, rules=None):
 # -----------------------------------------------------------------------------
 # Mesh context: models call ``constrain`` freely; it is the identity when no
 # mesh is active (single-device tests) and a sharding constraint otherwise.
+# The context also carries the rules table (DEFAULT_RULES unless the caller
+# enters with another, as the serving engine does with SERVING_RULES).
 # -----------------------------------------------------------------------------
 
 _CTX = threading.local()
@@ -189,10 +177,15 @@ def get_mesh() -> Mesh | None:
     return getattr(_CTX, "mesh", None)
 
 
+def get_rules() -> dict:
+    return getattr(_CTX, "rules", None) or DEFAULT_RULES
+
+
 @contextlib.contextmanager
-def use_mesh(mesh: Mesh | None):
-    prev = get_mesh()
+def use_mesh(mesh: Mesh | None, rules: dict | None = None):
+    prev, prev_rules = get_mesh(), getattr(_CTX, "rules", None)
     set_mesh(mesh)
+    _CTX.rules = rules
     try:
         if mesh is not None:
             with mesh:
@@ -201,6 +194,7 @@ def use_mesh(mesh: Mesh | None):
             yield None
     finally:
         set_mesh(prev)
+        _CTX.rules = prev_rules
 
 
 def constrain(x, *axes, rules=None):
@@ -209,5 +203,46 @@ def constrain(x, *axes, rules=None):
     if mesh is None:
         return x
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, spec_for(mesh, axes, x.shape, rules))
+        x, NamedSharding(mesh, spec_for(mesh, axes, x.shape, rules or get_rules()))
     )
+
+
+def shard_local(fn, args, axes, out_axes, *, local_dims):
+    """Run ``fn`` on each device's shard of ``args`` under the active mesh.
+
+    ``axes`` gives each argument's logical axes and ``out_axes`` those of the
+    result (a list of them for several results); the active rules map them
+    to mesh axes.  ``local_dims[i]`` names the dims of argument i along which
+    ``fn`` is independent (heads, feature columns); the j-th entries of all
+    arguments are the same logical dimension.  When every sharded dim of
+    every argument is one of those, and the j-th dims of all arguments are
+    sharded alike (query heads split only where kv heads split with them),
+    ``fn`` runs per shard under ``jax.shard_map`` and no data moves.
+    Otherwise every operand is replicated first (correct, at the cost of a
+    gather), so a kernel with no partitioning rule never sees a shard it
+    cannot handle.  Without a mesh, or on one device, ``fn`` runs as is.
+    """
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*args)
+    rules = get_rules()
+    specs = [spec_for(mesh, ax, a.shape, rules) for a, ax in zip(args, axes)]
+    local = all(
+        all(s is None or d in dims for d, s in enumerate(tuple(spec)))
+        for spec, dims in zip(specs, local_dims))
+    for j in range(max(map(len, local_dims))):
+        local &= len({tuple(spec)[dims[j]] for spec, dims in zip(specs, local_dims)
+                      if j < len(dims)}) <= 1
+    multi = isinstance(out_axes, list)
+    outs = out_axes if multi else [out_axes]
+    if local:
+        shapes = jax.eval_shape(fn, *args)
+        shapes = shapes if multi else [shapes]
+        out_specs = tuple(spec_for(mesh, ax, o.shape, rules) for o, ax in zip(shapes, outs))
+    else:
+        specs = [P() for _ in args]
+        out_specs = tuple(P() for _ in outs)
+    return jax.shard_map(
+        (lambda *a: tuple(fn(*a))) if multi else fn, mesh=mesh, in_specs=tuple(specs),
+        out_specs=out_specs if multi else out_specs[0], check_vma=False,
+    )(*args)

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.core.engine import greedy_decode
 from repro.models.api import make_model
 
 
@@ -40,15 +41,5 @@ def dense_pair():
 
 
 def greedy_reference(model, params, prompt, n, S_max=256):
-    """Target-only greedy decoding (the spec-equality oracle)."""
-    pref = jax.jit(lambda p, t: model.prefill(p, tokens=t, S_max=S_max))
-    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t, S_max))
-    lg, cache = pref(params, jnp.asarray(prompt))
-    cur = jnp.argmax(lg[:, -1, :], -1)[:, None].astype(jnp.int32)
-    out = [[int(cur[b, 0])] for b in range(prompt.shape[0])]
-    for _ in range(n - 1):
-        lg, cache = step(params, cache, cur)
-        cur = jnp.argmax(lg[:, -1, :], -1)[:, None].astype(jnp.int32)
-        for b in range(prompt.shape[0]):
-            out[b].append(int(cur[b, 0]))
-    return out
+    """Target-only greedy decoding (the spec-equality oracle), as lists."""
+    return greedy_decode(model, params, prompt, n, S_max)[0].tolist()

@@ -7,7 +7,7 @@ import sys
 import jax
 import jax.numpy as jnp
 
-from repro.launch.hlo_parse import analyze, compiled_cost as _cost
+from repro.launch.hlo_parse import analyze
 
 
 def _compiled(f, *avals):
@@ -19,8 +19,8 @@ def test_matches_xla_on_loop_free():
                   jax.ShapeDtypeStruct((256, 256), jnp.float32),
                   jax.ShapeDtypeStruct((256, 256), jnp.float32))
     mc = analyze(c.as_text())
-    assert mc.flops == _cost(c)["flops"] == 2 * 256**3
-    assert mc.bytes_raw == _cost(c)["bytes accessed"]
+    assert mc.flops == c.cost_analysis()["flops"] == 2 * 256**3
+    assert mc.bytes_raw == c.cost_analysis()["bytes accessed"]
 
 
 def test_scan_trip_scaling():
@@ -35,7 +35,7 @@ def test_scan_trip_scaling():
     assert list(mc.loop_trips.values()) == [8]
     # XLA's own aggregate counts the body once — document the gap we fix
     # (± a few scalar flops from the loop counter)
-    assert abs(_cost(c)["flops"] - 2 * 128**3) < 100
+    assert abs(c.cost_analysis()["flops"] - 2 * 128**3) < 100
 
 
 def test_nested_scan_trip_product():
@@ -60,7 +60,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_parse import analyze
 
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.ShapeDtypeStruct((64, 64), jnp.float32, sharding=NamedSharding(mesh, P(None, "model")))
 w = jax.ShapeDtypeStruct((64, 64), jnp.float32, sharding=NamedSharding(mesh, P("model", None)))
 

@@ -129,7 +129,7 @@ def test_apply_moves_flag_paths_identical():
     src, dst, mask = _random_plan(rng, 1, S, M)
     args = (jnp.asarray(src), jnp.asarray(dst), jnp.asarray(mask))
     ref = kvm.apply_moves(cache, *args)
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         fused = kvm.apply_moves(cache, *args)
         fused_d = kvm.apply_moves(cache, *args, donate=True)
     for got in (fused, fused_d):
@@ -160,7 +160,7 @@ def test_install_and_zero_slot_fused_match_xla():
     big, one = _toy_cache(rng, 3, 8), _toy_cache(rng, 1, 8)
     want_inst = kvm.install_slot(big, one, 1)
     want_zero = kvm.zero_slot(big, 2)
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         got_inst = kvm.install_slot(big, one, 1)
         got_zero = kvm.zero_slot(big, 2)
     for got, want in ((got_inst, want_inst), (got_zero, want_zero)):
@@ -171,7 +171,7 @@ def test_install_and_zero_slot_fused_match_xla():
 def test_slot_write_rows_traced_slot_and_dtype_fallback():
     rng = np.random.default_rng(4)
     big, one = _toy_cache(rng, 3, 8), _toy_cache(rng, 1, 8)
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         # traced slot: one jit covers every slot index (the engine contract)
         f = jax.jit(kvm.install_slot, donate_argnums=(0,))
         got = f(jax.tree.map(jnp.copy, big), one, jnp.asarray(2, jnp.int32))
@@ -224,7 +224,7 @@ def test_solo_generate_fused_identical(fused_engines):
     e, tp, dp = fused_engines
     prompt = _prompt(3).reshape(1, -1)
     out_ref, _ = e["ref"].session(tp, dp).generate(prompt)
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         out_fused, _ = e["fused"].session(tp, dp).generate(prompt)
     assert out_fused == out_ref
 
@@ -238,7 +238,7 @@ def test_async_commit_and_rollback_fused_identical(fused_engines):
     rows the reference would have."""
     e, tp, dp = fused_engines
     prompt = _prompt(5).reshape(1, -1)
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         out_lock, _ = e["fused_self"].session(tp, tp).generate(prompt)
         asyn = e["async_self"]
         out_commit, st = asyn.session(tp, tp).generate(prompt)
@@ -270,6 +270,6 @@ def test_sharded_serving_fused_identical(fused_engines):
         return rt.run()
 
     ref = serve(e["sharded_ref"])
-    with override_flags(use_pallas_kv_moves=True, pallas_interpret=True):
+    with override_flags(use_pallas_kv_moves=True):
         fused = serve(e["sharded_fused"])
     assert fused == ref and sorted(fused) == [0, 1, 2]

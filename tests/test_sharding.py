@@ -84,7 +84,8 @@ toks = (jnp.arange(24, dtype=jnp.int32).reshape(2, 12) * 7 + 1) % cfg.vocab_size
 ref = np.asarray(m.forward_train(params, tokens=toks), np.float32)
 
 # SPMD on a (2 data, 4 model) mesh: same math, sharded execution
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import host_device_mesh
+mesh = host_device_mesh(model=4, data=2)
 sh = sharding_for_tree(mesh, params)
 vals = jax.tree.map(jax.device_put, unbox(params), sh)
 import jax.tree_util as jtu

@@ -7,12 +7,13 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import ASSIGNED, SHAPES, cell_applicable, get_config
+from repro.launch.mesh import host_device_mesh
 from repro.launch.specs import batch_specs, cache_specs, cell_specs, dryrun_config
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return host_device_mesh()
 
 
 @pytest.mark.parametrize("arch", ASSIGNED)

@@ -1,0 +1,118 @@
+"""Mosaic compiles of the main-path Pallas kernels for a described TPU v5e.
+
+Nothing runs: each case lowers and compiles one kernel at the real widths of
+the DeepSeek-Coder-33B target / 1.3B draft pair in bf16 for a v5e chip that
+is described, not attached, and asserts that the compiled program holds the
+kernel as a ``tpu_custom_call``.  This catches what interpret mode cannot —
+block shapes off the (8, 128) tiling, DMA slices of tiled dims, VMEM
+overuse — at no chip time.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU compiler library, and under several
+pytest workers only the worker running this file may take it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import ops
+from repro.kernels.kv_moves import kv_move_rows_pallas, slot_write_rows_pallas
+
+TARGET = get_config("deepseek-coder-33b")
+DRAFT = get_config("deepseek-coder-1.3b")
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means: no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _tree_attention(dev):
+    cfg = TARGET  # verify: 8 tree rows against a 2048-row cache, GQA group 7
+    B, n, S = 1, 8, 2048
+    q = _sds((B, n, cfg.n_heads, cfg.head_dim), BF16, dev)
+    kv = _sds((B, S, cfg.n_kv_heads, cfg.head_dim), BF16, dev)
+    mask = _sds((B, n, S), jnp.bool_, dev)
+    fn = jax.jit(lambda q, k, v, m: ops.tree_attention(q, k, v, m, interpret=False))
+    return fn, (q, kv, kv, mask)
+
+
+def _fused_swiglu(cfg):
+    def build(dev):
+        x = _sds((16, cfg.d_model), BF16, dev)
+        w = _sds((cfg.d_model, cfg.d_ff), BF16, dev)
+        fn = jax.jit(lambda x, wg, wu: ops.fused_swiglu(x, wg, wu, interpret=False))
+        return fn, (x, w, w)
+
+    return build
+
+
+def _kv_cache_leaf(dev, B=2, S=2048, U=6):
+    return _sds((U, B, S, TARGET.n_kv_heads * TARGET.head_dim), BF16, dev)
+
+
+def _kv_move_rows(donate):
+    def build(dev):
+        B, M = 2, 8
+        arr = _kv_cache_leaf(dev, B=B)
+        idx = _sds((B, M), jnp.int32, dev)
+        fn = jax.jit(
+            lambda a, s, d, act: kv_move_rows_pallas(a, s, d, act, donate=donate,
+                                                     interpret=False),
+            donate_argnums=(0,) if donate else ())
+        return fn, (arr, idx, idx, idx)
+
+    return build
+
+
+def _slot_write_rows(dev):
+    U, B, S = 6, 2, 2048
+    big = _sds((U, B, S, TARGET.n_kv_heads, TARGET.head_dim), BF16, dev)
+    one = _sds((U, 1, S, TARGET.n_kv_heads, TARGET.head_dim), BF16, dev)
+    slot = _sds((), jnp.int32, dev)
+    fn = jax.jit(
+        lambda k, v, dk, dv, s: slot_write_rows_pallas([k, v], [dk, dv], s, interpret=False),
+        donate_argnums=(0, 1))
+    return fn, (big, big, one, one, slot)
+
+
+CASES = {
+    "tree_attention-33b-verify": _tree_attention,
+    "fused_swiglu-33b-7168x19200": _fused_swiglu(TARGET),
+    "fused_swiglu-1.3b-2048x5504": _fused_swiglu(DRAFT),
+    "kv_move_rows-donate": _kv_move_rows(True),
+    "kv_move_rows-copy-through": _kv_move_rows(False),
+    "slot_write_rows": _slot_write_rows,
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    fn, args = CASES[case](one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, f"{case}: no Mosaic kernel in the compiled program"
