@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.engine import EngineSession
 from repro.obs.clock import monotonic
 from repro.obs.trace import NULL_TRACER
 from repro.sharding import use_mesh
@@ -58,6 +59,7 @@ class ChainStats:
     reused_chains: int = 0
     draft_chains: int = 0
     wall_s: float = 0.0
+    sync_s: float = 0.0  # host seconds blocked in the rounds' designated sync
 
     @property
     def compression_ratio(self) -> float:
@@ -194,9 +196,9 @@ class ChainSession:
                         next_pre = (nxt_drafts, None)
                         stats.draft_chains += 1
 
-            # --- sync point ---------------------------------------------------
-            with self.tracer.span("sync_emitted", self.track):
-                argmax_h, drafts_h = jax.device_get((argmax, drafts))  # repro: disable=HOTSYNC — designated sync point: ONE fused transfer of the round's verdict
+            # --- sync point: ONE fused transfer of the round's verdict ------
+            argmax_h, drafts_h = EngineSession.sync_emitted(
+                self.tracer, self.track, stats, (argmax, drafts))
             vspan.end()
             argmax_h = np.asarray(argmax_h)[0]  # [k]
             drafts_h = np.asarray(drafts_h)[0]  # [k]

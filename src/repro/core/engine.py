@@ -61,6 +61,12 @@ class SpecStats:
     accepted_rows: np.ndarray | None = None  # i64[B] per-row accepted totals
     spec_rounds: int = 0  # rounds run through the async lookahead path
     spec_commits: int = 0  # of those, rounds whose lookahead tree was adopted
+    # always-on round accounting (docs/observability.md), the same whether a
+    # tracer is on or not: host seconds blocked in the round's one designated
+    # sync (``sync_emitted``), and dispatches per jitted program, keyed by the
+    # name the device trace gives the program
+    sync_s: float = 0.0
+    dispatches: dict = dataclasses.field(default_factory=dict)
 
     def add_round(self, n_emitted, n_accepted):
         n_emitted = np.asarray(n_emitted, np.int64)
@@ -70,6 +76,10 @@ class SpecStats:
         self.emitted_rows += n_emitted
         self.accepted_rows += np.asarray(n_accepted, np.int64)
         self.rounds += 1
+
+    def ran(self, program: str, n: int = 1) -> None:
+        """Count ``n`` dispatches of the jitted ``program``."""
+        self.dispatches[program] = self.dispatches.get(program, 0) + n
 
     @property
     def emitted(self) -> float:
@@ -305,22 +315,34 @@ class SpecEngine:
         self._seed = jax.jit(seed, static_argnums=(2,))
         self._verify = jax.jit(verify, donate_argnums=(1,))
         self._compact = jax.jit(compact, donate_argnums=(0,))
-        self._dprefill = jax.jit(lambda p, t, S: draft.prefill(p, tokens=t, S_max=S), static_argnums=(2,))
-        self._tprefill = jax.jit(lambda p, t, S: target.prefill(p, tokens=t, S_max=S), static_argnums=(2,))
+        # every program is a named function, so the device trace names it
+        # (``jit_<name>``; a lambda would show as ``jit__lambda``)
+        def draft_prefill(p, t, S):
+            return draft.prefill(p, tokens=t, S_max=S)
+
+        def target_prefill(p, t, S):
+            return target.prefill(p, tokens=t, S_max=S)
+
         # per-slot lifecycle (continuous batching); slot/plen are traced so
         # one compile covers every slot index and prompt length.  The cache
         # writes go through per-engine closures: jit caches traces by the
         # wrapped function, and a module function would share one trace (and
         # one kernel choice) across engines built under different flags.
-        self._install = jax.jit(lambda cache, donor, slot: kvm.install_slot(cache, donor, slot),
-                                donate_argnums=(0,))
-        self._zero_slot = jax.jit(lambda cache, slot: kvm.zero_slot(cache, slot),
-                                  donate_argnums=(0,))
+        def install_slot(cache, donor, slot):
+            return kvm.install_slot(cache, donor, slot)
+
+        def zero_slot(cache, slot):
+            return kvm.zero_slot(cache, slot)
+
+        def seed_slot(tr, slot, tok, plen, lg):
+            return T.seed_slot(tr, slot, tok, plen, lg, c.c)
+
+        self._dprefill = jax.jit(draft_prefill, static_argnums=(2,))
+        self._tprefill = jax.jit(target_prefill, static_argnums=(2,))
+        self._install = jax.jit(install_slot, donate_argnums=(0,))
+        self._zero_slot = jax.jit(zero_slot, donate_argnums=(0,))
         self._reset_slot = jax.jit(T.reset_slot, donate_argnums=(0,))
-        self._seed_slot = jax.jit(
-            lambda tr, slot, tok, plen, lg: T.seed_slot(tr, slot, tok, plen, lg, c.c),
-            donate_argnums=(0,),
-        )
+        self._seed_slot = jax.jit(seed_slot, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
     # state lifecycle (used by generate() below and by serving/runtime.py)
@@ -371,9 +393,11 @@ class SpecEngine:
         return jax.tree.map(lambda s, sh: jnp.zeros(s.shape, s.dtype, device=sh),
                             shapes, kvm.cache_shardings(mesh, shapes, SERVING_RULES))
 
-    def _prefill_state(self, tparams, dparams, prompt) -> EngineState:
+    def _prefill_state(self, tparams, dparams, prompt,
+                       stats: SpecStats | None = None) -> EngineState:
         """Whole-batch prefill + tree seed + initial growth (all rows start
-        together — the generate() path)."""
+        together — the generate() path); its dispatches are counted in
+        ``stats``."""
         B, P = prompt.shape
         with _serving(self.mesh_draft):
             dlogits, dcache = self._dprefill(dparams, jnp.asarray(prompt), self.S_max_d)
@@ -386,20 +410,27 @@ class SpecEngine:
             for _ in range(self.grow_per_round):
                 tr, dcache = self._expand(dparams, tr, dcache)
             plan = self._select_plan(tr)
+        if stats is not None:
+            for program in ("jit_draft_prefill", "jit_target_prefill", "jit_seed",
+                            "jit_select_plan"):
+                stats.ran(program)
+            stats.ran("jit_expand", self.grow_per_round)
         return EngineState(tcache, dcache, tr, plan)
 
     def session(self, tparams, dparams, *, state: EngineState | None = None,
-                n_slots: int | None = None, tracer=None, track: str = "engine") -> "EngineSession":
-        """Bind params (+ optional state and tracer) into an ``EngineSession``
-        — the round API: ``session.step()`` / ``admit_slot`` / ``release_slot``
-        / ``generate``, plus the async phase methods ``dispatch_verify`` /
-        ``draft_next_tree`` / ``reconcile``.  Pass ``n_slots`` to start from an
-        empty parked serving state."""
+                n_slots: int | None = None, tracer=None, track: str = "engine",
+                stats: SpecStats | None = None) -> "EngineSession":
+        """Bind params (+ optional state, tracer and stats) into an
+        ``EngineSession`` — the round API: ``session.step()`` / ``admit_slot``
+        / ``release_slot`` / ``generate``, plus the async phase methods
+        ``dispatch_verify`` / ``draft_next_tree`` / ``reconcile``.  Pass
+        ``n_slots`` to start from an empty parked serving state."""
         if state is None and n_slots is not None:
             state = self.init_state(n_slots)
         return EngineSession(
             engine=self, tparams=tparams, dparams=dparams, state=state,
-            tracer=tracer if tracer is not None else NULL_TRACER, track=track)
+            tracer=tracer if tracer is not None else NULL_TRACER, track=track,
+            stats=stats if stats is not None else SpecStats())
 
     # --- one-release deprecation shims over the session API ---------------
     def admit_slot(self, tparams, dparams, state: EngineState, slot: int, prompt) -> EngineState:
@@ -432,7 +463,8 @@ class SpecEngine:
             "SpecEngine.step(tparams, dparams, state, ...) is deprecated; "
             "bind an EngineSession via SpecEngine.session(...) instead",
             DeprecationWarning, stacklevel=2)
-        s = self.session(tparams, dparams, state=state, tracer=tracer, track=trace_track)
+        s = self.session(tparams, dparams, state=state, tracer=tracer, track=trace_track,
+                         stats=stats)
         res = s.step(stats=stats)
         return s.state, res
 
@@ -520,6 +552,11 @@ class EngineSession:
     Between ``begin_round`` and ``reconcile`` the session state is consumed
     (buffers donated into the round) — ``admit_slot``/``release_slot``/
     ``step`` must not run until the in-flight round reconciles.
+
+    ``stats`` takes the always-on counters, whatever the tracer: every
+    program the session dispatches (``SpecStats.dispatches``) and the host
+    seconds blocked in each round's sync (``SpecStats.sync_s``).  ``step``
+    and ``reconcile`` add the round itself to the ``stats`` they are handed.
     """
 
     engine: SpecEngine
@@ -528,7 +565,19 @@ class EngineSession:
     state: EngineState | None = None
     tracer: Any = NULL_TRACER
     track: str = "engine"
+    stats: SpecStats = dataclasses.field(default_factory=SpecStats)
     _inflight: RoundInFlight | None = dataclasses.field(default=None, repr=False)
+
+    @staticmethod
+    def sync_emitted(tracer, track: str, stats, tree):
+        """The round's ONE designated host sync, shared by the tree and chain
+        sessions: ``jax.device_get`` of ``tree`` under the ``sync_emitted``
+        span, the seconds the host blocked in it added to ``stats.sync_s``."""
+        with tracer.span("sync_emitted", track):
+            t0 = monotonic()
+            host = jax.device_get(tree)  # repro: disable=HOTSYNC — designated sync point
+            stats.sync_s += monotonic() - t0
+        return host
 
     # ------------------------------------------------------------------
     # slot lifecycle
@@ -562,6 +611,12 @@ class EngineSession:
                 tr, dcache = eng._expand(self.dparams, tr, dcache)
             plan = eng._select_plan(tr)
         self.state = EngineState(tcache, dcache, tr, plan)
+        st = self.stats
+        for program in ("jit_draft_prefill", "jit_target_prefill", "jit_seed_slot",
+                        "jit_select_plan"):
+            st.ran(program)
+        st.ran("jit_install_slot", 2)
+        st.ran("jit_expand", eng.grow_per_round)
 
     def release_slot(self, slot: int) -> None:
         """Retire batch row ``slot``: park its tree and physically zero its
@@ -575,6 +630,9 @@ class EngineSession:
             tr = eng._reset_slot(state.tr, slot)
             plan = eng._select_plan(tr)
         self.state = EngineState(tcache, dcache, tr, plan)
+        self.stats.ran("jit_zero_slot", 2)
+        self.stats.ran("jit_reset_slot")
+        self.stats.ran("jit_select_plan")
 
     def _dispatch(self, plan, tcache):
         """Enqueue verification of ``plan`` on the target group, then the
@@ -589,7 +647,18 @@ class EngineSession:
             )
             with self.tracer.span("kv_move", self.track):
                 tcache = eng._compact(tcache, *mv)
+        self.stats.ran("jit_verify")
+        self.stats.ran("jit_compact")
         return acc_pos, n_acc, bonus, emitted, n_emitted, tcache
+
+    def _count_reroot(self, n_grow: int) -> None:
+        """Count one re-root: tree bookkeeping, the draft KV move (a
+        ``functools.partial``, which the trace names ``jit__unknown``), the
+        prefix fill, ``n_grow`` expansions and the next plan."""
+        st = self.stats
+        for program in ("jit_reroot", "jit__unknown", "jit_fill_prefix", "jit_select_plan"):
+            st.ran(program)
+        st.ran("jit_expand", n_grow)
 
     # ------------------------------------------------------------------
     # the round, lockstep
@@ -640,11 +709,12 @@ class EngineSession:
                     for _ in range(d_eff):
                         tr, dcache = eng._expand(self.dparams, tr, dcache)
                     draft_steps += d_eff
+                    self.stats.ran("jit_expand", d_eff)
         # --- sync point: verified tokens cross groups (host-mediated) ------
-        with obs.span("sync_emitted", track):
-            # the round's ONE designated host sync: the verified-token
-            # transfer (paper's NCCL exchange), fused — everything else async
-            emitted_h, n_emitted_h, n_acc_h = jax.device_get((emitted, n_emitted, n_acc))  # repro: disable=HOTSYNC — designated sync point
+        # the verified-token transfer (paper's NCCL exchange), fused —
+        # everything else async
+        emitted_h, n_emitted_h, n_acc_h = self.sync_emitted(
+            obs, track, self.stats, (emitted, n_emitted, n_acc))
         # --- re-root, fill, grow, select next batch (draft group) ----------
         with obs.span("reroot_grow", track):
             with _serving(eng.mesh_draft):
@@ -658,6 +728,7 @@ class EngineSession:
                     tr, dcache = eng._expand(self.dparams, tr, dcache)
                 draft_steps += n_grow
                 new_plan = eng._select_plan(tr)
+            self._count_reroot(n_grow)
         self.state = EngineState(tcache, dcache, tr, new_plan)
         if stats is not None:
             stats.add_round(n_emitted_h, n_acc_h)
@@ -725,6 +796,9 @@ class EngineSession:
                     la_tr, la_dcache = eng._expand(self.dparams, la_tr, la_dcache)
                 rif.draft_steps += eng.grow_per_round
                 rif.lookahead = (la_tr, la_dcache, eng._select_plan(la_tr))
+            self.stats.ran("jit_expand", d_eff)
+            self.stats.ran("jit_predict_accept")
+            self._count_reroot(eng.grow_per_round)
         return rif
 
     def reconcile(self, rif: RoundInFlight, stats: SpecStats | None = None,
@@ -742,11 +816,11 @@ class EngineSession:
         eng, obs, track = self.engine, self.tracer, self.track
         acc_pos, n_acc, bonus, emitted, n_emitted = rif.verify
         pred_acc, pred_n, pred_bonus = rif.pred
-        with obs.span("sync_emitted", track):
-            # the round's ONE designated host sync: verified tokens and the
-            # prediction verdict cross in a single fused transfer
-            (emitted_h, n_emitted_h, n_acc_h, acc_h, bonus_h, pred_acc_h, pred_n_h, pred_bonus_h) = jax.device_get(  # repro: disable=HOTSYNC — designated sync point
-                (emitted, n_emitted, n_acc, acc_pos, bonus, pred_acc, pred_n, pred_bonus))
+        # verified tokens and the prediction verdict cross in one fused transfer
+        (emitted_h, n_emitted_h, n_acc_h, acc_h, bonus_h, pred_acc_h, pred_n_h,
+         pred_bonus_h) = self.sync_emitted(
+            obs, track, self.stats,
+            (emitted, n_emitted, n_acc, acc_pos, bonus, pred_acc, pred_n, pred_bonus))
         rif.verify_span.end()
         ok = ((pred_n_h == n_acc_h) & (pred_bonus_h == bonus_h)
               & (pred_acc_h == acc_h).all(axis=1))
@@ -772,6 +846,7 @@ class EngineSession:
                         tr, dcache = eng._expand(self.dparams, tr, dcache)
                     draft_steps += eng.grow_per_round
                     new_plan = eng._select_plan(tr)
+                self._count_reroot(eng.grow_per_round)
         self.state = EngineState(rif.tcache, dcache, tr, new_plan)
         self._inflight = None
         if stats is not None:
@@ -785,16 +860,17 @@ class EngineSession:
         """prompt: np.ndarray [B, P] int32. Returns (tokens [B, <=max_new] list, stats).
 
         Rebuilds the session state from a whole-batch prefill of ``prompt``
-        (any prior state is discarded), then loops rounds."""
+        (any prior state and counters are discarded: ``stats`` is the
+        session's new ``stats``), then loops rounds."""
         eng, c = self.engine, self.engine.cfg
         max_new = max_new or c.max_new
         B, P = prompt.shape
         t0 = monotonic()
 
-        self.state = eng._prefill_state(self.tparams, self.dparams, prompt)
+        stats = self.stats = SpecStats()
+        self.state = eng._prefill_state(self.tparams, self.dparams, prompt, stats=stats)
         out = [[] for _ in range(B)]
         done = np.zeros(B, bool)
-        stats = SpecStats()
         rounds_cap = max_new + 2  # greedy emits >=1 token/round
 
         for _ in range(rounds_cap):

@@ -9,8 +9,8 @@ end-of-run aggregates.  ``Tracer`` records host-side phase spans:
     where begin and end live in different methods, e.g. a round span opened
     by ``EngineStepper.step`` and closed by ``absorb_round``);
   * ``span(name, track)`` — the same span as a context manager;
-  * ``instant(name)`` / ``counter(name, value)`` — point events and
-    time-series counters (queue depth, occupancy).
+  * ``counter(name, value)`` — time-series counters (queue depth,
+    occupancy).
 
 Disabled-path contract: a disabled tracer is free.  ``begin``/``span``
 return the cached ``NOOP_SPAN`` singleton before touching the clock, so the
@@ -103,7 +103,6 @@ class Tracer:
         self._clock = clock if clock is not None else monotonic
         self._epoch = self._clock()
         self._spans: collections.deque[Span] = collections.deque(maxlen=capacity)
-        self._instants: collections.deque = collections.deque(maxlen=capacity)
         self._counters: collections.deque = collections.deque(maxlen=capacity)
         self._tracks: dict[str, int] = {}
 
@@ -125,13 +124,6 @@ class Tracer:
 
     # a span used inline reads better as ``with tracer.span(...):``
     span = begin
-
-    def instant(self, name: str, track: str = "main", args=None) -> None:
-        if not self.enabled:
-            return
-        if len(self._instants) == self.capacity:
-            self.dropped += 1
-        self._instants.append((name, track, self._now(), args))
 
     def counter(self, name: str, value, track: str = "counters") -> None:
         """One sample of a time-series counter (queue depth, occupancy)."""
@@ -166,12 +158,6 @@ class Tracer:
                   "ts": s.t0 * 1e6, "dur": s.dur * 1e6}
             if s.args:
                 ev["args"] = s.args
-            events.append(ev)
-        for name, track, t, args in self._instants:
-            ev = {"name": name, "cat": "event", "ph": "i", "s": "t",
-                  "pid": 0, "tid": self._tid(track), "ts": t * 1e6}
-            if args:
-                ev["args"] = args
             events.append(ev)
         for name, track, t, value in self._counters:
             events.append({"name": name, "cat": "counter", "ph": "C", "pid": 0,

@@ -150,11 +150,14 @@ class EngineStepper:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.track = f"replica{replica}"
         self._round_span = NOOP_SPAN
+        # the open round's start and the session's sync seconds at that start
+        self._round_t0 = self._round_sync0 = 0.0
+        # engine-level round accounting, and the session's always-on counters
+        self.spec_stats = SpecStats()
         # the bound round API: params + EngineState + tracer, one per replica
         self.session = engine.session(
             tparams, dparams, n_slots=n_slots, tracer=self.tracer,
-            track=self.track)
-        self.spec_stats = SpecStats()  # engine-level round accounting
+            track=self.track, stats=self.spec_stats)
         rep = str(replica)
         m = self.metrics
         self._m_rounds = m.counter("serving_rounds_total", replica=rep)
@@ -249,7 +252,10 @@ class EngineStepper:
         Opens this replica's ``round`` span; ``absorb_round`` closes it (or
         ``abort_round`` on a failed fleet turn), so the span brackets
         dispatch through absorption — the engine's phase spans
-        (verify/draft/sync/reroot) plus ``absorb`` are its children."""
+        (verify/draft/sync/reroot) plus ``absorb`` are its children.  The
+        same interval is timed, tracer or not, into ``ServerStats.round_s``."""
+        self._round_t0 = monotonic()
+        self._round_sync0 = self.spec_stats.sync_s
         self._round_span = self.tracer.begin("round", self.track)
         try:
             depth = None
@@ -295,6 +301,8 @@ class EngineStepper:
                     if act.done:
                         self._retire(slot, act, now)
             self._m_rounds.inc()
+            self.stats.on_round_time(monotonic() - self._round_t0,
+                                     self.spec_stats.sync_s - self._round_sync0)
         finally:
             self._round_span.end()
             self._round_span = NOOP_SPAN

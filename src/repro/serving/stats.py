@@ -127,6 +127,10 @@ class ServerStats:
         self.queue_depth_samples: list[int] = []
         self.started_s: float = 0.0
         self.finished_s: float = 0.0
+        # always-on host time of the stepper's rounds (``round`` span
+        # interval: EngineStepper.step to the end of absorb_round)
+        self.round_s = 0.0  # summed over rounds
+        self.round_max_s = 0.0  # the longest round's host time outside its sync
 
     # ---- runtime hooks ---------------------------------------------------
     def on_admit(self, rid: int, slot: int, arrival_s: float, now: float,
@@ -142,6 +146,12 @@ class ServerStats:
         self.rounds += 1
         self.occupancy_samples.append(occupied)
         self.queue_depth_samples.append(queue_depth)
+
+    def on_round_time(self, wall_s: float, sync_s: float) -> None:
+        """One round took ``wall_s`` host seconds, ``sync_s`` of them blocked
+        in its designated sync."""
+        self.round_s += wall_s
+        self.round_max_s = max(self.round_max_s, wall_s - sync_s)
 
     def on_tokens(self, rid: int, n_new: int, n_accepted: int, now: float) -> None:
         r = self.records[rid]

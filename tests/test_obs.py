@@ -79,12 +79,12 @@ def test_chrome_and_jsonl_export():
     tr = Tracer(clock=ft)
     with tr.span("round", "replica0"):
         ft.advance(0.002)
-    tr.instant("evt", "router")
     tr.counter("queue_depth", 3)
+    tr.counter("occupied", 1, "router")
     doc = tr.to_chrome()
     evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
-    assert {"round", "evt", "queue_depth", "thread_name"} <= names
+    assert {"round", "occupied", "queue_depth", "thread_name"} <= names
     x = next(e for e in evs if e["ph"] == "X")
     assert x["ts"] == pytest.approx(0.0) and x["dur"] == pytest.approx(2000.0)
     meta = {e["args"]["name"]: e["tid"] for e in evs if e["ph"] == "M"}
@@ -130,7 +130,7 @@ def test_disabled_tracer_noop_singleton_zero_allocation():
         with tr.span("absorb", "replica0"):
             pass
         tr.counter("queue_depth", 1)
-        tr.instant("evt")
+        tr.counter("occupied", 1, "router")
         s.set("k", 1)
         s.end()
 
