@@ -238,16 +238,22 @@ class SpecEngine:
                 "the lookahead pipeline IS the parallel overlap")
 
         # ----- jitted draft-side steps ------------------------------------
-        def expand(dparams, tr, dcache):
+        def leaves(tr):
             leaf_ids, leaf_valid = jax.vmap(lambda t: T.select_leaves(t, c.w))(tr)
             tokens, rows, positions, mask, _ = jax.vmap(
                 lambda t, li, lv: T.leaf_inputs(t, li, lv, S_max_d, draft.cfg.sliding_window)
             )(tr, leaf_ids, leaf_valid)
-            logits, dcache = draft.spec_forward(dparams, dcache, tokens, positions, rows, mask)
+            return leaf_ids, leaf_valid, tokens, rows, positions, mask
+
+        def grow(tr, leaf_ids, leaf_valid, rows, logits):
             lp = jax.nn.log_softmax(logits.astype(jnp.float32))
             top_lp, top_tok = jax.lax.top_k(lp, c.c)  # [B,w,c]
-            tr = jax.vmap(T.insert_children)(tr, leaf_ids, leaf_valid, rows, top_tok, top_lp)
-            return tr, dcache
+            return jax.vmap(T.insert_children)(tr, leaf_ids, leaf_valid, rows, top_tok, top_lp)
+
+        def expand(dparams, tr, dcache):
+            leaf_ids, leaf_valid, tokens, rows, positions, mask = leaves(tr)
+            logits, dcache = draft.spec_forward(dparams, dcache, tokens, positions, rows, mask)
+            return grow(tr, leaf_ids, leaf_valid, rows, logits), dcache
 
         def select_plan(tr):
             return jax.vmap(lambda t: T.select_batch(t, c.bs, S_max_t, window))(tr)
@@ -255,10 +261,11 @@ class SpecEngine:
         # the re-root is three separately-dispatched programs so the host can
         # put a `kv_move` tracer span around exactly the cache-reorganization
         # dispatch (the cost the fused kernels attack):
-        #   reroot   — tree bookkeeping; emits the MovePlan + FillPlan
-        #   kv_move  — apply the MovePlan to the draft cache (donating on the
-        #              committed path, snapshot-preserving on the lookahead)
-        #   fill     — forward pass for accepted-but-unexpanded prefix KV
+        #   reroot       — tree bookkeeping; emits the MovePlan + FillPlan
+        #   kv_move      — apply the MovePlan to the draft cache (donating on
+        #                  the committed path, snapshot-preserving on the
+        #                  lookahead)
+        #   fill_prefix  — prefix fill and the first regrowth expansion
         def reroot(tr, node_ids, acc_pos, n_acc, bonus):
             return jax.vmap(T.reroot)(tr, node_ids, acc_pos, n_acc, bonus)
 
@@ -266,14 +273,34 @@ class SpecEngine:
             dcache = kvm.apply_moves(dcache, src, dst, mask, donate=donate)
             return kvm.set_length(dcache, 0)  # length bookkeeping via tree.plen
 
-        def fill_prefix(dparams, dcache, fill):
-            # fill missing prefix KV (accepted-but-unexpanded tokens)
+        def fill_prefix(dparams, tr, dcache, fill):
+            """The re-rooted tree's prefix fill AND its first growth expansion,
+            in one draft forward over the w leaf slots followed by the F fill
+            slots (accepted-but-unexpanded tokens, causal over the prefix).
+            The forward writes every new K/V row before it attends, and the
+            fill rows lie in [plen_old, plen-1), inside the leaves' prefix
+            mask, so the leaves see them as they would after a separate
+            fill; fill, root and fresh tree rows never overlap.  Draft
+            weights are read once per re-root instead of twice.
+
+            The leaves go first for a capacity-dropping MoE draft: its
+            experts serve a call's tokens in slot order, so at B=1 the leaves
+            keep every expert slot they would hold alone (the call's capacity
+            only grows with its F extra tokens), and the fill slots, mostly
+            padding, queue behind them.  At B>1 the rows queue in batch
+            order, so a row's leaves also wait behind earlier rows' fill
+            slots."""
+            leaf_ids, leaf_valid, tokens, rows, positions, mask = leaves(tr)
             cols = jnp.arange(S_max_d, dtype=jnp.int32)
             fmask = (cols[None, None, :] <= fill.rows[:, :, None]) & fill.mask[:, :, None]
-            _, dcache = draft.spec_forward(
-                dparams, dcache, fill.tokens, fill.positions, fill.rows, fmask
-            )
-            return dcache
+
+            def cat(x, f):
+                return jnp.concatenate([x, f], axis=1)
+
+            logits, dcache = draft.spec_forward(
+                dparams, dcache, cat(tokens, fill.tokens), cat(positions, fill.positions),
+                cat(rows, fill.rows), cat(mask, fmask))
+            return grow(tr, leaf_ids, leaf_valid, rows, logits[:, :c.w]), dcache
 
         def seed(tr, root_tok, plen, root_logits):
             return jax.vmap(lambda t, tok, lg: T.seed_root(t, tok, plen, lg, c.c))(
@@ -310,7 +337,7 @@ class SpecEngine:
         # non-donating kv_move routes to the snapshot-preserving kernel)
         self._spec_reroot = jax.jit(reroot)
         self._spec_kv_move = jax.jit(functools.partial(kv_move, donate=False))
-        self._fill = jax.jit(fill_prefix, donate_argnums=(1,))
+        self._fill_grow = jax.jit(fill_prefix, donate_argnums=(1, 2))
         self._predict = jax.jit(jax.vmap(T.predict_accept))
         self._seed = jax.jit(seed, static_argnums=(2,))
         self._verify = jax.jit(verify, donate_argnums=(1,))
@@ -651,14 +678,33 @@ class EngineSession:
         self.stats.ran("jit_compact")
         return acc_pos, n_acc, bonus, emitted, n_emitted, tcache
 
-    def _count_reroot(self, n_grow: int) -> None:
-        """Count one re-root: tree bookkeeping, the draft KV move (a
-        ``functools.partial``, which the trace names ``jit__unknown``), the
-        prefix fill, ``n_grow`` expansions and the next plan."""
+    def _reroot_grow(self, programs, tr, dcache, node_ids, outcome, n_grow: int):
+        """The re-root tail of every round, on the draft group: re-root
+        ``tr`` on the verify ``outcome`` (acc_pos, n_acc, bonus, already on
+        the draft group) of the batch ``node_ids``, move the draft KV under
+        a ``kv_move`` span, fill the prefix and grow the first level in one
+        program (``_fill_grow``), grow ``n_grow - 1`` more levels and select
+        the next plan.  ``programs`` is the (reroot, kv_move) pair: the
+        donating one on the committed path, the snapshot-preserving one on
+        the lookahead.  Returns (tr', dcache', plan') and counts the
+        dispatches: one ``jit_fill_prefix`` per re-root and ``n_grow - 1``
+        ``jit_expand`` (the KV move, a ``functools.partial``, is
+        ``jit__unknown``)."""
+        eng = self.engine
+        reroot, kv_move = programs
+        with _serving(eng.mesh_draft):
+            tr, move, fillp = reroot(tr, node_ids, *outcome)
+            with self.tracer.span("kv_move", self.track):
+                dcache = kv_move(dcache, move.src, move.dst, move.mask)
+            tr, dcache = eng._fill_grow(self.dparams, tr, dcache, fillp)
+            for _ in range(n_grow - 1):
+                tr, dcache = eng._expand(self.dparams, tr, dcache)
+            plan = eng._select_plan(tr)
         st = self.stats
         for program in ("jit_reroot", "jit__unknown", "jit_fill_prefix", "jit_select_plan"):
             st.ran(program)
-        st.ran("jit_expand", n_grow)
+        st.ran("jit_expand", n_grow - 1)
+        return tr, dcache, plan
 
     # ------------------------------------------------------------------
     # the round, lockstep
@@ -717,18 +763,11 @@ class EngineSession:
             obs, track, self.stats, (emitted, n_emitted, n_acc))
         # --- re-root, fill, grow, select next batch (draft group) ----------
         with obs.span("reroot_grow", track):
-            with _serving(eng.mesh_draft):
-                tr, move, fillp = eng._reroot(
-                    tr, plan.node_ids, *_to(eng.mesh_draft, (acc_pos, n_acc, bonus)))
-                with obs.span("kv_move", track):
-                    dcache = eng._kv_move(dcache, move.src, move.dst, move.mask)
-                dcache = eng._fill(self.dparams, dcache, fillp)
-                n_grow = d_eff if c.mode == "serial" else eng.grow_per_round
-                for _ in range(n_grow):
-                    tr, dcache = eng._expand(self.dparams, tr, dcache)
-                draft_steps += n_grow
-                new_plan = eng._select_plan(tr)
-            self._count_reroot(n_grow)
+            n_grow = d_eff if c.mode == "serial" else eng.grow_per_round
+            tr, dcache, new_plan = self._reroot_grow(
+                (eng._reroot, eng._kv_move), tr, dcache, plan.node_ids,
+                _to(eng.mesh_draft, (acc_pos, n_acc, bonus)), n_grow)
+            draft_steps += n_grow
         self.state = EngineState(tcache, dcache, tr, new_plan)
         if stats is not None:
             stats.add_round(n_emitted_h, n_acc_h)
@@ -785,20 +824,14 @@ class EngineSession:
                 rif.snapshot = (tr, dcache)
                 rif.pred = eng._predict(
                     tr, rif.plan.node_ids, rif.plan.parent_pos, rif.plan.valid)
-                pred_acc, pred_n, pred_bonus = rif.pred
-                la_tr, move, fillp = eng._spec_reroot(
-                    tr, rif.plan.node_ids, pred_acc, pred_n, pred_bonus)
-                with self.tracer.span("kv_move", self.track):
-                    # snapshot-preserving move: dcache stays alive for rollback
-                    la_dcache = eng._spec_kv_move(dcache, move.src, move.dst, move.mask)
-                la_dcache = eng._fill(self.dparams, la_dcache, fillp)
-                for _ in range(eng.grow_per_round):
-                    la_tr, la_dcache = eng._expand(self.dparams, la_tr, la_dcache)
-                rif.draft_steps += eng.grow_per_round
-                rif.lookahead = (la_tr, la_dcache, eng._select_plan(la_tr))
+            # snapshot-preserving re-root and move: (tr, dcache) stays alive
+            # for rollback
+            rif.lookahead = self._reroot_grow(
+                (eng._spec_reroot, eng._spec_kv_move), tr, dcache, rif.plan.node_ids,
+                rif.pred, eng.grow_per_round)
+            rif.draft_steps += eng.grow_per_round
             self.stats.ran("jit_expand", d_eff)
             self.stats.ran("jit_predict_accept")
-            self._count_reroot(eng.grow_per_round)
         return rif
 
     def reconcile(self, rif: RoundInFlight, stats: SpecStats | None = None,
@@ -834,19 +867,11 @@ class EngineSession:
                 stats.spec_commits += 1
         else:
             with obs.span("reconcile", track):
-                with _serving(eng.mesh_draft):
-                    tr, dcache = rif.snapshot
-                    tr, move, fillp = eng._reroot(
-                        tr, rif.plan.node_ids, *_to(eng.mesh_draft, (acc_pos, n_acc, bonus)))
-                    with obs.span("kv_move", track):
-                        # actual-path move consumes the snapshot (donating)
-                        dcache = eng._kv_move(dcache, move.src, move.dst, move.mask)
-                    dcache = eng._fill(self.dparams, dcache, fillp)
-                    for _ in range(eng.grow_per_round):
-                        tr, dcache = eng._expand(self.dparams, tr, dcache)
-                    draft_steps += eng.grow_per_round
-                    new_plan = eng._select_plan(tr)
-                self._count_reroot(eng.grow_per_round)
+                # the actual-path re-root consumes the snapshot (donating)
+                tr, dcache, new_plan = self._reroot_grow(
+                    (eng._reroot, eng._kv_move), *rif.snapshot, rif.plan.node_ids,
+                    _to(eng.mesh_draft, (acc_pos, n_acc, bonus)), eng.grow_per_round)
+                draft_steps += eng.grow_per_round
         self.state = EngineState(rif.tcache, dcache, tr, new_plan)
         self._inflight = None
         if stats is not None:

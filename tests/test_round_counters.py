@@ -18,14 +18,19 @@ def _prompt(k, P=8):
     return ((np.arange(1, P + 1) * k + 3) % 128).astype(np.int32)
 
 
+# engine variants: the two round paths, one whose re-root grows two levels
+# (grow_per_round 2) and the serial mode, whose re-root grows all d levels
+VARIANTS = {"lockstep": {}, "async": dict(async_rounds=True),
+            "async-bs16": dict(async_rounds=True, bs=16), "serial": dict(mode="serial")}
+
+
 @pytest.fixture(scope="module")
 def engines(dense_pair):
-    """Lockstep and async engines over an independent draft, so the async
-    lookahead is rolled back."""
+    """The engine variants over an independent draft, so the async lookahead
+    is rolled back."""
     T, D, tp, dp = dense_pair
-    return {mode: SpecEngine(T, D, SpecConfig(**CFG, async_rounds=mode == "async"),
-                             S_max_t=256, S_max_d=256)
-            for mode in ("lockstep", "async")}, tp, dp
+    return {name: SpecEngine(T, D, SpecConfig(**{**CFG, **kw}), S_max_t=256, S_max_d=256)
+            for name, kw in VARIANTS.items()}, tp, dp
 
 
 def _serve(engines, mode, tracer=None):
@@ -39,7 +44,7 @@ def _serve(engines, mode, tracer=None):
     return rt
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("mode", list(VARIANTS))
 def test_every_round_dispatches_one_verify(engines, mode):
     rt = _serve(engines, mode)
     spec, n = rt.stepper.spec_stats, rt.stats.rounds
@@ -48,14 +53,25 @@ def test_every_round_dispatches_one_verify(engines, mode):
     # four admissions, four retirements
     assert spec.dispatches["jit_target_prefill"] == spec.dispatches["jit_draft_prefill"] == 4
     assert spec.dispatches["jit_install_slot"] == spec.dispatches["jit_zero_slot"] == 8
-    if mode == "async":
+    eng = engines[0][mode]
+    if eng.cfg.async_rounds:
         # a speculative re-root every round, a second on each rolled-back one
         rollbacks = spec.spec_rounds - spec.spec_commits
         assert rollbacks > 0
         assert spec.dispatches["jit_predict_accept"] == n
-        assert spec.dispatches["jit_reroot"] == spec.dispatches["jit__unknown"] == n + rollbacks
+        reroots = n + rollbacks
     else:
-        assert spec.dispatches["jit_reroot"] == spec.dispatches["jit__unknown"] == n
+        reroots = n
+    # each re-root fills the prefix and grows the first level in one program
+    assert (spec.dispatches["jit_reroot"] == spec.dispatches["jit__unknown"]
+            == spec.dispatches["jit_fill_prefix"] == reroots)
+    # d per round in parallel mode, the rest of each re-root's growth (all d
+    # levels in serial mode), and each admission's
+    grow, d = eng.grow_per_round, eng.cfg.d
+    parallel = eng.cfg.mode == "parallel"
+    n_grow = grow if parallel else d
+    assert grow == (2 if eng.cfg.bs == 16 else 1)
+    assert spec.dispatches["jit_expand"] == (d * n if parallel else 0) + (n_grow - 1) * reroots + grow * 4
 
 
 @pytest.mark.parametrize("mode", ["lockstep", "async"])

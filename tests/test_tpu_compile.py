@@ -52,14 +52,16 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _tree_attention(dev):
-    cfg = TARGET  # verify: 8 tree rows against a 2048-row cache, GQA group 7
-    B, n, S = 1, 8, 2048
-    q = _sds((B, n, cfg.n_heads, cfg.head_dim), BF16, dev)
-    kv = _sds((B, S, cfg.n_kv_heads, cfg.head_dim), BF16, dev)
-    mask = _sds((B, n, S), jnp.bool_, dev)
-    fn = jax.jit(lambda q, k, v, m: ops.tree_attention(q, k, v, m, interpret=False))
-    return fn, (q, kv, kv, mask)
+def _tree_attention(cfg, n):
+    def build(dev):
+        B, S = 1, 2048
+        q = _sds((B, n, cfg.n_heads, cfg.head_dim), BF16, dev)
+        kv = _sds((B, S, cfg.n_kv_heads, cfg.head_dim), BF16, dev)
+        mask = _sds((B, n, S), jnp.bool_, dev)
+        fn = jax.jit(lambda q, k, v, m: ops.tree_attention(q, k, v, m, interpret=False))
+        return fn, (q, kv, kv, mask)
+
+    return build
 
 
 def _fused_swiglu(cfg):
@@ -102,7 +104,10 @@ def _slot_write_rows(dev):
 
 
 CASES = {
-    "tree_attention-33b-verify": _tree_attention,
+    # verify: 8 tree rows against a 2048-row cache, GQA group 7
+    "tree_attention-33b-verify": _tree_attention(TARGET, 8),
+    # the draft's fused prefix fill and first expansion: 8 fill rows + 4 leaves
+    "tree_attention-1.3b-fill-grow": _tree_attention(DRAFT, 12),
     "fused_swiglu-33b-7168x19200": _fused_swiglu(TARGET),
     "fused_swiglu-1.3b-2048x5504": _fused_swiglu(DRAFT),
     "kv_move_rows-donate": _kv_move_rows(True),
