@@ -1,9 +1,5 @@
 """Plain float32 references, one module per architecture, named by the
-configuration file's ``reference`` key.  They import nothing of the program."""
-
-import importlib
-
-
-def load(name: str):
-    """The reference module ``bench/reference/<name>.py``."""
-    return importlib.import_module(f"bench.reference.{name}")
+configuration file's ``reference`` key (the draft's by ``draft.reference``)
+and found by path under the benchmark root (``spec.load_reference``).  They
+import nothing of the program.  Each supplies ``hidden(weights, cfg, tokens,
+control=False)`` and ``head(h, weights, cfg, nxt, control=False)``."""

@@ -23,6 +23,15 @@ by name under ``bench/`` (``spec.py``).  The run:
   6. prints the end-to-end metrics (``--trace 0``) or, under the profiler, the
      per-layer metrics read by ``bench/metrics/<name>.py`` (``--trace 1``).
 
+Nothing here depends on the architecture.  Each model's architecture is
+named by the configuration (``reference``, and ``draft.reference`` where the
+draft's differs) and supplied by two modules found by that name under the
+benchmark root: ``layouts/<name>.py`` (sizes, the check that the program runs
+the configuration's model, the seeded layer weights, their mapping onto the
+program's parameter tree and shardings, the roofline counts) and
+``reference/<name>.py`` (the plain float32 forward).  So a model of another
+architecture is new files only.
+
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` are the last lines of standard error.  JAX's persistent
 compilation cache lives in ``JAX_COMPILATION_CACHE_DIR`` when set, else in
@@ -91,26 +100,36 @@ def enable_cache(root: Path) -> None:
 # the pair
 # ---------------------------------------------------------------------------
 
-_PROGRAM_KEYS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
-                 "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-                 "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-                 "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"}
-
-
 def as_run(model: dict, config: dict) -> dict:
     """A model's published numbers with the configuration's ``runs_as``
     departures of the program (the same for the target and the draft)."""
     return {**model, **config.get("runs_as", {})}
 
 
-def _same_model(label: str, hf: dict, mc) -> None:
-    """The program's ModelConfig must be the configuration file's model."""
-    for k, attr in _PROGRAM_KEYS.items():
-        if float(hf[k]) != float(getattr(mc, attr)):
-            raise ValueError(f"{label}: the configuration states {k}={hf[k]}, the program "
-                             f"would run {attr}={getattr(mc, attr)}")
-    if hf.get("rope_scaling") or hf.get("tie_word_embeddings"):
-        raise ValueError(f"{label}: the program has no rotary scaling and no tied head")
+@dataclasses.dataclass(frozen=True)
+class Model:
+    """One model of the pair: its published numbers as run, and the layout
+    and reference modules of its architecture."""
+
+    role: str
+    hf: dict
+    arch: str
+    layout: object  # bench/layouts/<arch>.py
+    ref: object  # bench/reference/<arch>.py
+
+
+def models(config: dict, root: Path = ROOT) -> tuple[Model, Model]:
+    """The target and the draft.  The configuration's ``reference`` names the
+    architecture of both; ``draft.reference``, where given, the draft's."""
+    from bench import spec
+
+    out = []
+    for role, hf, arch in (("target", config, config["reference"]),
+                           ("draft", config["draft"],
+                            config["draft"].get("reference", config["reference"]))):
+        out.append(Model(role, as_run(hf, config), arch, spec.load_layout(root, arch),
+                         spec.load_reference(root, arch)))
+    return tuple(out)
 
 
 @dataclasses.dataclass
@@ -118,48 +137,14 @@ class Pair:
     engine: object
     tparams: object
     dparams: object
-    tplain: dict  # the target's weights, in the reference's layout
+    tplain: dict  # the target's weights, in its reference's layout
     n_target: int
     n_draft: int
-    dplain: dict | None = None  # the draft's weights, in the reference's layout
+    dplain: dict | None = None  # the draft's weights, in its reference's layout
     pi: object = None  # the target's planted next-token map, i64[V]
 
 
-def _program_tree(plain: dict, like):
-    """The program's parameter tree over the benchmark's arrays (no copy)."""
-    from repro.sharding import Param
-
-    blk = like["groups"][0][0]
-    L = plain["layers"]
-    block = {"ln1": Param(L["ln1"], blk["ln1"].axes),
-             "attn": {k: Param(L[k], blk["attn"][k].axes) for k in ("wq", "wk", "wv", "wo")},
-             "ln2": Param(L["ln2"], blk["ln2"].axes),
-             "mlp": {k: Param(L[k], blk["mlp"][k].axes) for k in ("wg", "wu", "wd")}}
-    tree = {"embed": Param(plain["embed"], like["embed"].axes),
-            "final_norm": Param(plain["final_norm"], like["final_norm"].axes),
-            "lm_head": Param(plain["lm_head"], like["lm_head"].axes),
-            "groups": [(block,)], "shared_attn": None}
-    import jax
-
-    if jax.tree.structure(tree) != jax.tree.structure(like):
-        raise ValueError("the program's parameter tree changed; bench/run.py maps the "
-                         "benchmark's weights onto the old one")
-    return tree
-
-
-def _plain_shardings(mesh, like):
-    """NamedShardings of the program's layout, in the benchmark's layout."""
-    from repro.sharding import sharding_for_tree
-
-    sh = sharding_for_tree(mesh, like)
-    g = sh["groups"][0][0]
-    return {"embed": sh["embed"], "final_norm": sh["final_norm"], "lm_head": sh["lm_head"],
-            "layers": {"ln1": g["ln1"], "ln2": g["ln2"],
-                       **{k: g["attn"][k] for k in ("wq", "wk", "wv", "wo")},
-                       **{k: g["mlp"][k] for k in ("wg", "wu", "wd")}}}
-
-
-def build(config: dict, mix: dict):
+def build(config: dict, mix: dict, root: Path = ROOT):
     """The engine ``build_engine`` gives for the configuration (its own
     seeded weights are dropped: the benchmark makes its own)."""
     from bench.traffic.generate import cache_rows, max_output
@@ -168,8 +153,8 @@ def build(config: dict, mix: dict):
     prog = config["program"]
     cfgT, cfgD = serving_configs(prog["target"], prog["draft"], smoke=prog.get("smoke", False),
                                  target_layers=config["num_hidden_layers"], dtype=prog["dtype"])
-    _same_model("target", as_run(config, config), cfgT)
-    _same_model("draft", as_run(config["draft"], config), cfgD)
+    for m, mc in zip(models(config, root), (cfgT, cfgD)):
+        m.layout.same_model(m.role, m.hf, mc)
     eng, tp0, dp0, _ = build_engine(
         cfgT, cfgD, mode="parallel", bs=prog["bs"], w=prog["w"], c=prog["c"], d=prog["d"],
         max_new=max_output(mix), S_max=cache_rows(mix, prog["bs"]),
@@ -179,27 +164,34 @@ def build(config: dict, mix: dict):
     return eng
 
 
-def build_pair(eng, config: dict, seed: int) -> Pair:
-    """The benchmark's weights for ``seed`` on the engine's meshes."""
+def build_pair(eng, config: dict, seed: int, root: Path = ROOT) -> Pair:
+    """The benchmark's weights for ``seed`` on the engine's meshes: made in
+    each model's reference layout (``weights.make_fn``) with the program's
+    shardings, and handed to the program as its own tree without a copy."""
     import jax
     import numpy as np
 
     from bench import weights as W
+    from repro.sharding import sharding_for_tree
 
     prog, a = config["program"], config["assumed"]
     inv_t, inv_d, keep = W.plant_maps(config["vocab_size"], seed,
                                       disagree=1.0 - a["draft_agreement"], free=a["free_share"])
     out = []
-    for role, hf, model, mesh, inv, kp, stream in (
-            ("target", config, eng.target, eng.mesh_target, inv_t, keep, 10),
-            ("draft", config["draft"], eng.draft, eng.mesh_draft, inv_d, None, 11)):
+    for m, model, mesh, inv, kp, stream in zip(
+            models(config, root), (eng.target, eng.draft), (eng.mesh_target, eng.mesh_draft),
+            (inv_t, inv_d), (keep, None), (10, 11)):
         like = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        dims = W.Dims.of(hf)
-        fn = W.make_fn(dims, prog["dtype"], a["logit_scale"],
-                       out_shardings=_plain_shardings(mesh, like))
+        dims = m.layout.dims(m.hf)
+        fn = W.make_fn(m.layout, dims, prog["dtype"], a["logit_scale"],
+                       out_shardings=m.layout.plain_shardings(sharding_for_tree(mesh, like)))
         kp = kp if kp is not None else [1.0] * dims.vocab
         plain = fn(W.key_for(seed, stream), jax.numpy.asarray(inv), jax.numpy.asarray(kp))
-        out.append((plain, _program_tree(plain, like)))
+        tree = m.layout.program_tree(plain, like)
+        if jax.tree.structure(tree) != jax.tree.structure(like):
+            raise ValueError(f"{m.role}: bench/layouts/{m.arch}.py maps the benchmark's "
+                             f"weights onto another tree than the program's")
+        out.append((plain, tree))
     (tplain, tparams), (dplain, dparams) = out
     jax.block_until_ready((tparams, dparams))
     return Pair(eng, tparams, dparams, tplain, prog["n_target"], prog["n_draft"],
@@ -360,8 +352,8 @@ class RunData:
     server: object  # the program's ServerStats
     spec: object  # the program's SpecStats
     trace: dict | None  # xtrace.reduce of the traced run, else None
-    target: object  # roofline.Decoder of the target
-    draft: object  # roofline.Decoder of the draft
+    target: object  # the target's roofline (its layout's ``roofline``)
+    draft: object  # the draft's roofline
     n_target: int  # chips of the target (1 when colocated)
     n_draft: int  # chips of the draft (0 when colocated)
     peak: dict  # peaks.json entry of the device
@@ -377,7 +369,7 @@ class RunData:
 # ---------------------------------------------------------------------------
 
 def check(pair: Pair, config: dict, mix: dict, items, results: dict, seed: int,
-          control: bool = False) -> dict:
+          control: bool = False, root: Path = ROOT) -> dict:
     """Widest gap, over a seeded sample of finished requests (the longest
     among them), by which a served token's logit lies below the float32
     reference's best at its position.  ``control``: also the widest gap of
@@ -386,10 +378,10 @@ def check(pair: Pair, config: dict, mix: dict, items, results: dict, seed: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from bench.reference import load
     from bench.traffic.generate import cache_rows, rng
 
-    ref = load(config["reference"])
+    target, draft = models(config, root)
+    ref, tcfg = target.ref, target.hf
     by_rid = {it.rid: it for it in items}
     done = sorted(results, key=lambda rid: (-len(results[rid]), rid))
     k = int(mix["check_requests"])
@@ -411,7 +403,6 @@ def check(pair: Pair, config: dict, mix: dict, items, results: dict, seed: int,
         lo = len(prompt) - 1
         nxt[b, lo: lo + len(served)] = served
         valid[b, lo: lo + len(served)] = True
-    tcfg = as_run(config, config)
     jtoks, jnxt = jnp.asarray(toks), jnp.asarray(nxt)
     h = ref.hidden(pair.tplain, tcfg, jtoks)
     gap, top = ref.head(h, pair.tplain, tcfg, jnxt)
@@ -428,8 +419,8 @@ def check(pair: Pair, config: dict, mix: dict, items, results: dict, seed: int,
         out["control_max_gap"] = float(cgap.max()) if cgap.size else float("inf")
         out["control_differ"] = int((np.asarray(ctop)[valid] != nxt[valid]).sum())
     if pair.dplain is not None and valid.any():
-        dcfg = as_run(config["draft"], config)
-        _, dtop = ref.head(ref.hidden(pair.dplain, dcfg, jtoks), pair.dplain, dcfg, jnxt)
+        dref, dcfg = draft.ref, draft.hf
+        _, dtop = dref.head(dref.hidden(pair.dplain, dcfg, jtoks), pair.dplain, dcfg, jnxt)
         out["plant"] = plant_readings(np.asarray(dtop) == nxt, pair.pi[toks] == nxt, valid,
                                       depth=1 + config["program"]["d"])
     return out
@@ -474,8 +465,9 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool, *,
     kind = devs[0].device_kind
     peak = roofline.peaks(kind) if require_tpu else None
 
-    pair = build_pair(build(config, mix), config, seed)
-    log(f"bench: {workload} on {len(devs)} x {kind}; target {roofline.Decoder.of(config).params} "
+    target, draft = (m.layout.roofline(m.hf) for m in models(config, root))
+    pair = build_pair(build(config, mix, root), config, seed, root)
+    log(f"bench: {workload} on {len(devs)} x {kind}; target {target.params} "
         f"params over {pair.n_target} chip(s), draft on {pair.n_draft or 'the same'}")
     warm = warm_up(pair, mix, config["vocab_size"])
     log(f"bench: warm-up {warm}")
@@ -544,7 +536,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool, *,
             n += k
     data = RunData(cell=cell, seconds=seconds, run_s=run_s, idle_s=idle_s, reqs=reqs,
                    server=server, spec=spec_stats, trace=red,
-                   target=roofline.Decoder.of(config), draft=roofline.Decoder.of(config["draft"]),
+                   target=target, draft=draft,
                    n_target=pair.n_target, n_draft=pair.n_draft, peak=peak,
                    mean_plen=statistics.fmean(plens) if plens else 0.0)
     metrics = {}
@@ -560,7 +552,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool, *,
             if m["name"] in e2e:
                 metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
 
-    res = check(pair, config, mix, items, results, seed)
+    res = check(pair, config, mix, items, results, seed, root=root)
     correct = res["max_gap"] <= res["limit"] and res["short"] == 0 and res["requests"] > 0
     out = {"correct": bool(correct), "attempted": attempted, "failed": failed,
            "metrics": metrics, "device": device}

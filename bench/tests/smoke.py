@@ -1,5 +1,6 @@
 """A benchmark root at smoke size for tests on the CPU: the program's smoke
-shapes of the same pair, short mixes, and the real metric readers.  The
+shapes of the same pair, short mixes, and the real metric readers, layout
+modules and references.  The
 harness finds everything by name in it, as in the repository's own root."""
 
 from __future__ import annotations
@@ -51,7 +52,9 @@ def make_root(tmp: Path, bench: dict | None = None) -> Path:
     root = Path(tmp)
     (root / "bench" / "configs").mkdir(parents=True, exist_ok=True)
     (root / "bench" / "traffic").mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", root / "bench" / "metrics", dirs_exist_ok=True)
+    for sub in ("metrics", "layouts", "reference"):
+        shutil.copytree(BENCH / sub, root / "bench" / sub, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs" / "smoke.json").write_text(json.dumps(SMOKE_CONFIG))
     for name, mix in MIXES.items():
         (root / "bench" / "traffic" / f"{name}.json").write_text(json.dumps(mix))

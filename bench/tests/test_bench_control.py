@@ -15,15 +15,17 @@ import numpy as np
 import pytest
 
 from bench import run as R
+from bench import spec
 from bench import weights as W
 from bench.reference import llama as ref
-from bench.tests.smoke import LIMIT
+from bench.tests.smoke import BENCH, LIMIT
 from bench.traffic.generate import Item
 
 CONFIG = {"vocab_size": 32256, "hidden_size": 512, "num_hidden_layers": 24,
           "num_attention_heads": 8, "num_key_value_heads": 8, "intermediate_size": 1408,
           "rope_theta": 100000, "rms_norm_eps": 1e-5, "reference": "llama",
-          "check": LIMIT, "program": {"bs": 8}}
+          "check": LIMIT, "program": {"bs": 8}, "draft": {}}
+LAYOUT = spec.load_layout(BENCH.parent, "llama")
 REQUESTS, PROMPT, OUT = 4, 128, 112
 MIX = {"prompt_buckets": [PROMPT], "output": {"kind": "fixed", "tokens": OUT},
        "check_requests": REQUESTS}
@@ -59,7 +61,7 @@ def _greedy(weights, prompts, pi):
 def test_control_reads_above_the_limit(seed):
     V = CONFIG["vocab_size"]
     inv_t, _, keep = W.plant_maps(V, seed, disagree=0.2, free=0.1)
-    weights = W.make_fn(W.Dims.of(CONFIG), "bfloat16", 4.0)(
+    weights = W.make_fn(LAYOUT, LAYOUT.dims(CONFIG), "bfloat16", 4.0)(
         W.key_for(seed, 10), jnp.asarray(inv_t), jnp.asarray(keep))
     prompts = np.random.default_rng(seed).integers(0, V, (REQUESTS, PROMPT), dtype=np.int32)
     served = _greedy(weights, prompts, np.argsort(inv_t))

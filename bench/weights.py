@@ -1,5 +1,5 @@
-"""Seeded weights of a Llama-architecture decoder, with a planted next-token
-map, made on the device in one jitted call per model.
+"""Seeded weights of a decoder, with a planted next-token map, made on the
+device in one jitted call per model.
 
 Greedy verification pins the served tokens to target-only greedy decoding,
 so the draft's weights change no output; they only set how often the draft
@@ -20,15 +20,13 @@ are the positions where rounding can change a token.  The draft then agrees
 with the target on ``1 - |D|/V`` of the steps, spread evenly along every
 chain (``plant_maps``).
 
-The weights are a plain dict with the layer weights stacked on a leading axis:
-  embed [V, d], final_norm [d], lm_head [d, V],
-  layers: ln1 [L, d], wq [L, d, Hq, hd], wk/wv [L, d, Hkv, hd],
-          wo [L, Hq, hd, d], ln2 [L, d], wg/wu [L, d, F], wd [L, F, d]
+What is shared by every architecture is made here: embed [V, d],
+final_norm [d] and lm_head [d, V].  The model's layout module
+(``bench/layouts/<name>.py``) makes what sits between them, ``layers``, in
+the layout its reference reads, from ``KEYS`` seeded keys.
 """
 
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -38,24 +36,10 @@ from bench.traffic.generate import rng
 
 EMBED_STD = 1.0  # of the embedding's entries; the other layers' scales follow from it
 
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    vocab: int
-    d_model: int
-    n_layers: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
 
-    @classmethod
-    def of(cls, cfg: dict) -> "Dims":
-        """From a Hugging Face style configuration."""
-        return cls(vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
-                   n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-                   n_kv_heads=cfg["num_key_value_heads"],
-                   head_dim=cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"],
-                   d_ff=cfg["intermediate_size"])
+def normal(key, shape, std, dt):
+    """Entries drawn from N(0, std^2) in the weights' dtype."""
+    return jax.random.normal(key, shape, dt) * jnp.asarray(std, dt)
 
 
 def plant_maps(vocab: int, seed: int, disagree: float, free: float, block: int = 10):
@@ -87,32 +71,20 @@ def plant_maps(vocab: int, seed: int, disagree: float, free: float, block: int =
     return np.argsort(pi).astype(np.int32), np.argsort(pd).astype(np.int32), keep
 
 
-def make_fn(dims: Dims, dtype, logit_scale: float, out_shardings=None):
-    """The jitted ``f(key, inv_perm, keep) -> weights`` of one model."""
-    V, d, L = dims.vocab, dims.d_model, dims.n_layers
-    hq, hkv, hd, ff = dims.n_heads, dims.n_kv_heads, dims.head_dim, dims.d_ff
+def make_fn(layout, dims, dtype, logit_scale: float, out_shardings=None):
+    """The jitted ``f(key, inv_perm, keep) -> weights`` of one model of the
+    layout module ``layout``, whose ``dims`` give ``vocab`` and ``d_model``."""
+    V, d = dims.vocab, dims.d_model
     dt = jnp.dtype(dtype)
 
     def make(key, inv_perm, keep):
-        k = jax.random.split(key, 8)
-        normal = lambda kk, shape, std: (jax.random.normal(kk, shape, dt) * jnp.asarray(std, dt))  # noqa: E731
-        embed = normal(k[0], (V, d), EMBED_STD)
+        k = jax.random.split(key, 1 + layout.KEYS)
+        embed = normal(k[0], (V, d), EMBED_STD, dt)
         # unit-scale columns: the planted logit is a * |h| * cos(h, embed[x])
         a = logit_scale / (EMBED_STD * np.sqrt(d))
         lm_head = (embed[inv_perm].T.astype(jnp.float32) * (a * keep)[None, :]).astype(dt)
-        layers = {
-            "ln1": jnp.ones((L, d), dt),
-            "wq": normal(k[1], (L, d, hq, hd), d ** -0.5),
-            "wk": normal(k[2], (L, d, hkv, hd), d ** -0.5),
-            "wv": normal(k[3], (L, d, hkv, hd), d ** -0.5),
-            "wo": normal(k[4], (L, hq, hd, d), (hq * hd) ** -0.5),
-            "ln2": jnp.ones((L, d), dt),
-            "wg": normal(k[5], (L, d, ff), d ** -0.5),
-            "wu": normal(k[6], (L, d, ff), d ** -0.5),
-            "wd": normal(k[7], (L, ff, d), ff ** -0.5),
-        }
         return {"embed": embed, "final_norm": jnp.ones((d,), dt), "lm_head": lm_head,
-                "layers": layers}
+                "layers": layout.layer_weights(k[1:], dims, dt)}
 
     return jax.jit(make, out_shardings=out_shardings)
 
