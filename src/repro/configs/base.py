@@ -13,6 +13,43 @@ from dataclasses import dataclass, field, replace
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071), in DeepSeek-V2's form: every
+    rotary frequency between the correction range's ends is mixed linearly
+    from theta^(-2i/dim) towards that over ``factor``; cos/sin are scaled by
+    ``mscale(mscale) / mscale(mscale_all_dim)`` and the attention's softmax
+    scale by ``mscale(mscale_all_dim)**2``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _mscale(self, m: float) -> float:
+        return 1.0 if self.factor <= 1 else 0.1 * m * math.log(self.factor) + 1.0
+
+    def correction_range(self, dim: int, theta: float) -> tuple[int, int]:
+        """(low, high): frequency indices below ``low`` keep their frequency,
+        those above ``high`` are divided by ``factor``."""
+
+        def at(rotations):
+            return dim * math.log(self.original_max_position_embeddings
+                                  / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+        return max(math.floor(at(self.beta_fast)), 0), min(math.ceil(at(self.beta_slow)), dim - 1)
+
+    @property
+    def cos_sin_factor(self) -> float:
+        return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
+
+    @property
+    def softmax_factor(self) -> float:
+        return self._mscale(self.mscale_all_dim) ** 2 if self.mscale_all_dim else 1.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     n_layers: int
@@ -35,14 +72,16 @@ class ModelConfig:
     attn_kind: str = "gqa"  # gqa | mla | none
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    yarn: YarnScaling | None = None  # None: unscaled rotary frequencies
     sliding_window: int = 0  # 0 = full attention
 
     # MoE
     n_experts: int = 0
     n_shared_experts: int = 0
     moe_top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = math.inf  # experts have no capacity: the dispatch is dropless
     moe_d_ff: int = 0  # per-expert ff (deepseek fine-grained); 0 -> d_ff
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to one
 
     # MLA (minicpm3 / deepseek-v2 style)
     q_lora_rank: int = 0
@@ -147,10 +186,14 @@ class ModelConfig:
         d, hd = self.d_model, self.head_dim
         if self.attn_kind == "mla":
             qk = self.nope_head_dim + self.rope_head_dim
+            if self.q_lora_rank:  # w_dq, q_norm, w_uq
+                q = d * self.q_lora_rank + self.q_lora_rank + self.q_lora_rank * self.n_heads * qk
+            else:  # w_q
+                q = d * self.n_heads * qk
             return (
-                d * self.q_lora_rank
-                + self.q_lora_rank * self.n_heads * qk
+                q
                 + d * (self.kv_lora_rank + self.rope_head_dim)
+                + self.kv_lora_rank  # kv_norm
                 + self.kv_lora_rank * self.n_heads * (self.nope_head_dim + self.v_head_dim)
                 + self.n_heads * self.v_head_dim * d
             )

@@ -38,6 +38,5 @@ def smoke_config() -> ModelConfig:
         n_shared_experts=2,
         moe_top_k=3,
         moe_d_ff=96,
-        capacity_factor=8.0,  # drop-free for exact-match smoke tests
         family="moe",
     )

@@ -33,6 +33,5 @@ def smoke_config() -> ModelConfig:
         n_experts=4,
         moe_top_k=2,
         sliding_window=32,
-        capacity_factor=8.0,  # drop-free for exact-match smoke tests
         family="moe",
     )

@@ -67,6 +67,12 @@ class SpecStats:
     # name the device trace gives the program
     sync_s: float = 0.0
     dispatches: dict = dataclasses.field(default_factory=dict)
+    # MoE targets: the MoE layers the verify calls ran (``moe_layer_calls``),
+    # and, summed over them, the routed experts that received at least one
+    # live tree token, held on the device by the verify program itself
+    # (``touched``) and fetched only when ``experts_touched`` is read
+    moe_layer_calls: int = 0
+    touched: Any = None
 
     def add_round(self, n_emitted, n_accepted):
         n_emitted = np.asarray(n_emitted, np.int64)
@@ -80,6 +86,10 @@ class SpecStats:
     def ran(self, program: str, n: int = 1) -> None:
         """Count ``n`` dispatches of the jitted ``program``."""
         self.dispatches[program] = self.dispatches.get(program, 0) + n
+
+    @property
+    def experts_touched(self) -> int:
+        return 0 if self.touched is None else int(jax.device_get(self.touched))
 
     @property
     def emitted(self) -> float:
@@ -281,15 +291,10 @@ class SpecEngine:
             fill rows lie in [plen_old, plen-1), inside the leaves' prefix
             mask, so the leaves see them as they would after a separate
             fill; fill, root and fresh tree rows never overlap.  Draft
-            weights are read once per re-root instead of twice.
-
-            The leaves go first for a capacity-dropping MoE draft: its
-            experts serve a call's tokens in slot order, so at B=1 the leaves
-            keep every expert slot they would hold alone (the call's capacity
-            only grows with its F extra tokens), and the fill slots, mostly
-            padding, queue behind them.  At B>1 the rows queue in batch
-            order, so a row's leaves also wait behind earlier rows' fill
-            slots."""
+            weights are read once per re-root instead of twice.  A MoE
+            draft's dispatch is dropless and the padding fill slots route
+            nowhere, so the leaves' logits are what a plain expansion
+            gives."""
             leaf_ids, leaf_valid, tokens, rows, positions, mask = leaves(tr)
             cols = jnp.arange(S_max_d, dtype=jnp.int32)
             fmask = (cols[None, None, :] <= fill.rows[:, :, None]) & fill.mask[:, :, None]
@@ -308,8 +313,7 @@ class SpecEngine:
             )
 
         # ----- jitted target-side steps -------------------------------------
-        def verify(tparams, tcache, tokens, positions, rows, mask, parent_pos, valid):
-            logits, tcache = self.target.spec_forward(tparams, tcache, tokens, positions, rows, mask)
+        def verify_walk(logits, tokens, rows, parent_pos, valid):
             argmax = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             acc_pos, n_acc, bonus, emitted, n_emitted = jax.vmap(T.verify_walk)(
                 tokens, parent_pos, valid, argmax
@@ -322,7 +326,23 @@ class SpecEngine:
             src = jnp.where(acc_pos >= 0, jnp.take_along_axis(rows, jnp.maximum(acc_pos, 0), axis=1), -1)
             dst = plen[:, None] + jnp.arange(bs, dtype=jnp.int32)[None, :]
             mmask = (jnp.arange(bs)[None, :] < n_acc[:, None]) & (src >= 0)
-            return acc_pos, n_acc, bonus, emitted, n_emitted, tcache, (src, dst, mmask)
+            return acc_pos, n_acc, bonus, emitted, n_emitted, (src, dst, mmask)
+
+        if target.n_moe_layers:
+            def verify(tparams, tcache, tokens, positions, rows, mask, parent_pos, valid,
+                       touched):
+                """Also advances ``touched``, the running count of routed
+                experts that live tree tokens reached (``SpecStats.touched``)."""
+                logits, tcache, n = self.target.spec_forward(
+                    tparams, tcache, tokens, positions, rows, mask, count_experts=True)
+                *out, mv = verify_walk(logits, tokens, rows, parent_pos, valid)
+                return (*out, tcache, mv, touched + n)
+        else:
+            def verify(tparams, tcache, tokens, positions, rows, mask, parent_pos, valid):
+                logits, tcache = self.target.spec_forward(
+                    tparams, tcache, tokens, positions, rows, mask)
+                *out, mv = verify_walk(logits, tokens, rows, parent_pos, valid)
+                return (*out, tcache, mv)
 
         def compact(tcache, src, dst, mask):
             return kvm.apply_moves(tcache, src, dst, mask, donate=True)
@@ -529,7 +549,8 @@ class SpecEngine:
             nonlocal tcache
             with _serving(self.mesh_target):
                 out = self._verify(tparams, tcache, plan.tokens, plan.positions,
-                                   plan.rows, plan.mask, plan.parent_pos, plan.valid)
+                                   plan.rows, plan.mask, plan.parent_pos, plan.valid,
+                                   *self._touched0())
                 tcache = self._compact(out[5], *out[6])
                 jax.block_until_ready(out[0])
 
@@ -543,6 +564,12 @@ class SpecEngine:
             target_once()
         t_t = (monotonic() - t0) / iters
         return ProfileResult(t_draft_s=t_d, t_target_s=t_t)
+
+    def _touched0(self) -> tuple:
+        """The verify program's expert-count argument before its first call:
+        a zero for a target with MoE layers, else none (its verify counts
+        nothing)."""
+        return (np.zeros((), np.int32),) if self.target.n_moe_layers else ()
 
     def _bypass(self, plan):
         """Straggler mitigation: degenerate to root-only verification."""
@@ -665,17 +692,21 @@ class EngineSession:
         """Enqueue verification of ``plan`` on the target group, then the
         target cache compaction; returns (acc_pos, n_acc, bonus, emitted,
         n_emitted, tcache') as target-side device futures."""
-        eng = self.engine
+        eng, st = self.engine, self.stats
         plan = _to(eng.mesh_target, plan)
+        touched = (st.touched,) if st.touched is not None else eng._touched0()
         with _serving(eng.mesh_target):
-            acc_pos, n_acc, bonus, emitted, n_emitted, tcache, mv = eng._verify(
+            acc_pos, n_acc, bonus, emitted, n_emitted, tcache, mv, *touched = eng._verify(
                 self.tparams, tcache, plan.tokens, plan.positions, plan.rows,
-                plan.mask, plan.parent_pos, plan.valid,
+                plan.mask, plan.parent_pos, plan.valid, *touched,
             )
             with self.tracer.span("kv_move", self.track):
                 tcache = eng._compact(tcache, *mv)
-        self.stats.ran("jit_verify")
-        self.stats.ran("jit_compact")
+        st.ran("jit_verify")
+        st.ran("jit_compact")
+        if touched:
+            st.touched = touched[0]
+            st.moe_layer_calls += eng.target.n_moe_layers
         return acc_pos, n_acc, bonus, emitted, n_emitted, tcache
 
     def _reroot_grow(self, programs, tr, dcache, node_ids, outcome, n_grow: int):

@@ -53,7 +53,7 @@ class Model:
         B, S, _ = h.shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         ctx = Ctx(mode="full", positions=positions, enc=enc)
-        h, _ = apply_model(self.cfg, params, h, ctx, cache=None)
+        h, _, _ = apply_model(self.cfg, params, h, ctx, cache=None)
         return logits_from_hidden(self.cfg, params, h)
 
     # ---- serving -----------------------------------------------------------
@@ -64,19 +64,29 @@ class Model:
         S_max = S_max or S
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         ctx = Ctx(mode="full", make_cache=S_max, positions=positions, enc=enc)
-        h, cache = apply_model(self.cfg, params, h, ctx, cache=None)
+        h, cache, _ = apply_model(self.cfg, params, h, ctx, cache=None)
         cache["len"] = jnp.full((), S, jnp.int32)
         return logits_from_hidden(self.cfg, params, h), cache
 
-    def spec_forward(self, params, cache, tokens, positions, row_idx, attn_mask):
+    def spec_forward(self, params, cache, tokens, positions, row_idx, attn_mask, *,
+                     count_experts=False):
         """Tree-structured forward: K/V written at ``row_idx``, attention under
         the non-square ``attn_mask`` [B,n,S_max]. ``cache['len']`` unchanged —
-        the engine owns length bookkeeping (core/kv.py)."""
+        the engine owns length bookkeeping (core/kv.py).  Returns (logits,
+        cache'), and with ``count_experts`` also the routed experts that the
+        live rows (``row_idx >= 0``) reached, summed over the MoE layers."""
         h = self._embed(params, tokens)
         ctx = Ctx(mode="cached", positions=positions, row_idx=row_idx, attn_mask=attn_mask)
-        h, nc = apply_model(self.cfg, params, h, ctx, cache=cache)
+        h, nc, touched = apply_model(self.cfg, params, h, ctx, cache=cache)
         nc["len"] = cache["len"]
-        return logits_from_hidden(self.cfg, params, h), nc
+        logits = logits_from_hidden(self.cfg, params, h)
+        if count_experts:
+            return logits, nc, jnp.asarray(touched, jnp.int32)
+        return logits, nc
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.cfg.layer_kinds.count("moe")
 
     def chain_forward(self, params, cache, tokens, n_commit, S_max):
         """Chain-mode forward of n tokens starting at cache['len'].
@@ -104,7 +114,7 @@ class Model:
             commit_mask=commit,
             row_start=start,  # contiguous rows: dynamic_update_slice fast path
         )
-        h, nc = apply_model(self.cfg, params, h, ctx, cache=cache)
+        h, nc, _ = apply_model(self.cfg, params, h, ctx, cache=cache)
         nc["len"] = start + jnp.asarray(n_commit, jnp.int32)
         return logits_from_hidden(self.cfg, params, h), nc
 

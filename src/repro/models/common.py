@@ -32,18 +32,29 @@ def rms_norm(x, weight, eps: float):
     return (out * weight.astype(jnp.float32)).astype(dt)
 
 
-def rope_frequencies(head_dim: int, theta: float):
+def rope_frequencies(head_dim: int, theta: float, yarn=None):
+    """theta^(-2i/head_dim) for i < head_dim/2; with ``yarn``
+    (``configs.base.YarnScaling``) each frequency past the correction
+    range's low end is mixed linearly towards its value over the factor,
+    wholly so past the high end."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        return freqs
+    low, high = yarn.correction_range(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float):
+def apply_rope(x, positions, theta: float, yarn=None):
     """Rotary embedding. x: [..., seq, heads, head_dim]; positions: [..., seq]."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)  # [half]
+    freqs = rope_frequencies(head_dim, theta, yarn)  # [half]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, half]
     cos = jnp.cos(angles)[..., None, :]  # [..., seq, 1, half]
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None and yarn.cos_sin_factor != 1.0:
+        cos, sin = cos * yarn.cos_sin_factor, sin * yarn.cos_sin_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -1,5 +1,10 @@
 """Multi-head Latent Attention (MiniCPM3 / DeepSeek-V2 style).
 
+Queries come through a compressed latent (``w_dq -> q_norm -> w_uq``) when
+``q_lora_rank`` is set, else straight from one ``w_q`` (DeepSeek-V2-Lite).
+With YaRN (``cfg.yarn``) the rotary frequencies are scaled and the softmax
+scale is multiplied by its ``softmax_factor``.
+
 The KV cache stores only the compressed latent ``c_kv`` plus the shared
 rotary key ``k_rope`` — the MLA memory win.  Cached mode uses the *absorbed*
 formulation (W_uk folded into the query, W_uv applied after the probability-
@@ -22,10 +27,14 @@ def init_mla(cfg, key):
     nd, rd, vd = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 6)
     dt = jnp.dtype(cfg.param_dtype)
+    if ql:
+        q = {"w_dq": dense_init(ks[0], (d, ql), ("embed", "lora"), dt),
+             "q_norm": ones_init((ql,), ("lora",), dt),
+             "w_uq": dense_init(ks[1], (ql, H, nd + rd), ("lora", "heads", "qk_dim"), dt)}
+    else:
+        q = {"w_q": dense_init(ks[0], (d, H, nd + rd), ("embed", "heads", "qk_dim"), dt)}
     return {
-        "w_dq": dense_init(ks[0], (d, ql), ("embed", "lora"), dt),
-        "q_norm": ones_init((ql,), ("lora",), dt),
-        "w_uq": dense_init(ks[1], (ql, H, nd + rd), ("lora", "heads", "qk_dim"), dt),
+        **q,
         "w_dkv": dense_init(ks[2], (d, kvl + rd), ("embed", "lora"), dt),
         "kv_norm": ones_init((kvl,), ("lora",), dt),
         "w_uk": dense_init(ks[3], (kvl, H, nd), ("lora", "heads", "qk_dim"), dt),
@@ -35,11 +44,14 @@ def init_mla(cfg, key):
 
 
 def _queries(cfg, p, x, positions):
-    nd, rd = cfg.nope_head_dim, cfg.rope_head_dim
-    q_lat = rms_norm(x @ p["w_dq"].value, p["q_norm"].value, cfg.norm_eps)
-    q = jnp.einsum("bsl,lhk->bshk", q_lat, p["w_uq"].value)  # [B,S,H,nd+rd]
+    nd = cfg.nope_head_dim
+    if cfg.q_lora_rank:
+        q_lat = rms_norm(x @ p["w_dq"].value, p["q_norm"].value, cfg.norm_eps)
+        q = jnp.einsum("bsl,lhk->bshk", q_lat, p["w_uq"].value)  # [B,S,H,nd+rd]
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"].value)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
     return q_nope, q_rope
 
 
@@ -47,8 +59,13 @@ def _latents(cfg, p, x, positions):
     kvl, rd = cfg.kv_lora_rank, cfg.rope_head_dim
     lat = x @ p["w_dkv"].value  # [B,S,kvl+rd]
     c_kv = rms_norm(lat[..., :kvl], p["kv_norm"].value, cfg.norm_eps)
-    k_rope = apply_rope(lat[..., None, kvl:], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = apply_rope(lat[..., None, kvl:], positions, cfg.rope_theta, cfg.yarn)[..., 0, :]
     return c_kv, k_rope
+
+
+def _softmax_scale(cfg):
+    scale = 1.0 / jnp.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    return scale if cfg.yarn is None else scale * cfg.yarn.softmax_factor
 
 
 def mla_full(cfg, p, x, positions):
@@ -59,14 +76,14 @@ def mla_full(cfg, p, x, positions):
     from repro.flags import get_flags
 
     B, S, _ = x.shape
-    nd, rd, vd = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
+    vd = cfg.v_head_dim
     q_nope, q_rope = _queries(cfg, p, x, positions)
     c_kv, k_rope = _latents(cfg, p, x, positions)
     k_nope = jnp.einsum("bsl,lhk->bshk", c_kv, p["w_uk"].value)
     v = jnp.einsum("bsl,lhk->bshk", c_kv, p["w_uv"].value)
     k_nope = constrain(k_nope, "batch", "seq", "heads", "qk_dim")
     v = constrain(v, "batch", "seq", "heads", "head_dim")
-    scale = 1.0 / jnp.sqrt(nd + rd)
+    scale = _softmax_scale(cfg)
 
     chunk = min(get_flags().attn_chunk, S)
     while S % chunk:
@@ -101,7 +118,6 @@ def mla_cached(cfg, p, x, cache_ckv, cache_krope, row_idx, positions, attn_mask,
     """Cached MLA (decode / spec tree), absorbed form. Returns (out, ckv', krope')."""
     from repro.models.attention import update_rows_contiguous
 
-    nd, rd = cfg.nope_head_dim, cfg.rope_head_dim
     q_nope, q_rope = _queries(cfg, p, x, positions)
     c_new, kr_new = _latents(cfg, p, x, positions)
     if row_start is not None:  # contiguous decode/chain fast path
@@ -115,7 +131,7 @@ def mla_cached(cfg, p, x, cache_ckv, cache_krope, row_idx, positions, attn_mask,
 
     # absorbed: q_eff[h] = q_nope[h] @ W_uk[h]^T -> dot with latent directly
     q_eff = jnp.einsum("bnhk,lhk->bnhl", q_nope, p["w_uk"].value)
-    scale = 1.0 / jnp.sqrt(nd + rd)
+    scale = _softmax_scale(cfg)
     scores = (
         jnp.einsum("bnhl,bsl->bhns", q_eff, ckv)
         + jnp.einsum("bnhk,bsk->bhns", q_rope, krope)

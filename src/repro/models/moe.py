@@ -1,4 +1,4 @@
-"""Mixture-of-Experts: capacity-based grouped dispatch, jittable & shardable.
+"""Mixture-of-Experts: dropless grouped dispatch, jittable & shardable.
 
 Two execution strategies (flags.moe_impl):
   "tp" — TP-within-expert (default): expert weights replicated across "model"
@@ -9,16 +9,21 @@ Two execution strategies (flags.moe_impl):
          local tokens and a psum combines contributions.  Evaluated against
          "tp" in the §Perf hillclimb.
 
-Dispatch is the sort-based capacity scheme: (token, k) pairs are sorted by
-expert id, positions-within-expert beyond capacity drop (weighted renorm keeps
-the estimator unbiased enough for routing studies; capacity_factor controls
-drops).
+Dispatch is dropless: the (token, k) pairs are sorted by expert and each
+expert's run of rows goes through one grouped matmul
+(``jax.lax.ragged_dot``), so only real pairs are computed and no expert has
+a capacity.  A row's result depends on that row alone, whatever else shares
+the call: a speculative tree's nodes verify as they would one by one.  Rows
+marked dead (``live`` false: a tree's padding slots) route nowhere and add
+nothing.  The gates are a softmax over all experts, the top k kept and,
+with ``cfg.norm_topk_prob``, renormalised to sum to one.
+
+Named scopes ``moe_dispatch``, ``moe_experts`` and ``moe_combine`` label
+the three parts in the compiled program's op metadata, where a device trace
+read with its HLO proto finds them.
 """
 
 from __future__ import annotations
-
-import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -52,120 +57,89 @@ def init_moe(cfg, key):
     return p
 
 
-def _dispatch(x2d, router_w, n_experts, top_k, capacity):
-    """Route tokens to per-expert slots. Returns (xbuf [E,C,d], combine info)."""
+def _route(x2d, router_w, cfg):
+    """Gates [T, k] and expert ids [T, k] of each token's top k."""
+    gates = jax.nn.softmax(x2d.astype(jnp.float32) @ router_w.astype(jnp.float32))
+    topv, topi = jax.lax.top_k(gates, cfg.moe_top_k)
+    if cfg.norm_topk_prob:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    return topv, topi
+
+
+def _experts(x2d, topv, topi, live, wg, wu, wd, first: int):
+    """The routed experts ``first .. first + len(wg) - 1`` on the pairs routed
+    to them: returns (their weighted sum per token [T, d] in float32, the
+    live rows each of them received [n])."""
     T, d = x2d.shape
-    gates = jax.nn.softmax((x2d.astype(jnp.float32)) @ router_w.astype(jnp.float32))
-    topv, topi = jax.lax.top_k(gates, top_k)  # [T,k]
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    flat_e = topi.reshape(-1)  # [T*k]
-    sort_idx = jnp.argsort(flat_e)  # stable
-    sorted_e = flat_e[sort_idx]
-    seg_start = jnp.searchsorted(sorted_e, jnp.arange(n_experts))
-    pos_in_e = jnp.arange(T * top_k) - seg_start[sorted_e]
-    keep = pos_in_e < capacity
-    dest = jnp.where(keep, sorted_e * capacity + pos_in_e, n_experts * capacity)
-    tok = sort_idx // top_k
-
-    xbuf = jnp.zeros((n_experts * capacity + 1, d), x2d.dtype).at[dest].add(x2d[tok])
-    w_sorted = topv.reshape(-1)[sort_idx] * keep
-    return xbuf[:-1].reshape(n_experts, capacity, d), (dest, tok, w_sorted)
-
-
-def _combine(h, info, T):
-    dest, tok, w_sorted = info
-    E_C, d = h.reshape(-1, h.shape[-1]).shape
-    hflat = jnp.concatenate([h.reshape(E_C, d), jnp.zeros((1, d), h.dtype)], 0)
-    contrib = hflat[dest] * w_sorted[:, None].astype(h.dtype)
-    return jnp.zeros((T, d), h.dtype).at[tok].add(contrib)
+    k, n = topi.shape[1], wg.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        e = topi.reshape(-1) - first
+        mine = jnp.repeat(live, k) & (e >= 0) & (e < n)
+        key = jnp.where(mine, e, n)  # pairs for no expert here sort last
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+        tok = order // k
+        xs = x2d[tok]
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, wg, sizes)
+        u = jax.lax.ragged_dot(xs, wu, sizes)
+        h = jax.lax.ragged_dot(jax.nn.silu(g) * u, wd, sizes)  # rows past the groups: 0
+    with jax.named_scope("moe_combine"):
+        w = jnp.where(mine, topv.reshape(-1), 0.0)[order]
+        out = jnp.zeros((T, d), jnp.float32).at[tok].add(h.astype(jnp.float32) * w[:, None])
+    return out, sizes
 
 
-def _expert_mlp(xbuf, wg, wu, wd):
-    g = jnp.einsum("ecd,edf->ecf", xbuf, wg)
-    u = jnp.einsum("ecd,edf->ecf", xbuf, wu)
-    return jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, wd)
-
-
-def _moe_local(x2d, p_vals, cfg, capacity):
-    xbuf, info = _dispatch(x2d, p_vals["router"], cfg.n_experts, cfg.moe_top_k, capacity)
-    h = _expert_mlp(xbuf, p_vals["wg"], p_vals["wu"], p_vals["wd"])
-    return _combine(h, info, x2d.shape[0])
-
-
-def moe_apply(cfg, p, x):
-    """x: [B, S, d] (or [B, n, d]); returns same shape."""
+def moe_apply(cfg, p, x, live=None):
+    """x: [B, S, d] (or [B, n, d]); ``live`` [B, S] marks the rows that are
+    real tokens (None: all).  Returns (out of x's shape, the number of
+    routed experts that received at least one live row)."""
     flags = get_flags()
     B, S, d = x.shape
     x2d = x.reshape(B * S, d)
+    live = jnp.ones((B * S,), bool) if live is None else live.reshape(B * S)
     mesh = get_mesh()
-    p_vals = {k: v.value for k, v in p.items() if k != "shared"}
+    pv = {k: v.value for k, v in p.items() if k != "shared"}
 
     if mesh is None or "model" not in mesh.axis_names:
-        T = x2d.shape[0]
-        cap = max(1, math.ceil(T * cfg.moe_top_k / cfg.n_experts * cfg.capacity_factor))
-        out = _moe_local(x2d, p_vals, cfg, cap)
+        topv, topi = _route(x2d, pv["router"], cfg)
+        out, sizes = _experts(x2d, topv, topi, live, pv["wg"], pv["wu"], pv["wd"], 0)
+        touched = jnp.sum(sizes > 0)
     else:
         data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-        dsize = math.prod(mesh.shape[a] for a in data_axes) if data_axes else 1
         msize = mesh.shape["model"]
-        T_local = max(1, (B * S) // max(dsize, 1))
-        cap = max(1, math.ceil(T_local * cfg.moe_top_k / cfg.n_experts * cfg.capacity_factor))
         tok_spec = P(data_axes if data_axes else None, None)
+        live_spec = P(data_axes if data_axes else None)
+        ep = flags.moe_impl == "ep" and cfg.n_experts % msize == 0
 
-        if flags.moe_impl == "ep" and cfg.n_experts % msize == 0:
-            # expert-parallel: shard experts over "model"; tokens replicated on
-            # "model"; each shard computes its experts' full-ff MLP; psum merges.
-            e_loc = cfg.n_experts // msize
+        def block(x_loc, live_loc, router, wg, wu, wd):
+            topv, topi = _route(x_loc, router, cfg)
+            # expert-parallel: this shard holds experts [midx*e_loc, ...);
+            # TP-within-expert: every expert, a slice of its ff columns
+            first = jax.lax.axis_index("model") * wg.shape[0] if ep else 0
+            y, sizes = _experts(x_loc, topv, topi, live_loc, wg, wu, wd, first)
+            if data_axes:
+                sizes = jax.lax.psum(sizes, data_axes)
+            touched = jnp.sum(sizes > 0)
+            if ep:
+                touched = jax.lax.psum(touched, "model")
+            return jax.lax.psum(y, "model"), touched
 
-            def ep_block(x_loc, router, wg, wu, wd):
-                midx = jax.lax.axis_index("model")
-                gates = jax.nn.softmax(x_loc.astype(jnp.float32) @ router.astype(jnp.float32))
-                topv, topi = jax.lax.top_k(gates, cfg.moe_top_k)
-                topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-                # local expert ids owned by this shard: [midx*e_loc, (midx+1)*e_loc)
-                rel = topi - midx * e_loc  # [T,k]
-                mine = (rel >= 0) & (rel < e_loc)
-                flat_e = jnp.where(mine, rel, e_loc).reshape(-1)
-                sort_idx = jnp.argsort(flat_e)
-                sorted_e = flat_e[sort_idx]
-                seg_start = jnp.searchsorted(sorted_e, jnp.arange(e_loc))
-                cap_ep = max(1, math.ceil(T_local * cfg.moe_top_k / cfg.n_experts * cfg.capacity_factor))
-                pos_in_e = jnp.arange(flat_e.shape[0]) - seg_start[sorted_e.clip(0, e_loc - 1)]
-                keep = (sorted_e < e_loc) & (pos_in_e < cap_ep)
-                dest = jnp.where(keep, sorted_e * cap_ep + pos_in_e, e_loc * cap_ep)
-                tok = sort_idx // cfg.moe_top_k
-                xbuf = jnp.zeros((e_loc * cap_ep + 1, d), x_loc.dtype).at[dest].add(x_loc[tok])
-                h = _expert_mlp(xbuf[:-1].reshape(e_loc, cap_ep, d), wg, wu, wd)
-                w_sorted = (topv.reshape(-1)[sort_idx] * keep).astype(h.dtype)
-                y = _combine(h, (dest, tok, w_sorted), x_loc.shape[0])
-                return jax.lax.psum(y, "model")
+        w_specs = ((P("model", None, None),) * 3 if ep else
+                   (P(None, None, "model"), P(None, None, "model"), P(None, "model", None)))
+        out, touched = jax.shard_map(
+            block,
+            mesh=mesh,
+            in_specs=(tok_spec, live_spec, P(None, None)) + w_specs,
+            out_specs=(tok_spec, P()),
+            check_vma=False,
+        )(x2d, live, pv["router"], pv["wg"], pv["wu"], pv["wd"])
 
-            out = jax.shard_map(
-                ep_block,
-                mesh=mesh,
-                in_specs=(tok_spec, P(None, None), P("model", None, None), P("model", None, None), P("model", None, None)),
-                out_specs=tok_spec,
-                check_vma=False,
-            )(x2d, p_vals["router"], p_vals["wg"], p_vals["wu"], p_vals["wd"])
-        else:
-            # TP-within-expert: ff dim sharded over "model"; dispatch local.
-            def tp_block(x_loc, router, wg, wu, wd):
-                y = _moe_local(x_loc, {"router": router, "wg": wg, "wu": wu, "wd": wd}, cfg, cap)
-                return jax.lax.psum(y, "model")
-
-            out = jax.shard_map(
-                tp_block,
-                mesh=mesh,
-                in_specs=(tok_spec, P(None, None), P(None, None, "model"), P(None, None, "model"), P(None, "model", None)),
-                out_specs=tok_spec,
-                check_vma=False,
-            )(x2d, p_vals["router"], p_vals["wg"], p_vals["wu"], p_vals["wd"])
-
+    out = out.astype(x.dtype)
     if cfg.n_shared_experts:
         sh = p["shared"]
         g = x2d @ sh["wg"].value
         u = x2d @ sh["wu"].value
         out = out + (jax.nn.silu(g) * u) @ sh["wd"].value
 
-    return out.reshape(B, S, d)
+    return out.reshape(B, S, d), touched

@@ -245,6 +245,8 @@ def _attn_dispatch(cfg, p, h, ctx: Ctx, cache, kind):
 
 
 def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
+    """Returns (h', the block's new cache, the routed experts that live rows
+    reached: an int32 scalar for a MoE block, else 0)."""
     if kind in ("dense", "moe", "cross"):
         a, new_cache = _attn_dispatch(cfg, p["attn"], rms_norm(h, p["ln1"].value, cfg.norm_eps), ctx, cache, kind)
         h = h + a
@@ -252,17 +254,18 @@ def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
         if kind == "moe":
             from repro.models.moe import moe_apply
 
-            h = h + moe_apply(cfg, p["mlp"], hn)
-        else:
-            h = h + _mlp_apply(cfg, p["mlp"], hn)
-        return h, new_cache
+            # a cached call's rows with no cache row are padding: they route nowhere
+            live = ctx.row_idx >= 0 if ctx.mode == "cached" else None
+            out, touched = moe_apply(cfg, p["mlp"], hn, live)
+            return h + out, new_cache, touched
+        return h + _mlp_apply(cfg, p["mlp"], hn), new_cache, 0
     if kind == "mamba2":
         out, new_cache = m2.mamba2_apply(
             cfg, p["mamba"], rms_norm(h, p["ln"].value, cfg.norm_eps), cache, ctx.commit_mask
         )
         if ctx.mode == "full" and not ctx.make_cache:
             new_cache = None
-        return h + out, new_cache
+        return h + out, new_cache, 0
     if kind == "rwkv6":
         tm_cache = None if cache is None else {"sx_tm": cache["sx_tm"], "wkv": cache["wkv"]}
         cm_cache = None if cache is None else {"sx_cm": cache["sx_cm"]}
@@ -273,7 +276,7 @@ def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
         new_cache = {**nc_tm, **nc_cm}
         if ctx.mode == "full" and not ctx.make_cache:
             new_cache = None
-        return h, new_cache
+        return h, new_cache, 0
     if kind == "shared":
         # zamba2: weight-shared attn+mlp block on concat(h, x0)
         inp = jnp.concatenate([h, ctx.x0], axis=-1) @ p["in_w"].value
@@ -283,7 +286,7 @@ def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
         inp = inp + a
         hn = rms_norm(inp, shared_p["ln2"].value, cfg.norm_eps)
         inp = inp + _mlp_apply(cfg, shared_p["mlp"], hn)
-        return h + inp, new_cache
+        return h + inp, new_cache, 0
     raise ValueError(kind)
 
 
@@ -328,26 +331,35 @@ def init_cache(cfg, B, S_max, dtype):
 
 
 def apply_model(cfg, params, h, ctx: Ctx, cache=None):
-    """h: [B, n, d] embedded inputs. Returns (hidden [B,n,d], new_cache)."""
+    """h: [B, n, d] embedded inputs. Returns (hidden [B,n,d], new_cache, the
+    routed experts that live rows reached, summed over the MoE layers: an
+    int32 scalar, or 0 for a model with none)."""
     flags = get_flags()
     plan = build_plan(cfg)
     ctx.x0 = h if any("shared" in u for u, _ in plan) else None
     shared_p = params["shared_attn"]
     new_groups = []
+    touched = 0
 
     for gi, (unit_def, n_reps) in enumerate(plan):
         stacked = params["groups"][gi]
         cache_g = cache["groups"][gi] if cache is not None else None
         emit_cache = ctx.mode == "cached" or ctx.make_cache
+        count = "moe" in unit_def
 
         def unit_fn(h_carry, xs):
             up, uc = xs
             new_uc = []
+            n = 0
             for bi, kind in enumerate(unit_def):
                 bc = None if uc is None else uc[bi]
-                h_carry, nc = apply_block(cfg, kind, up[bi], h_carry, ctx, bc, shared_p)
+                h_carry, nc, nb = apply_block(cfg, kind, up[bi], h_carry, ctx, bc, shared_p)
                 new_uc.append(nc)
-            return h_carry, tuple(new_uc) if emit_cache else None
+                n = n + nb
+            ys = tuple(new_uc) if emit_cache else None
+            if count:  # (caches, experts reached by this unit's MoE blocks)
+                ys = (ys, n)
+            return h_carry, ys
 
         if flags.seq_shard_acts:
             # sequence parallelism: the residual stream carried between units
@@ -365,6 +377,9 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
 
         if flags.scan_layers and n_reps > 1:
             h, ys = jax.lax.scan(unit_fn, h, (stacked, cache_g))
+            if count:
+                ys, n = ys
+                touched = touched + jnp.sum(n)
             new_groups.append(ys)
         else:
             ys = []
@@ -376,6 +391,9 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
                 )
                 uc = None if cache_g is None else jax.tree.map(lambda x, _r=r: x[_r], cache_g)
                 h, nc = unit_fn(h, (up, uc))
+                if count:
+                    nc, n = nc
+                    touched = touched + n
                 ys.append(nc)
             if emit_cache:
                 new_groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *ys))
@@ -384,8 +402,8 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
 
     h = rms_norm(h, params["final_norm"].value, cfg.norm_eps)
     if cache is not None or ctx.make_cache:
-        return h, {"len": None, "groups": new_groups}  # len managed by caller
-    return h, None
+        return h, {"len": None, "groups": new_groups}, touched  # len managed by caller
+    return h, None, touched
 
 
 def axes_tree(stacked):

@@ -6,11 +6,10 @@ and leave the same draft cache, as a separate prefix fill followed by a plain
 expansion: the fill rows are written before the leaves attend, and the leaves'
 prefix mask covers them.  Checked in float32 on the CPU on an empty fill, a
 fill of several tokens and the rolled-back re-root that ``reconcile`` runs;
-and, for a capacity-dropping MoE draft, that the leaves are served before the
-fill slots."""
+and, for a MoE draft, that the padding fill slots take nothing from the
+leaves."""
 
 import dataclasses
-import math
 
 import jax
 import jax.numpy as jnp
@@ -150,33 +149,22 @@ def test_fused_fill_grows_what_fill_then_expand_grows(engines, case):
     _assert_same_growth(tr, (ref_tr, ref_cache), (got_tr, got_cache))
 
 
-def _capacity(cfg, n_tokens):
-    """Per-expert capacity of one single-device MoE call (models/moe.py)."""
-    return max(1, math.ceil(n_tokens * cfg.moe_top_k / cfg.n_experts * cfg.capacity_factor))
-
-
 def test_fused_fill_serves_moe_leaves_first():
-    """A Mixtral-shaped draft (8 experts, top-2) at its published capacity
-    factor of 1.25 drops tokens beyond each expert's capacity, and serves a
-    call's tokens in slot order.  On an empty fill the eight fill slots are
-    all padding; the leaves must not lose expert slots to them.  So the fused
-    program must grow exactly what a plain expansion grows when the leaves
-    alone are given the fused call's capacity."""
-    cfg = dataclasses.replace(get_config("mixtral-8x22b", smoke=True),
-                              n_experts=8, capacity_factor=1.25)
+    """A Mixtral-shaped draft (8 experts, top-2).  Its dispatch is dropless,
+    so a leaf's logits do not depend on what else shares the call: on an
+    empty fill the eight fill slots are all padding and route nowhere, and
+    the fused program must grow exactly what a plain expansion of the
+    leaves alone grows, with the same engine."""
+    cfg = dataclasses.replace(get_config("mixtral-8x22b", smoke=True), n_experts=8)
     M = make_model(cfg)
     mp = M.init(jax.random.PRNGKey(2))
     mp["lm_head"].value = mp["lm_head"].value * 4.0
     eng = SpecEngine(M, M, SpecConfig(**CFG), S_max_t=256, S_max_d=256)
-    w, F = eng.cfg.w, eng.cfg.bs
-    ref_cfg = dataclasses.replace(cfg, capacity_factor=cfg.capacity_factor * (w + F) / w)
-    assert _capacity(ref_cfg, w) == _capacity(cfg, w + F) > _capacity(cfg, w)
-    ref_eng = SpecEngine(M, make_model(ref_cfg), SpecConfig(**CFG), S_max_t=256, S_max_d=256)
 
     tr, dcache, fill = _rerooted(eng, mp, mp, strip_kv=False, keep_subtree=True)
     assert not np.asarray(fill.mask).any()  # nothing to fill: the slots are padding
-    _, leaf_valid = jax.vmap(lambda t: select_leaves(t, w))(tr)
+    _, leaf_valid = jax.vmap(lambda t: select_leaves(t, eng.cfg.w))(tr)
     assert int(np.asarray(leaf_valid).sum()) >= 2
-    ref_tr, ref_cache = ref_eng._expand(mp, _copy(tr), _copy(dcache))
+    ref_tr, ref_cache = eng._expand(mp, _copy(tr), _copy(dcache))
     got_tr, got_cache = eng._fill_grow(mp, _copy(tr), _copy(dcache), fill)
     _assert_same_growth(tr, (ref_tr, ref_cache), (got_tr, got_cache))

@@ -1,8 +1,9 @@
 """Mosaic compiles of the main-path Pallas kernels for a described TPU v5e.
 
 Nothing runs: each case lowers and compiles one kernel at the real widths of
-the DeepSeek-Coder-33B target / 1.3B draft pair in bf16 for a v5e chip that
-is described, not attached, and asserts that the compiled program holds the
+the DeepSeek-Coder-33B target / 1.3B draft pair (and DeepSeek-V2-Lite's
+expert layer, whose grouped matmuls ``jax.lax.ragged_dot`` lowers to a
+kernel) in bf16 for a v5e chip that is described, not attached, and asserts that the compiled program holds the
 kernel as a ``tpu_custom_call``.  This catches what interpret mode cannot —
 block shapes off the (8, 128) tiling, DMA slices of tiled dims, VMEM
 overuse — at no chip time.
@@ -24,6 +25,7 @@ from repro.kernels import ops
 from repro.kernels.kv_moves import kv_move_rows_pallas, slot_write_rows_pallas
 
 TARGET = get_config("deepseek-coder-33b")
+MOE = get_config("deepseek-v2-lite")
 DRAFT = get_config("deepseek-coder-1.3b")
 BF16 = jnp.bfloat16
 
@@ -103,6 +105,26 @@ def _slot_write_rows(dev):
     return fn, (big, big, one, one, slot)
 
 
+def _moe_experts(dev):
+    """One MoE layer of DeepSeek-V2-Lite on a verify's 8 tree tokens: 64
+    experts of width 1408, top-6, 2 shared."""
+    from repro.models.moe import moe_apply
+    from repro.sharding import Param
+
+    d, E, f, sf = MOE.d_model, MOE.n_experts, MOE.moe_d_ff, MOE.n_shared_experts * MOE.moe_d_ff
+
+    def layer(x, live, router, wg, wu, wd, swg, swu, swd):
+        box = lambda v: Param(v, ())  # noqa: E731
+        p = {"router": box(router), "wg": box(wg), "wu": box(wu), "wd": box(wd),
+             "shared": {"wg": box(swg), "wu": box(swu), "wd": box(swd)}}
+        return moe_apply(MOE, p, x, live)
+
+    args = (_sds((1, 8, d), BF16, dev), _sds((1, 8), jnp.bool_, dev), _sds((d, E), BF16, dev),
+            _sds((E, d, f), BF16, dev), _sds((E, d, f), BF16, dev), _sds((E, f, d), BF16, dev),
+            _sds((d, sf), BF16, dev), _sds((d, sf), BF16, dev), _sds((sf, d), BF16, dev))
+    return jax.jit(layer), args
+
+
 CASES = {
     # verify: 8 tree rows against a 2048-row cache, GQA group 7
     "tree_attention-33b-verify": _tree_attention(TARGET, 8),
@@ -113,6 +135,7 @@ CASES = {
     "kv_move_rows-donate": _kv_move_rows(True),
     "kv_move_rows-copy-through": _kv_move_rows(False),
     "slot_write_rows": _slot_write_rows,
+    "moe_experts-v2lite-verify": _moe_experts,
 }
 
 
