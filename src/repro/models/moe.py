@@ -18,6 +18,19 @@ marked dead (``live`` false: a tree's padding slots) route nowhere and add
 nothing.  The gates are a softmax over all experts, the top k kept and,
 with ``cfg.norm_topk_prob``, renormalised to sum to one.
 
+Group layout.  A serving forward (verify, expansion, prefill) hands a MoE
+layer the whole group's expert stacks, ``[L, E, d, f]``, and its layer
+index ``l``; the grouped matmuls read them in place as ``[L·E, d, f]``
+(merging the two leading dims is a bitcast), with layer ``l``'s expert
+``j`` as group ``l·E + j`` and every other layer's groups empty, so no
+slice of the layer's experts is copied out (``ragged_dot`` lowers to a
+kernel that cannot fuse one).  Under ``ep`` each shard holds ``E/m``
+experts of every layer, ``[L, E/m, ...]``, and expert ``j`` of the shard
+that starts at ``first`` is group ``l·E/m + j - first``.  Training passes
+the layer's own slice ``[E, d, f]`` (group ``j``): a grouped matmul's
+weight gradient has its weight operand's shape, so over the whole stack
+every layer would write an ``[L·E, d, f]`` gradient.
+
 Named scopes ``moe_dispatch``, ``moe_experts`` and ``moe_combine`` label
 the three parts in the compiled program's op metadata, where a device trace
 read with its HLO proto finds them.
@@ -66,18 +79,23 @@ def _route(x2d, router_w, cfg):
     return topv, topi
 
 
-def _experts(x2d, topv, topi, live, wg, wu, wd, first: int):
-    """The routed experts ``first .. first + len(wg) - 1`` on the pairs routed
-    to them: returns (their weighted sum per token [T, d] in float32, the
-    live rows each of them received [n])."""
+def _experts(x2d, topv, topi, live, wg, wu, wd, shift, lo, n):
+    """The routed experts on the (token, k) pairs routed to them.
+
+    ``wg``, ``wu`` [G, d, f] and ``wd`` [G, f, d] hold G groups; routed
+    expert ``j`` is group ``j - shift``, and only pairs whose group lies in
+    ``lo .. lo + n - 1`` (this layer's experts held here) are computed: the
+    others (dead rows, another shard's experts) sort last and add nothing.
+    Returns (their weighted sum per token [T, d] in float32, the live rows
+    each group received [G]; zero outside that run)."""
     T, d = x2d.shape
-    k, n = topi.shape[1], wg.shape[0]
+    k, G = topi.shape[1], wg.shape[0]
     with jax.named_scope("moe_dispatch"):
-        e = topi.reshape(-1) - first
-        mine = jnp.repeat(live, k) & (e >= 0) & (e < n)
-        key = jnp.where(mine, e, n)  # pairs for no expert here sort last
+        g = topi.reshape(-1) - shift
+        mine = jnp.repeat(live, k) & (g >= lo) & (g < lo + n)
+        key = jnp.where(mine, g, G)  # pairs for no group here sort last
         order = jnp.argsort(key, stable=True)
-        sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+        sizes = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)[:G]
         tok = order // k
         xs = x2d[tok]
     with jax.named_scope("moe_experts"):
@@ -90,10 +108,29 @@ def _experts(x2d, topv, topi, live, wg, wu, wd, first: int):
     return out, sizes
 
 
-def moe_apply(cfg, p, x, live=None):
+def _layer_experts(x2d, topv, topi, live, wg, wu, wd, first, layer):
+    """``_experts`` over the experts ``first ..`` of one layer held here:
+    ``wg``, ``wu``, ``wd`` are that layer's own [n, d, f] (``layer`` None),
+    or the group's whole stacks [L, n, d, f], read in place as L·n groups
+    of which groups ``layer·n ..`` are this layer's."""
+    n = wg.shape[-3]
+    if layer is None:
+        return _experts(x2d, topv, topi, live, wg, wu, wd, first, 0, n)
+    lo = layer * n
+
+    def merged(w):
+        return w.reshape((-1,) + w.shape[2:])
+
+    return _experts(x2d, topv, topi, live, merged(wg), merged(wu), merged(wd), first - lo, lo, n)
+
+
+def moe_apply(cfg, p, x, live=None, layer=None):
     """x: [B, S, d] (or [B, n, d]); ``live`` [B, S] marks the rows that are
-    real tokens (None: all).  Returns (out of x's shape, the number of
-    routed experts that received at least one live row)."""
+    real tokens (None: all).  ``p``'s routed experts ``wg``, ``wu``, ``wd``
+    are this layer's [E, d, f] / [E, f, d], or, with ``layer`` (its index in
+    the group), the group's whole stacks [L, E, d, f] / [L, E, f, d] (see
+    the module docstring).  Returns (out of x's shape, the number of routed
+    experts that received at least one live row)."""
     flags = get_flags()
     B, S, d = x.shape
     x2d = x.reshape(B * S, d)
@@ -103,7 +140,8 @@ def moe_apply(cfg, p, x, live=None):
 
     if mesh is None or "model" not in mesh.axis_names:
         topv, topi = _route(x2d, pv["router"], cfg)
-        out, sizes = _experts(x2d, topv, topi, live, pv["wg"], pv["wu"], pv["wd"], 0)
+        out, sizes = _layer_experts(x2d, topv, topi, live, pv["wg"], pv["wu"], pv["wd"], 0,
+                                    layer)
         touched = jnp.sum(sizes > 0)
     else:
         data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -112,12 +150,12 @@ def moe_apply(cfg, p, x, live=None):
         live_spec = P(data_axes if data_axes else None)
         ep = flags.moe_impl == "ep" and cfg.n_experts % msize == 0
 
-        def block(x_loc, live_loc, router, wg, wu, wd):
+        def block(x_loc, live_loc, router, wg, wu, wd, layer=None):
             topv, topi = _route(x_loc, router, cfg)
             # expert-parallel: this shard holds experts [midx*e_loc, ...);
             # TP-within-expert: every expert, a slice of its ff columns
-            first = jax.lax.axis_index("model") * wg.shape[0] if ep else 0
-            y, sizes = _experts(x_loc, topv, topi, live_loc, wg, wu, wd, first)
+            first = jax.lax.axis_index("model") * wg.shape[-3] if ep else 0
+            y, sizes = _layer_experts(x_loc, topv, topi, live_loc, wg, wu, wd, first, layer)
             if data_axes:
                 sizes = jax.lax.psum(sizes, data_axes)
             touched = jnp.sum(sizes > 0)
@@ -125,15 +163,18 @@ def moe_apply(cfg, p, x, live=None):
                 touched = jax.lax.psum(touched, "model")
             return jax.lax.psum(y, "model"), touched
 
-        w_specs = ((P("model", None, None),) * 3 if ep else
-                   (P(None, None, "model"), P(None, None, "model"), P(None, "model", None)))
+        lead = () if layer is None else (None,)  # the stacks' layer dim
+        w_specs = ((P(*lead, "model", None, None),) * 3 if ep else
+                   (P(*lead, None, None, "model"), P(*lead, None, None, "model"),
+                    P(*lead, None, "model", None)))
+        at = () if layer is None else (jnp.asarray(layer, jnp.int32),)
         out, touched = jax.shard_map(
             block,
             mesh=mesh,
-            in_specs=(tok_spec, live_spec, P(None, None)) + w_specs,
+            in_specs=(tok_spec, live_spec, P(None, None)) + w_specs + (P(),) * len(at),
             out_specs=(tok_spec, P()),
             check_vma=False,
-        )(x2d, live, pv["router"], pv["wg"], pv["wu"], pv["wd"])
+        )(x2d, live, pv["router"], pv["wg"], pv["wu"], pv["wd"], *at)
 
     out = out.astype(x.dtype)
     if cfg.n_shared_experts:

@@ -244,9 +244,11 @@ def _attn_dispatch(cfg, p, h, ctx: Ctx, cache, kind):
     return out, {"k": ck, "v": cv}
 
 
-def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
+def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p, layer=None):
     """Returns (h', the block's new cache, the routed experts that live rows
-    reached: an int32 scalar for a MoE block, else 0)."""
+    reached: an int32 scalar for a MoE block, else 0).  With ``layer``, a
+    MoE block's routed experts in ``p`` are the group's whole stacks and
+    ``layer`` is the block's index in them (``moe.moe_apply``)."""
     if kind in ("dense", "moe", "cross"):
         a, new_cache = _attn_dispatch(cfg, p["attn"], rms_norm(h, p["ln1"].value, cfg.norm_eps), ctx, cache, kind)
         h = h + a
@@ -256,7 +258,7 @@ def apply_block(cfg, kind, p, h, ctx: Ctx, cache, shared_p):
 
             # a cached call's rows with no cache row are padding: they route nowhere
             live = ctx.row_idx >= 0 if ctx.mode == "cached" else None
-            out, touched = moe_apply(cfg, p["mlp"], hn, live)
+            out, touched = moe_apply(cfg, p["mlp"], hn, live, layer)
             return h + out, new_cache, touched
         return h + _mlp_apply(cfg, p["mlp"], hn), new_cache, 0
     if kind == "mamba2":
@@ -330,6 +332,9 @@ def init_cache(cfg, B, S_max, dtype):
     return {"len": jnp.zeros((), jnp.int32), "groups": groups}
 
 
+_ROUTED = ("wg", "wu", "wd")  # a MoE block's routed-expert weights
+
+
 def apply_model(cfg, params, h, ctx: Ctx, cache=None):
     """h: [B, n, d] embedded inputs. Returns (hidden [B,n,d], new_cache, the
     routed experts that live rows reached, summed over the MoE layers: an
@@ -346,14 +351,28 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
         cache_g = cache["groups"][gi] if cache is not None else None
         emit_cache = ctx.mode == "cached" or ctx.make_cache
         count = "moe" in unit_def
+        # A serving forward (verify, expansion, prefill) reads the routed
+        # experts' weights in place: they stay out of the scanned tree, and
+        # each MoE block gets the group's whole stacks and its layer index.
+        # Training scans them as the layer's slice, since a grouped matmul's
+        # weight gradient over the whole stack would be L times the size.
+        whole = count and emit_cache
+        held = (None,) * len(unit_def)
+        if whole:
+            held = tuple({k: b["mlp"][k] for k in _ROUTED} if kind == "moe" else None
+                         for b, kind in zip(stacked, unit_def))
+            stacked = tuple({**b, "mlp": {k: v for k, v in b["mlp"].items() if k not in _ROUTED}}
+                            if kind == "moe" else b for b, kind in zip(stacked, unit_def))
 
         def unit_fn(h_carry, xs):
-            up, uc = xs
+            up, uc, layer = xs
             new_uc = []
             n = 0
             for bi, kind in enumerate(unit_def):
                 bc = None if uc is None else uc[bi]
-                h_carry, nc, nb = apply_block(cfg, kind, up[bi], h_carry, ctx, bc, shared_p)
+                bp = up[bi] if held[bi] is None else {
+                    **up[bi], "mlp": {**up[bi]["mlp"], **held[bi]}}
+                h_carry, nc, nb = apply_block(cfg, kind, bp, h_carry, ctx, bc, shared_p, layer)
                 new_uc.append(nc)
                 n = n + nb
             ys = tuple(new_uc) if emit_cache else None
@@ -376,7 +395,8 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
             unit_fn = jax.checkpoint(unit_fn)
 
         if flags.scan_layers and n_reps > 1:
-            h, ys = jax.lax.scan(unit_fn, h, (stacked, cache_g))
+            layers = jnp.arange(n_reps, dtype=jnp.int32) if whole else None
+            h, ys = jax.lax.scan(unit_fn, h, (stacked, cache_g, layers))
             if count:
                 ys, n = ys
                 touched = touched + jnp.sum(n)
@@ -390,7 +410,7 @@ def apply_model(cfg, params, h, ctx: Ctx, cache=None):
                     is_leaf=lambda x: isinstance(x, Param),
                 )
                 uc = None if cache_g is None else jax.tree.map(lambda x, _r=r: x[_r], cache_g)
-                h, nc = unit_fn(h, (up, uc))
+                h, nc = unit_fn(h, (up, uc, r if whole else None))
                 if count:
                     nc, n = nc
                     touched = touched + n

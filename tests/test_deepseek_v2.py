@@ -12,7 +12,11 @@ gates beside shared experts, a leading dense layer.
 - The serving depth cut keeps layer 0 dense, and the published model counts
   15.7 B parameters.
 - Served through ``EngineSession`` the verify program counts the experts
-  its tree reaches on the device, with no sync of its own."""
+  its tree reaches on the device, with no sync of its own.
+- A serving forward (a tree verify, a prefill) reads each MoE layer's
+  experts from the group's whole stack, scanned or unrolled, and gives
+  what a loop over the layers gives with each layer's own slice of them:
+  the same hidden states, caches and expert count."""
 
 import dataclasses
 import math
@@ -23,10 +27,12 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.flags import override_flags
 from repro.launch.serve import build_engine, serving_configs
 from repro.models import moe
-from repro.models.common import apply_rope, rope_frequencies
-from repro.models.transformer import build_plan
+from repro.models.common import apply_rope, rms_norm, rope_frequencies
+from repro.models.transformer import Ctx, apply_block, apply_model, build_plan
+from repro.sharding import Param
 
 CFG = get_config("deepseek-v2-lite")
 
@@ -100,8 +106,6 @@ def smoke_moe():
 
 
 def _layer0(mlp):
-    from repro.sharding import Param
-
     return jax.tree.map(lambda p: Param(p.value[0], p.axes[1:]), mlp,
                         is_leaf=lambda x: isinstance(x, Param))
 
@@ -191,3 +195,83 @@ def test_a_dense_target_counts_no_experts(dense_pair):
     out, st = eng.session(tp, dp).generate(np.arange(1, 9, dtype=np.int32).reshape(1, -1))
     assert st.rounds > 0 and st.moe_layer_calls == 0 and st.touched is None
     assert st.experts_touched == 0
+
+
+def _per_layer(cfg, params, h, ctx, cache):
+    """``apply_model`` as a plain loop over the layers, each MoE layer on its
+    own slice of the expert stacks (``moe_apply`` with no layer index, so
+    ``_experts`` takes the layer's [E, d, f] as its groups)."""
+    touched, groups = 0, []
+    for gi, (unit_def, n_reps) in enumerate(build_plan(cfg)):
+        ys = []
+        for r in range(n_reps):
+            up = jax.tree.map(lambda p, _r=r: Param(p.value[_r], p.axes[1:]), params["groups"][gi],
+                              is_leaf=lambda x: isinstance(x, Param))
+            uc = None if cache is None else jax.tree.map(lambda x, _r=r: x[_r], cache["groups"][gi])
+            new = []
+            for bi, kind in enumerate(unit_def):
+                h, nc, n = apply_block(cfg, kind, up[bi], h, ctx, None if uc is None else uc[bi],
+                                       None)
+                touched, new = touched + n, new + [nc]
+            ys.append(tuple(new))
+        groups.append(jax.tree.map(lambda *xs: jnp.stack(xs), *ys))
+    return rms_norm(h, params["final_norm"].value, cfg.norm_eps), groups, touched
+
+
+def _tree_verify_ctx(n_prompt, S):
+    """A verify's 8 rows: a 6-node tree below the prompt, 2 padding rows."""
+    parent = [-1, 0, 0, 1, 1, 2]
+    rows = np.full((1, 8), -1, np.int32)
+    pos = np.zeros((1, 8), np.int32)
+    mask = np.zeros((1, 8, S), bool)
+    mask[0, :, :n_prompt] = True
+    for i, par in enumerate(parent):
+        rows[0, i] = n_prompt + i
+        if par >= 0:
+            pos[0, i], mask[0, i] = pos[0, par] + 1, mask[0, par]
+        else:
+            pos[0, i] = n_prompt
+        mask[0, i, n_prompt + i] = True
+    return Ctx(mode="cached", positions=jnp.asarray(pos), row_idx=jnp.asarray(rows),
+               attn_mask=jnp.asarray(mask))
+
+
+@pytest.mark.parametrize("scan_layers", [True, False], ids=["scanned", "unrolled"])
+@pytest.mark.parametrize("mode", ["verify", "prefill"])
+def test_serving_forward_reads_each_layers_experts_from_the_whole_stack(mode, scan_layers):
+    from repro.models.api import make_model
+
+    # three MoE layers after the dense one, so that layers sit at offsets 0, E and 2E
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite", smoke=True), n_layers=4)
+    M = make_model(cfg)
+    params = M.init(jax.random.PRNGKey(7))
+    S, n_prompt = 32, 6
+    prompt = (jnp.arange(1, n_prompt + 1, dtype=jnp.int32) * 37 % cfg.vocab_size)[None]
+    _, cache = jax.jit(lambda p, t: M.prefill(p, tokens=t, S_max=S))(params, prompt)
+    if mode == "verify":
+        ctx = _tree_verify_ctx(n_prompt, S)
+        tokens = jnp.arange(8, dtype=jnp.int32)[None] * 11 % cfg.vocab_size
+    else:
+        ctx = Ctx(mode="full", make_cache=S,
+                  positions=jnp.arange(n_prompt, dtype=jnp.int32)[None])
+        tokens, cache = prompt, None
+    h = M._embed(params, tokens)
+    with override_flags(scan_layers=scan_layers):
+        got_h, got_cache, got_n = jax.jit(lambda p, h, c: apply_model(cfg, p, h, ctx, c))(
+            params, h, cache)
+    want_h, want_groups, want_n = jax.jit(lambda p, h, c: _per_layer(cfg, p, h, ctx, c))(
+        params, h, cache)
+    _assert_close(got_h, want_h)
+    for got, want in zip(jax.tree.leaves(got_cache["groups"]), jax.tree.leaves(want_groups)):
+        _assert_close(got, want)
+    assert int(got_n) == int(want_n) > 0
+
+
+def _assert_close(got, want):
+    """Within 1e-6 of ``want``'s largest magnitude: the CPU's grouped matmul
+    contracts over every group at once, so more groups sum a row's products
+    in another order (a lone MoE layer already differs by 2-3 ulp, and the
+    same loop jitted and eager differ by 1.6e-6 at these hidden states)."""
+    got, want = np.asarray(got), np.asarray(want)
+    err, scale = np.abs(got - want).max(), np.abs(want).max()
+    assert err <= 1e-6 * scale, (err, scale)

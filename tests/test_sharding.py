@@ -69,6 +69,7 @@ def test_tp_padding_equivalence(arch):
 _MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.models.api import make_model
@@ -111,6 +112,32 @@ with use_mesh(mesh):
             o = jax.jit(lambda p, t: m2.forward_train(p, tokens=t))(p2, toks % cfg2.vocab_size)
         np.testing.assert_allclose(np.asarray(o, np.float32), ref2, atol=3e-4, rtol=3e-4)
 
+# MoE serving forwards (prefill, then a tree verify with a padding row),
+# whose MoE layers read their experts from the whole stack: tp and ep agree
+# with one device, expert count included; three MoE layers, scanned
+m2 = make_model(dataclasses.replace(cfg2, n_layers=4))
+p2 = m2.init(jax.random.PRNGKey(1))
+S, V = 24, cfg2.vocab_size
+new = jnp.asarray([[3, 5, 7, 0]] * 2, jnp.int32)
+rows = jnp.asarray([[12, 13, 14, -1]] * 2, jnp.int32)
+pos = jnp.asarray([[12, 13, 13, 12]] * 2, jnp.int32)
+cols = jnp.arange(S)
+mask = (cols < 12)[None, None, :] | (cols[None, None, :] == rows[:, :, None])
+mask = mask.at[:, 1, 12].set(True).at[:, 2, 12].set(True)  # nodes 1, 2 are children of node 0
+prefill = jax.jit(lambda p, t: m2.prefill(p, tokens=t, S_max=S))
+verify = jax.jit(lambda p, c: m2.spec_forward(p, c, new, pos, rows, mask, count_experts=True))
+want_pre, cache = prefill(p2, toks % V)
+want_ver, _, want_n = verify(p2, cache)
+with use_mesh(mesh):
+    for impl in ("tp", "ep"):
+        with override_flags(moe_impl=impl):
+            got_pre, got_cache = prefill(p2, toks % V)
+            got_ver, _, got_n = verify(p2, got_cache)
+        np.testing.assert_allclose(np.asarray(got_pre), np.asarray(want_pre), atol=3e-4, rtol=3e-4)
+        np.testing.assert_allclose(np.asarray(got_ver[:, :3]), np.asarray(want_ver[:, :3]),
+                                   atol=3e-4, rtol=3e-4)
+        assert int(got_n) == int(want_n) > 0, (impl, int(got_n), int(want_n))
+
 # collective matmul variants == plain matmul
 from repro.core.collective_matmul import matmul_allreduce, matmul_ag_pipelined
 rng = np.random.default_rng(0)
@@ -125,7 +152,8 @@ print("MULTIDEV_OK")
 
 def test_spmd_multidevice_subprocess():
     """8 forced host devices: sharded forward == single-device forward; MoE
-    tp/ep agree; collective matmuls agree.  Subprocess so the main test
+    tp/ep agree in training and in serving forwards (prefill, tree verify);
+    collective matmuls agree.  Subprocess so the main test
     session keeps one device."""
     r = subprocess.run([sys.executable, "-c", _MULTIDEV_SCRIPT], capture_output=True,
                        text=True, timeout=900, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})

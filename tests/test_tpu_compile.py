@@ -6,7 +6,9 @@ expert layer, whose grouped matmuls ``jax.lax.ragged_dot`` lowers to a
 kernel) in bf16 for a v5e chip that is described, not attached, and asserts that the compiled program holds the
 kernel as a ``tpu_custom_call``.  This catches what interpret mode cannot —
 block shapes off the (8, 128) tiling, DMA slices of tiled dims, VMEM
-overuse — at no chip time.
+overuse — at no chip time.  The MoE target's whole verify program is
+compiled too, and its temporaries show that the grouped matmuls read the
+expert stacks in place.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU compiler library, and under several
@@ -17,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -107,22 +110,54 @@ def _slot_write_rows(dev):
 
 def _moe_experts(dev):
     """One MoE layer of DeepSeek-V2-Lite on a verify's 8 tree tokens: 64
-    experts of width 1408, top-6, 2 shared."""
+    experts of width 1408, top-6, 2 shared, read from the stacks of six
+    layers at a layer index, as a serving forward reads them."""
     from repro.models.moe import moe_apply
     from repro.sharding import Param
 
     d, E, f, sf = MOE.d_model, MOE.n_experts, MOE.moe_d_ff, MOE.n_shared_experts * MOE.moe_d_ff
+    L = 6
 
-    def layer(x, live, router, wg, wu, wd, swg, swu, swd):
+    def layer(x, live, router, wg, wu, wd, swg, swu, swd, at):
         box = lambda v: Param(v, ())  # noqa: E731
         p = {"router": box(router), "wg": box(wg), "wu": box(wu), "wd": box(wd),
              "shared": {"wg": box(swg), "wu": box(swu), "wd": box(swd)}}
-        return moe_apply(MOE, p, x, live)
+        return moe_apply(MOE, p, x, live, at)
 
     args = (_sds((1, 8, d), BF16, dev), _sds((1, 8), jnp.bool_, dev), _sds((d, E), BF16, dev),
-            _sds((E, d, f), BF16, dev), _sds((E, d, f), BF16, dev), _sds((E, f, d), BF16, dev),
-            _sds((d, sf), BF16, dev), _sds((d, sf), BF16, dev), _sds((sf, d), BF16, dev))
+            _sds((L, E, d, f), BF16, dev), _sds((L, E, d, f), BF16, dev),
+            _sds((L, E, f, d), BF16, dev), _sds((d, sf), BF16, dev), _sds((d, sf), BF16, dev),
+            _sds((sf, d), BF16, dev), _sds((), jnp.int32, dev))
     return jax.jit(layer), args
+
+
+def _moe_target_verify(dev):
+    """DeepSeek-V2-Lite cut to 7 layers (one dense, six MoE layers scanned)
+    as the benchmark's verify runs it: 8 tree rows, some of them padding
+    (``row_idx`` -1, the live mask), cached mode against a 2048-row latent
+    cache, under a one-chip ``model`` mesh with the serving rules."""
+    from jax.sharding import Mesh
+
+    from repro.launch.serve import serving_configs
+    from repro.models.api import make_model
+    from repro.sharding import SERVING_RULES, use_mesh
+
+    cfgT, _ = serving_configs("deepseek-v2-lite", "deepseek-v2-lite-draft", smoke=False,
+                              target_layers=7, dtype="bfloat16")
+    m = make_model(cfgT)
+    S, n = 2048, 8
+    mesh = Mesh(np.array([next(iter(dev.device_set))]), ("model",))
+
+    def on(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, dev), tree)
+
+    with use_mesh(mesh, SERVING_RULES):
+        params = on(jax.eval_shape(m.init, jax.random.PRNGKey(0)))
+        cache = on(jax.eval_shape(lambda: m.init_cache(1, S, BF16)))
+        rows = _sds((1, n), jnp.int32, dev)
+        fn = jax.jit(lambda p, c, t, pos, r, mask: m.spec_forward(p, c, t, pos, r, mask,
+                                                                  count_experts=True))
+        return fn.lower(params, cache, rows, rows, rows, _sds((1, n, S), jnp.bool_, dev))
 
 
 CASES = {
@@ -144,3 +179,12 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, args = CASES[case](one_chip)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, f"{case}: no Mosaic kernel in the compiled program"
+
+
+def test_moe_verify_reads_the_expert_stacks_in_place(one_chip):
+    """The six MoE layers' grouped matmuls read the whole [L·E, d, f] stacks:
+    no layer's experts are sliced out into a temporary (3 x 369 MB per layer
+    when they were)."""
+    compiled = _moe_target_verify(one_chip).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert "tpu_custom_call" in compiled.as_text()
