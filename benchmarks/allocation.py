@@ -40,10 +40,10 @@ PAIRS = {
 
 
 def ratio_at_depth(T, tp, d):
-    eng = SpecEngine(T, T, SpecConfig(bs=8, w=4, c=2, d=max(1, min(d, 6)), mode="parallel",
-                                      max_new=32), 512, 512)
+    eng = SpecEngine(T, T, SpecConfig(bs=8, w=4, c=2, d=max(1, min(d, 6)), max_new=32),
+                     512, 512)
     prompt = (np.arange(1, 9, dtype=np.int32) % 100).reshape(1, 8)
-    _, st = eng.generate(tp, tp, prompt)
+    _, st = eng.session(tp, tp).generate(prompt)
     return st.compression_ratio
 
 

@@ -1,4 +1,4 @@
-"""Shared benchmark plumbing: model builders, timing, CSV output.
+"""Shared benchmark plumbing: model builders, time models, CSV output.
 
 Two measurement regimes on this CPU-only container (each benchmark states
 which it uses):
@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import csv
 import os
-import time
 
 import jax
 import numpy as np
@@ -49,15 +48,6 @@ def write_json(name: str, payload: dict):
     with open(path, "w") as f:
         json.dump(payload, f, indent=1)
     return path
-
-
-def time_call(fn, iters=5, warmup=2):
-    for _ in range(warmup):
-        jax.block_until_ready(fn())
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        jax.block_until_ready(fn())
-    return (time.perf_counter() - t0) / iters
 
 
 def build_pair(target_arch="qwen2.5-14b", draft_layers=2, seed=0, peak=4.0):

@@ -1,7 +1,7 @@
 """Benchmark harness: one entry per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run            # all
-  PYTHONPATH=src python -m benchmarks.run table1 fig2
+  PYTHONPATH=src python -m benchmarks.run table1 fig9
 
 CSV outputs land in benchmarks/results/.  Regimes (measured vs derived) are
 documented per module; the dry-run roofline table (EXPERIMENTS.md §Roofline)
@@ -15,22 +15,14 @@ import time
 import traceback
 
 from benchmarks import (
-    ablation,
     allocation,
-    compression,
-    e2e,
     kernel_micro,
-    parallel_vs_serial,
     serving,
     tp_scaling,
 )
 
 BENCHES = {
     "table1": ("Paper Table 1  — TP scaling per model size", tp_scaling.run),
-    "fig2": ("Paper Figure 2 — compression vs bs / w", compression.run),
-    "table6": ("Paper Table 6  — parallel vs serial tree generation", parallel_vs_serial.run),
-    "fig7": ("Paper Figure 7 — end-to-end decoding speed", e2e.run),
-    "fig8": ("Paper Figure 8 — ablation (parallel x kernels)", ablation.run),
     "table7": ("Paper Tables 3/7 — kernel micro-benchmarks", kernel_micro.run),
     "fig9": ("Paper Figure 9 — draft/target allocation sweep", allocation.run),
     "serving": ("Serving — replicas x offered-load sweep (sharded runtime)", serving.run),

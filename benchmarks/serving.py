@@ -195,25 +195,6 @@ def run() -> None:
                       "occupancy_per_replica", "shed"],
                      rows)
     print(f"  -> {path}")
-    # async-disaggregation cell: replay the saturating-rate trace with
-    # async rounds on, on its own tracer — the overlap metrics are the
-    # evidence draft work left the critical path (tests assert the outputs
-    # are byte-identical, so only the schedule differs)
-    import dataclasses
-
-    a_eng = SpecEngine(eng.target, eng.draft,
-                       dataclasses.replace(eng.cfg, async_rounds=True),
-                       S_max_t=256, S_max_d=256)
-    a_tracer = Tracer()
-    a_trace = make_request_trace(cfgT.vocab_size, N_REQUESTS, rate_rps=max(RATES),
-                                 prompt_len=(8, 16), max_new=MAX_NEW, seed=7)
-    a_rt = ShardedServingRuntime([a_eng], tp, dp, n_slots=N_SLOTS,
-                                 clock=VirtualClock(round_dt=0.1), tracer=a_tracer)
-    a_rt.submit_trace(Request(rid=r.rid, prompt=r.prompt, arrival_s=r.arrival_s,
-                              max_new=r.max_new) for r in a_trace)
-    a_rt.run()
-    a_bd = phase_breakdown(a_tracer)
-
     # SLO sweep: deadline attainment under fixed vs adaptive draft depth
     slo_cells, slo_summary = slo_sweep(eng, tp, dp, cfgT)
 
@@ -236,17 +217,14 @@ def run() -> None:
         ],
         "phase_breakdown": bd,
         "accept_depth_mean": accept.mean,
-        "async_phase_breakdown": a_bd,
-        "async_overlap_draft_verify_s": a_bd["overlap_draft_verify_s"],
-        "async_draft_serialized_frac": a_bd["draft_serialized_frac"],
-        "lockstep_draft_serialized_frac": bd["draft_serialized_frac"],
+        "overlap_draft_verify_s": bd["overlap_draft_verify_s"],
+        "draft_serialized_frac": bd["draft_serialized_frac"],
         "slo_cells": slo_cells,
         "slo_summary": slo_summary,
     })
     print(breakdown_report(bd))
-    print(f"  async: draft overlapped verify {a_bd['overlap_draft_verify_s']*1e3:.1f} ms, "
-          f"serialized draft {a_bd['draft_serialized_frac']:.1%} of round "
-          f"(lockstep {bd['draft_serialized_frac']:.1%})")
+    print(f"  draft overlapped verify {bd['overlap_draft_verify_s']*1e3:.1f} ms, "
+          f"serialized draft {bd['draft_serialized_frac']:.1%} of round")
     print(f"  -> {jpath}")
     # sanity AFTER the CSV lands, so a violation can't discard data
     assert all(p <= N_SLOTS for p in peak_occ), peak_occ

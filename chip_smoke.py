@@ -12,9 +12,9 @@ target keeps its published widths (d_model 7168, 56 query / 8 KV heads of
 whole 24-layer draft.
 
 One chip:
-  1. solo generate, lockstep and async rounds, against target-only greedy
+  1. solo generate (the pipelined round) against target-only greedy
      decoding of the same weights (the tie rule below);
-  2. continuous serving (async rounds, WallClock, 2 slots): every output
+  2. continuous serving (WallClock, 2 slots): every output
      byte-identical to the same request served alone (same engine, same
      slot count), and within the tie rule of target-only greedy decoding;
   3. phase 1 again with the Pallas kernels on; the compiled round programs
@@ -32,7 +32,6 @@ names the device.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import gc
 import json
 import re
@@ -119,12 +118,12 @@ def check_tie_rule(label: str, rows, oracle) -> None:
             raise AssertionError(f"{label} row {b}: left greedy at {p} with no bf16 tie")
 
 
-def engine_variant(eng, **cfg):
-    """The same model pair and meshes under another SpecConfig: fresh jitted
-    programs (they trace the flags active at their first call)."""
+def fresh_engine(eng):
+    """The same model pair, SpecConfig and meshes with fresh jitted programs
+    (they trace the flags active at their first call)."""
     from repro.core.engine import SpecEngine
 
-    return SpecEngine(eng.target, eng.draft, dataclasses.replace(eng.cfg, **cfg),
+    return SpecEngine(eng.target, eng.draft, eng.cfg,
                       S_max_t=eng.S_max_t, S_max_d=eng.S_max_d,
                       mesh_target=eng.mesh_target, mesh_draft=eng.mesh_draft)
 
@@ -201,21 +200,18 @@ def one_chip(max_new: int, device: str) -> None:
     oracle = greedy_decode(eng.target, tp, prompt, max_new, eng.S_max_t)
 
     log("phase 1: solo generate vs target-only greedy")
-    timed_generate("lockstep", eng, tp, dp, prompt, oracle, device)
-    eng_async = engine_variant(eng, async_rounds=True)
-    timed_generate("async", eng_async, tp, dp, prompt, oracle, device)
+    timed_generate("round", eng, tp, dp, prompt, oracle, device)
     log("phase 1: pass")
 
-    log("phase 2: continuous serving (async rounds, 2 slots, WallClock)")
-    served, rows = serve_and_verify(eng_async, tp, dp, cfgT.vocab_size, max_new, device)
+    log("phase 2: continuous serving (2 slots, WallClock)")
+    served, rows = serve_and_verify(eng, tp, dp, cfgT.vocab_size, max_new, device)
     check_tie_rule("served", rows, greedy_decode(eng.target, tp, served, max_new, eng.S_max_t))
     log("phase 2: pass")
 
     log("phase 3: Pallas kernels on (tree_attention, fused_swiglu, kv_move_rows, slot_write_rows)")
     with override_flags(**KERNELS):
-        for label, variant in (("lockstep+kernels", {}), ("async+kernels", {"async_rounds": True})):
-            k_eng = engine_variant(eng, **variant)
-            sess = timed_generate(label, k_eng, tp, dp, prompt, oracle, device)
+        k_eng = fresh_engine(eng)
+        sess = timed_generate("round+kernels", k_eng, tp, dp, prompt, oracle, device)
     check_kernel_programs(k_eng, tp, dp, sess.state)
     log("phase 3: pass")
 
@@ -266,7 +262,7 @@ def four_chips(max_new: int, device: str) -> None:
     with override_flags(**KERNELS):
         log("multi-chip A: 2 replicas of 1 target + 1 draft chip, sharded runtime, kernels on")
         engs, tps, dps, _ = build_engine(cfgT, cfgD, n_target=1, n_draft=1, replicas=2,
-                                         async_rounds=True, max_new=max_new, **ENGINE)
+                                         max_new=max_new, **ENGINE)
         for i, e in enumerate(engs):
             log(f"  replica {i}: target on {list(e.mesh_target.devices.flat)}, "
                 f"draft on {list(e.mesh_draft.devices.flat)}")
@@ -281,7 +277,7 @@ def four_chips(max_new: int, device: str) -> None:
         gc.collect()
 
         log("multi-chip B: 1 replica, target TP=2 + draft TP=2 on disjoint chips, kernels on")
-        eng, tp, dp, _ = build_engine(cfgT, cfgD, n_target=2, n_draft=2, async_rounds=True,
+        eng, tp, dp, _ = build_engine(cfgT, cfgD, n_target=2, n_draft=2,
                                       max_new=max_new, **ENGINE)
         log(f"  target on {list(eng.mesh_target.devices.flat)}, "
             f"draft on {list(eng.mesh_draft.devices.flat)}")

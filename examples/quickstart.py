@@ -24,7 +24,7 @@ params["lm_head"].value = params["lm_head"].value * 4.0  # peaked logits
 # 2. engine: bs/w/c/d are the paper's knobs (§5.5)
 engine = SpecEngine(
     target=model, draft=model,
-    cfg=SpecConfig(bs=8, w=4, c=2, d=2, mode="parallel", max_new=32),
+    cfg=SpecConfig(bs=8, w=4, c=2, d=2, max_new=32),
     S_max_t=256, S_max_d=256,
 )
 

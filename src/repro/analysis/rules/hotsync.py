@@ -9,8 +9,7 @@ double-digit-percent regression that no test catches.
 
 Scope: the hot round methods only —
 
-  * ``SpecEngine.step`` / ``SpecEngine.generate`` (and the chain-engine
-    equivalents),
+  * every ``EngineSession`` / ``ChainSession`` method (the bound round API),
   * every ``EngineStepper`` method (the per-round admit/absorb/retire path),
   * ``ServingRuntimeBase.run`` (the fleet round loop).
 
@@ -28,10 +27,6 @@ from repro.analysis.core import FileContext, Finding, ImportMap, Rule, register
 
 # (class glob, method glob) pairs defining the hot path
 HOT_SCOPES = (
-    ("SpecEngine", "step"),
-    ("SpecEngine", "generate"),
-    ("ChainSpecEngine", "step"),
-    ("ChainSpecEngine", "generate"),
     ("EngineSession", "*"),      # the bound round API: every phase method is hot
     ("ChainSession", "*"),
     ("EngineStepper", "*"),

@@ -30,7 +30,6 @@ paper's latency regime; batch > 1 is served by replication.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any
 
 import jax
@@ -113,13 +112,6 @@ class ChainSpecEngine:
         API surface; mirrors ``SpecEngine.session``."""
         return ChainSession(self, tparams, dparams,
                             tracer=tracer or NULL_TRACER, track=track)
-
-    def generate(self, tparams, dparams, prompt, max_new=None):
-        warnings.warn(
-            "ChainSpecEngine.generate(tparams, dparams, prompt) is deprecated; "
-            "use ChainSpecEngine.session(tparams, dparams).generate(prompt)",
-            DeprecationWarning, stacklevel=2)
-        return self.session(tparams, dparams).generate(prompt, max_new=max_new)
 
 
 @dataclasses.dataclass

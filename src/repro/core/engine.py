@@ -4,10 +4,11 @@ Algorithm 1, Figure 3).
 The draft model lives on one device group (submesh), the target on another.
 JAX's asynchronous dispatch makes the two jitted programs run concurrently on
 disjoint device sets: the verify step for round n is enqueued first, then the
-d draft-tree expansions for round n+1 are enqueued on the draft group; the
-host blocks only on the tiny verified-token transfer (the paper's NCCL
-exchange).  ``mode="serial"`` is the SwiftSpec-base baseline (expand, then
-verify, no overlap).
+d draft-tree expansions and round n+1's tree, drafted on the predicted
+accept path, are enqueued on the draft group; the host blocks only on the
+tiny verified-token transfer (the paper's NCCL exchange).  That pipelined
+round (``EngineSession.begin_round``/``reconcile``) is the engine's only
+round.
 
 Greedy-verification invariant: the emitted stream equals target-only greedy
 decoding token-for-token (tests/test_engine.py).
@@ -17,7 +18,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 from typing import Any
 
 import jax
@@ -39,11 +39,8 @@ class SpecConfig:
     c: int = 2  # children proposed per expanded leaf
     d: int = 3  # tree expansions per round (profiled: ~t_target/t_draft)
     n_cap: int = 64  # tree node capacity
-    mode: str = "parallel"  # "parallel" | "serial"
     max_new: int = 64
     eos_id: int = -1  # -1: never stop early
-    draft_bypass: bool = False  # straggler mitigation: verify root-only chain
-    async_rounds: bool = False  # pipeline rounds: draft N+1's tree while N verifies
 
 
 @dataclasses.dataclass
@@ -59,7 +56,7 @@ class SpecStats:
     wall_s: float = 0.0
     emitted_rows: np.ndarray | None = None  # i64[B] per-row emitted totals
     accepted_rows: np.ndarray | None = None  # i64[B] per-row accepted totals
-    spec_rounds: int = 0  # rounds run through the async lookahead path
+    spec_rounds: int = 0  # rounds reconciled (each drafts a lookahead tree)
     spec_commits: int = 0  # of those, rounds whose lookahead tree was adopted
     # always-on round accounting (docs/observability.md), the same whether a
     # tracer is on or not: host seconds blocked in the round's one designated
@@ -115,12 +112,11 @@ class SpecStats:
 
 @dataclasses.dataclass
 class EngineState:
-    """Device-side state of one decode batch, advanced by ``SpecEngine.step``.
+    """Device-side state of one decode batch, advanced by ``EngineSession``.
 
     Treat it linearly: the jitted steps donate their cache/tree buffers, so a
-    state consumed by step()/admit_slot()/release_slot() must not be reused —
-    always thread the returned state forward (generate() and the serving
-    runtime both do)."""
+    state consumed by a round or by admit_slot()/release_slot() must not be
+    reused — the session threads the returned state forward."""
 
     tcache: Any  # target KV cache [U, B, S_max_t, ...]
     dcache: Any  # draft KV cache [U, B, S_max_d, ...]
@@ -149,7 +145,7 @@ class RoundInFlight:
     fields at reconcile time.
     """
 
-    plan: Any  # BatchPlan actually submitted to verify (post-bypass)
+    plan: Any  # BatchPlan submitted to verify
     tcache: Any  # verify-updated target cache (correct regardless of outcome)
     verify: tuple  # (acc_pos, n_acc, bonus, emitted, n_emitted) device futures
     snapshot: tuple | None = None  # (tr, dcache) post-expansion, pre-reroot
@@ -242,10 +238,6 @@ class SpecEngine:
         self.mesh_target, self.mesh_draft = mesh_target, mesh_draft
         window = target.cfg.sliding_window
         c = cfg
-        if c.async_rounds and c.mode != "parallel":
-            raise ValueError(
-                f"async_rounds requires mode='parallel' (got mode={c.mode!r}): "
-                "the lookahead pipeline IS the parallel overlap")
 
         # ----- jitted draft-side steps ------------------------------------
         def leaves(tr):
@@ -351,7 +343,7 @@ class SpecEngine:
         self._select_plan = jax.jit(select_plan)
         self._reroot = jax.jit(reroot, donate_argnums=(0,))
         self._kv_move = jax.jit(functools.partial(kv_move, donate=True), donate_argnums=(0,))
-        # async lookahead twins: the speculative re-root must NOT donate —
+        # lookahead twins: the speculative re-root must NOT donate —
         # the pre-reroot (tr, dcache) snapshot stays alive as the reconcile
         # fallback basis until the verify outcome lands on the host (and the
         # non-donating kv_move routes to the snapshot-preserving kernel)
@@ -469,7 +461,7 @@ class SpecEngine:
                 stats: SpecStats | None = None) -> "EngineSession":
         """Bind params (+ optional state, tracer and stats) into an
         ``EngineSession`` — the round API: ``session.step()`` / ``admit_slot``
-        / ``release_slot`` / ``generate``, plus the async phase methods
+        / ``release_slot`` / ``generate``, plus the round's phase methods
         ``dispatch_verify`` / ``draft_next_tree`` / ``reconcile``.  Pass
         ``n_slots`` to start from an empty parked serving state."""
         if state is None and n_slots is not None:
@@ -478,50 +470,6 @@ class SpecEngine:
             engine=self, tparams=tparams, dparams=dparams, state=state,
             tracer=tracer if tracer is not None else NULL_TRACER, track=track,
             stats=stats if stats is not None else SpecStats())
-
-    # --- one-release deprecation shims over the session API ---------------
-    def admit_slot(self, tparams, dparams, state: EngineState, slot: int, prompt) -> EngineState:
-        """Deprecated: use ``session(tparams, dparams, state=...).admit_slot``."""
-        warnings.warn(
-            "SpecEngine.admit_slot(tparams, dparams, state, ...) is deprecated; "
-            "bind an EngineSession via SpecEngine.session(...) instead",
-            DeprecationWarning, stacklevel=2)
-        s = self.session(tparams, dparams, state=state)
-        s.admit_slot(slot, prompt)
-        return s.state
-
-    def release_slot(self, state: EngineState, slot: int) -> EngineState:
-        """Deprecated: use ``EngineSession.release_slot``.
-
-        The old positional form never carried params, so the shim binds None —
-        release touches no model weights."""
-        warnings.warn(
-            "SpecEngine.release_slot(state, slot) is deprecated; "
-            "bind an EngineSession via SpecEngine.session(...) instead",
-            DeprecationWarning, stacklevel=2)
-        s = self.session(None, None, state=state)
-        s.release_slot(slot)
-        return s.state
-
-    def step(self, tparams, dparams, state: EngineState, stats: SpecStats | None = None,
-             tracer=None, trace_track: str = "engine"):
-        """Deprecated: use ``EngineSession.step``.  Returns (state', StepResult)."""
-        warnings.warn(
-            "SpecEngine.step(tparams, dparams, state, ...) is deprecated; "
-            "bind an EngineSession via SpecEngine.session(...) instead",
-            DeprecationWarning, stacklevel=2)
-        s = self.session(tparams, dparams, state=state, tracer=tracer, track=trace_track,
-                         stats=stats)
-        res = s.step(stats=stats)
-        return s.state, res
-
-    def generate(self, tparams, dparams, prompt, max_new=None):
-        """Deprecated: use ``session(tparams, dparams).generate(prompt)``."""
-        warnings.warn(
-            "SpecEngine.generate(tparams, dparams, prompt) is deprecated; "
-            "use SpecEngine.session(tparams, dparams).generate(prompt)",
-            DeprecationWarning, stacklevel=2)
-        return self.session(tparams, dparams).generate(prompt, max_new=max_new)
 
     def profile(self, tparams, dparams, prompt, iters: int = 3):
         """Paper §5.5 profile pass: wall-time one draft expansion and one
@@ -571,19 +519,6 @@ class SpecEngine:
         nothing)."""
         return (np.zeros((), np.int32),) if self.target.n_moe_layers else ()
 
-    def _bypass(self, plan):
-        """Straggler mitigation: degenerate to root-only verification."""
-        keep = jnp.arange(plan.tokens.shape[1]) == 0
-        return T.BatchPlan(
-            node_ids=plan.node_ids,
-            tokens=plan.tokens,
-            rows=jnp.where(keep[None, :], plan.rows, -1),
-            positions=plan.positions,
-            mask=plan.mask & keep[None, :, None],
-            parent_pos=plan.parent_pos,
-            valid=plan.valid & keep[None, :],
-        )
-
 
 @dataclasses.dataclass
 class EngineSession:
@@ -593,19 +528,16 @@ class EngineSession:
     session owns the linear ``EngineState`` and advances it in place.  One
     session per serving replica (``EngineStepper``) or per solo ``generate``.
 
-    Lockstep round (``async_rounds=False``)::
-
-        res = session.step()          # verify → expand → sync → reroot/grow
-
-    Pipelined round (``async_rounds=True``) — the paper's headline overlap::
+    The round is pipelined — the paper's headline overlap::
 
         rif = session.begin_round()   # dispatch_verify + draft_next_tree
         ...                           # other replicas dispatch here
         res = session.reconcile(rif)  # sync, adopt lookahead or roll back
 
-    Between ``begin_round`` and ``reconcile`` the session state is consumed
-    (buffers donated into the round) — ``admit_slot``/``release_slot``/
-    ``step`` must not run until the in-flight round reconciles.
+    ``session.step()`` runs the two back to back.  Between ``begin_round``
+    and ``reconcile`` the session state is consumed (buffers donated into
+    the round) — ``admit_slot``/``release_slot``/``step`` must not run until
+    the in-flight round reconciles.
 
     ``stats`` takes the always-on counters, whatever the tracer: every
     program the session dispatches (``SpecStats.dispatches``) and the host
@@ -709,19 +641,20 @@ class EngineSession:
             st.moe_layer_calls += eng.target.n_moe_layers
         return acc_pos, n_acc, bonus, emitted, n_emitted, tcache
 
-    def _reroot_grow(self, programs, tr, dcache, node_ids, outcome, n_grow: int):
+    def _reroot_grow(self, programs, tr, dcache, node_ids, outcome):
         """The re-root tail of every round, on the draft group: re-root
         ``tr`` on the verify ``outcome`` (acc_pos, n_acc, bonus, already on
         the draft group) of the batch ``node_ids``, move the draft KV under
         a ``kv_move`` span, fill the prefix and grow the first level in one
-        program (``_fill_grow``), grow ``n_grow - 1`` more levels and select
-        the next plan.  ``programs`` is the (reroot, kv_move) pair: the
-        donating one on the committed path, the snapshot-preserving one on
-        the lookahead.  Returns (tr', dcache', plan') and counts the
-        dispatches: one ``jit_fill_prefix`` per re-root and ``n_grow - 1``
-        ``jit_expand`` (the KV move, a ``functools.partial``, is
-        ``jit__unknown``)."""
+        program (``_fill_grow``), grow the rest of ``grow_per_round`` levels
+        and select the next plan.  ``programs`` is the (reroot, kv_move)
+        pair: the donating one on the rolled-back path, the
+        snapshot-preserving one on the lookahead.  Returns (tr', dcache',
+        plan') and counts the dispatches: one ``jit_fill_prefix`` per
+        re-root and ``grow_per_round - 1`` ``jit_expand`` (the KV move, a
+        ``functools.partial``, is ``jit__unknown``)."""
         eng = self.engine
+        n_grow = eng.grow_per_round
         reroot, kv_move = programs
         with _serving(eng.mesh_draft):
             tr, move, fillp = reroot(tr, node_ids, *outcome)
@@ -738,14 +671,13 @@ class EngineSession:
         return tr, dcache, plan
 
     # ------------------------------------------------------------------
-    # the round, lockstep
+    # the round
     # ------------------------------------------------------------------
     def step(self, stats: SpecStats | None = None,
              depth: int | None = None) -> StepResult:
-        """One round for every slot.  With ``async_rounds`` this is the
-        degenerate pipeline (begin + reconcile back-to-back — same tokens,
-        no cross-replica overlap); the serving runtime splits the two calls
-        to keep one verify and one draft outstanding per replica.
+        """One round for every slot: ``begin_round`` and ``reconcile`` back
+        to back (the same tokens; the serving runtime splits the two calls
+        to keep one verify and one draft outstanding per replica).
 
         ``depth`` is the round's effective draft depth — how many tree
         expansions this round runs — as a plain Python int (None: the
@@ -764,50 +696,11 @@ class EngineSession:
         jitted round.
 
         The session ``tracer`` records the round's host-side phase spans —
-        verify_dispatch / draft_expand / sync_emitted / reroot_grow (plus
-        draft_lookahead / reconcile on the async path) on ``track`` (one
-        track per serving replica); the default NULL_TRACER path is free."""
-        if self.engine.cfg.async_rounds:
-            return self.reconcile(self.begin_round(depth=depth), stats=stats)
-        self._check_quiescent("step")
-        eng, obs, track = self.engine, self.tracer, self.track
-        c, state = eng.cfg, self.state
-        d_eff = _effective_depth(depth, c.d)
-        plan = eng._bypass(state.plan) if c.draft_bypass else state.plan
-        tr, dcache = state.tr, state.dcache
-        draft_steps = 0
-        # --- dispatch verification on the target group (async) -------------
-        with obs.span("verify_dispatch", track):
-            acc_pos, n_acc, bonus, emitted, n_emitted, tcache = self._dispatch(plan, state.tcache)
-        # --- concurrently: d tree expansions on the draft group ------------
-        if c.mode == "parallel":
-            with obs.span("draft_expand", track):
-                with _serving(eng.mesh_draft):
-                    for _ in range(d_eff):
-                        tr, dcache = eng._expand(self.dparams, tr, dcache)
-                    draft_steps += d_eff
-                    self.stats.ran("jit_expand", d_eff)
-        # --- sync point: verified tokens cross groups (host-mediated) ------
-        # the verified-token transfer (paper's NCCL exchange), fused —
-        # everything else async
-        emitted_h, n_emitted_h, n_acc_h = self.sync_emitted(
-            obs, track, self.stats, (emitted, n_emitted, n_acc))
-        # --- re-root, fill, grow, select next batch (draft group) ----------
-        with obs.span("reroot_grow", track):
-            n_grow = d_eff if c.mode == "serial" else eng.grow_per_round
-            tr, dcache, new_plan = self._reroot_grow(
-                (eng._reroot, eng._kv_move), tr, dcache, plan.node_ids,
-                _to(eng.mesh_draft, (acc_pos, n_acc, bonus)), n_grow)
-            draft_steps += n_grow
-        self.state = EngineState(tcache, dcache, tr, new_plan)
-        if stats is not None:
-            stats.add_round(n_emitted_h, n_acc_h)
-            stats.draft_steps += draft_steps
-        return StepResult(np.asarray(emitted_h), np.asarray(n_emitted_h), np.asarray(n_acc_h))
+        verify_dispatch / draft_lookahead / sync_emitted / reconcile, with
+        kv_move inside them — on ``track`` (one track per serving replica);
+        the default NULL_TRACER path is free."""
+        return self.reconcile(self.begin_round(depth=depth), stats=stats)
 
-    # ------------------------------------------------------------------
-    # the round, disaggregated (async_rounds)
-    # ------------------------------------------------------------------
     def begin_round(self, depth: int | None = None) -> RoundInFlight:
         """Dispatch one full round without syncing: verify on the target
         group, then the speculative next-round draft on the draft group.
@@ -822,10 +715,10 @@ class EngineSession:
         on the trace it is the round's verify window and the overlap with
         ``draft_lookahead`` is directly measurable."""
         self._check_quiescent("dispatch_verify")
-        eng, state = self.engine, self.state
-        plan = eng._bypass(state.plan) if eng.cfg.draft_bypass else state.plan
+        plan = self.state.plan
         span = self.tracer.begin("verify_dispatch", self.track)
-        acc_pos, n_acc, bonus, emitted, n_emitted, tcache = self._dispatch(plan, state.tcache)
+        acc_pos, n_acc, bonus, emitted, n_emitted, tcache = self._dispatch(
+            plan, self.state.tcache)
         rif = RoundInFlight(
             plan=plan, tcache=tcache,
             verify=(acc_pos, n_acc, bonus, emitted, n_emitted),
@@ -859,7 +752,7 @@ class EngineSession:
             # for rollback
             rif.lookahead = self._reroot_grow(
                 (eng._spec_reroot, eng._spec_kv_move), tr, dcache, rif.plan.node_ids,
-                rif.pred, eng.grow_per_round)
+                rif.pred)
             rif.draft_steps += eng.grow_per_round
             self.stats.ran("jit_expand", d_eff)
             self.stats.ran("jit_predict_accept")
@@ -869,14 +762,12 @@ class EngineSession:
                   live=None) -> StepResult:
         """Sync the verify outcome and resolve the speculation: adopt the
         lookahead tree when the predicted accept path held, else roll back
-        to the retained snapshot and re-root on the actual path (the exact
-        lockstep tail, one round late).
+        to the retained snapshot and re-root on the actual path.
 
         ``live``: optional bool[B] row occupancy mask — prediction mismatches
         on parked rows are ignored (their trees never reach verification and
         admission fully overwrites the row).  Emitted tokens always come from
-        the actual verify, so outputs are byte-identical to lockstep on both
-        branches."""
+        the actual verify, so outputs are the same on both branches."""
         eng, obs, track = self.engine, self.tracer, self.track
         acc_pos, n_acc, bonus, emitted, n_emitted = rif.verify
         pred_acc, pred_n, pred_bonus = rif.pred
@@ -901,7 +792,7 @@ class EngineSession:
                 # the actual-path re-root consumes the snapshot (donating)
                 tr, dcache, new_plan = self._reroot_grow(
                     (eng._reroot, eng._kv_move), *rif.snapshot, rif.plan.node_ids,
-                    _to(eng.mesh_draft, (acc_pos, n_acc, bonus)), eng.grow_per_round)
+                    _to(eng.mesh_draft, (acc_pos, n_acc, bonus)))
                 draft_steps += eng.grow_per_round
         self.state = EngineState(rif.tcache, dcache, tr, new_plan)
         self._inflight = None

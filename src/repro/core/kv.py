@@ -14,9 +14,9 @@ under ``flags.use_pallas_kv_moves`` — the fused Pallas kernel that DMAs only
 the moved rows, O(B·M·F) HBM traffic instead of the two dense O(B·S·F)
 passes of the retired one-hot einsum formulation (docs/kernels.md).
 
-Speculative fork / rollback contract (async rounds): because every operation
-here is functional, a cache "snapshot" is just a retained reference — zero
-copies.  The async lookahead (``EngineSession.draft_next_tree``) keeps the
+Speculative fork / rollback contract (the round's lookahead): because every
+operation here is functional, a cache "snapshot" is just a retained
+reference — zero copies.  The lookahead (``EngineSession.draft_next_tree``) keeps the
 pre-reroot (tree, dcache) pair alive and re-roots through a NON-donating jit;
 if the lookahead seed is rejected, ``reconcile`` simply re-applies the move
 plan to the retained reference (exact rollback), and if it commits, dropping

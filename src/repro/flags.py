@@ -1,8 +1,7 @@
 """Trace-time feature flags (kernel selection, MoE impl, remat policy).
 
 Flags are read while tracing/jitting, so changing them re-specializes the
-compiled program.  They drive the §Perf hillclimb knobs and the ablation
-benchmark configurations (paper Fig. 8: kernels on/off x parallel on/off).
+compiled program.  They drive the §Perf hillclimb knobs (kernels on/off).
 """
 
 from __future__ import annotations

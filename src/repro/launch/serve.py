@@ -16,18 +16,17 @@ printed, and each finished output is checked byte-identical against the same
 request served alone (--no-verify to skip).  ``--replicas N`` shards the
 continuous runtime over N SpecEngine replicas on disjoint device groups
 (one global queue, least-loaded routing, per-replica + fleet telemetry).
-``--async-rounds`` turns on asynchronous round disaggregation
-(docs/async-disaggregation.md): each replica drafts round N+1's tree while
-round N verifies, reconciling on a rejected lookahead seed — outputs stay
-byte-identical to lockstep, and the traced ``draft_lookahead`` /
-``verify_dispatch`` overlap in the phase breakdown is the evidence.
+Every round is pipelined (docs/async-disaggregation.md): each replica drafts
+round N+1's tree while round N verifies, reconciling on a rejected lookahead
+seed, and the traced ``draft_lookahead`` / ``verify_dispatch`` overlap in
+the phase breakdown is the evidence.
 ``--adaptive-depth`` turns on per-slot adaptive draft depth and
 ``--deadline-s X`` stamps every request with a finish deadline X seconds
 after its arrival — EDF queueing, slack-aware routing, and an SLO
 attainment report (docs/scheduling.md); outputs stay byte-identical.
 ``--trace-out trace.json --metrics-out metrics.json`` records per-round
-phase spans (draft expand / verify / sync / reroot / absorb — viewable in
-ui.perfetto.dev) and a metrics snapshot with the round-time decomposition
+phase spans (verify / draft lookahead / sync / reconcile / absorb — viewable
+in ui.perfetto.dev) and a metrics snapshot with the round-time decomposition
 (repro.obs, docs/observability.md).
 ``--n-target``/``--n-draft`` select the disaggregated split carved once per
 replica (by default derived from the device count: on one device target and
@@ -102,21 +101,28 @@ def place_params(params, mesh):
     return jax.device_put(params, sharding_for_tree(mesh, params))
 
 
-def build_engine(cfgT: ModelConfig, cfgD: ModelConfig, *, mode="parallel",
+def build_engine(cfgT: ModelConfig, cfgD: ModelConfig, *,
                  bs=8, w=4, c=2, d=2, max_new=48, S_max=512, n_target=None, n_draft=None,
-                 peaked=True, replicas=1, async_rounds=False):
+                 peaked=True, replicas=1,
+                 # the benchmark harness (bench/run.py) still passes these two
+                 mode="parallel", async_rounds=True):
     """Build the serving engine(s) for a (target, draft) config pair.
 
     The devices are carved by ``make_serving_mesh`` (``n_target``/``n_draft``
     per replica; None derives the split from the device count, colocating
     both models on a one-device replica).  Each replica gets its own
     SpecEngine with parameters placed on its own target and draft meshes.
+    ``mode``/``async_rounds`` accept only the one schedule, ``"parallel"``
+    and ``True``.
     Returns (engine, tparams, dparams, cfgT) for ``replicas == 1`` and
     ([engines], [tparams], [dparams], cfgT) otherwise."""
+    if mode != "parallel" or async_rounds is not True:
+        raise ValueError(
+            f"the engine has one round schedule, the pipelined one: got mode={mode!r}, "
+            f"async_rounds={async_rounds!r}")
     assert cfgT.vocab_size == cfgD.vocab_size, "draft/target must share a vocab"
     T, D = make_model(cfgT), make_model(cfgD)
-    cfg = SpecConfig(bs=bs, w=w, c=c, d=d, mode=mode, max_new=max_new,
-                     async_rounds=async_rounds)
+    cfg = SpecConfig(bs=bs, w=w, c=c, d=d, max_new=max_new)
     pairs = make_serving_mesh(n_target, n_draft, replicas=replicas)
     pairs = [pairs] if replicas == 1 else pairs
     kt, kd = jax.random.PRNGKey(0), jax.random.PRNGKey(1)
@@ -251,7 +257,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--target-arch", default="qwen2.5-14b")
     ap.add_argument("--draft-arch", default="qwen2.5-14b")
-    ap.add_argument("--mode", choices=["parallel", "serial"], default="parallel")
     ap.add_argument("--requests", type=int, default=3)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=48)
@@ -264,11 +269,6 @@ def main(argv=None):
                     help="draft devices per replica; 0 colocates with the target")
     ap.add_argument("--continuous", action="store_true",
                     help="serve a Poisson trace through the continuous-batching runtime")
-    ap.add_argument("--async-rounds", action="store_true",
-                    help="asynchronous round disaggregation: draft round N+1's "
-                         "tree on the draft mesh while round N verifies on the "
-                         "target mesh (parallel mode only; outputs stay "
-                         "byte-identical to lockstep)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="continuous: SpecEngine replicas on disjoint device groups "
                          "(one global queue, depth-aware routing)")
@@ -299,9 +299,9 @@ def main(argv=None):
     replicas = args.replicas if args.continuous else 1
     cfgT, cfgD = serving_configs(args.target_arch, args.draft_arch)
     eng, tp, dp, cfgT = build_engine(
-        cfgT, cfgD, mode=args.mode, bs=args.bs, w=args.w,
+        cfgT, cfgD, bs=args.bs, w=args.w,
         d=args.d or 2, max_new=args.max_new, n_target=args.n_target, n_draft=args.n_draft,
-        replicas=replicas, async_rounds=args.async_rounds,
+        replicas=replicas,
     )
     eng0, tp0, dp0 = (eng[0], tp[0], dp[0]) if isinstance(eng, list) else (eng, tp, dp)
 
@@ -330,7 +330,7 @@ def main(argv=None):
         total_s += dt
         print(f"req {i}: {len(out[0])} tokens in {dt:.2f}s "
               f"({len(out[0])/dt:.1f} tok/s), compression {stats.compression_ratio:.2f}")
-    print(f"aggregate: {total_toks/total_s:.1f} tokens/s ({args.mode} mode)")
+    print(f"aggregate: {total_toks/total_s:.1f} tokens/s")
 
 
 if __name__ == "__main__":

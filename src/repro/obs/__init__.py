@@ -7,8 +7,8 @@ instrument that measures it end to end:
     ``Tracer`` — ring-buffered phase spans with monotonic timestamps,
     Chrome/Perfetto ``trace.json`` + JSONL export, and a zero-allocation
     disabled path (``NULL_TRACER``/``NOOP_SPAN``).  Woven through
-    ``SpecEngine.step`` (verify dispatch / draft expand / emitted sync /
-    reroot+grow), ``EngineStepper`` (admit prefill, absorb, retire) and the
+    ``EngineSession``'s round (verify dispatch / draft lookahead / emitted
+    sync / reconcile), ``EngineStepper`` (admit prefill, absorb, retire) and the
     serving runtimes (routing, queue pop, per-replica round spans).
 ``metrics``
     ``MetricsRegistry`` — labeled counters / gauges / fixed-bucket

@@ -3,33 +3,34 @@
 Folds a ``Tracer``'s spans into a per-round latency breakdown: how much of
 each serving round went to draft-tree work (expansion + KV reconciliation
 after re-root), target verification (dispatch + the verified-token device
-sync), and host-side absorption.  With async disaggregation on
-(``SpecConfig.async_rounds``) the breakdown additionally measures the
-pipeline's whole point: the wall time where ``draft_lookahead`` ran *inside*
-the open verify window (``overlap_draft_verify_s``), and the draft time that
-stayed serialized on the critical path (``draft_serialized_s`` /
-``draft_serialized_frac`` — the number async mode exists to shrink).
+sync), and host-side absorption.  The round is pipelined, so the breakdown
+also measures the pipeline's whole point: the wall time where
+``draft_lookahead`` ran *inside* the open verify window
+(``overlap_draft_verify_s``), and the draft time that stayed serialized on
+the critical path (``draft_serialized_s`` / ``draft_serialized_frac`` — the
+number the pipeline exists to shrink).
 
 Span taxonomy (docs/observability.md):
   round         one global serving round on one replica track
-  ├─ verify_dispatch   target verification window; lockstep: the enqueue
-  │                    only (async dispatch), async rounds: held open from
-  │                    dispatch until the verified tokens land
-  ├─ draft_expand      the d concurrent tree expansions (lockstep parallel mode)
-  ├─ draft_lookahead   async: next round's tree drafted on the predicted-
-  │                    accept path while verify is still in flight
+  ├─ verify_dispatch   target verification window: held open from dispatch
+  │                    until the verified tokens land
+  ├─ draft_lookahead   the round's d expansions, then next round's tree
+  │                    drafted on the predicted-accept path, while verify
+  │                    is still in flight
   ├─ sync_emitted      host sync on the verified-token transfer
-  ├─ reroot_grow       tree re-root + KV fill + regrow + next plan (lockstep)
-  ├─ reconcile         async: rollback + re-root after a rejected lookahead seed
-  └─ absorb            host-side token absorption / retire / stream
+  ├─ reconcile         rollback + re-root after a rejected lookahead seed
+  ├─ absorb            host-side token absorption / retire / stream
+  │  (chain sessions only:)
+  ├─ draft_expand      the chain's k draft tokens
+  └─ reroot_grow       commit of the accepted prefix
 
-``kv_move`` is a nested *detail* span (inside verify_dispatch, reroot_grow,
+``kv_move`` is a nested *detail* span (inside verify_dispatch,
 draft_lookahead, or reconcile): the KV-reorganization dispatch that the
 fused row-move kernels attack (docs/kernels.md).  It is reported on its own
 ``kv_move_s``/``kv_move_frac`` keys but deliberately kept out of
 ROUND_PHASES so the coverage/overlap unions never double-count its parent.
 
-Because async phases genuinely overlap (that is the feature), coverage and
+Because the pipelined phases genuinely overlap (that is the feature), coverage and
 the overlap metrics are computed on interval *unions* per round, never by
 summing durations — a nested span can't push coverage past 1.0 or count the
 same wall-clock millisecond twice.
@@ -49,7 +50,7 @@ PHASE_GROUPS = {
 # nested detail spans: measured and reported on their own keys but NEVER
 # part of the coverage/overlap unions — they live inside a ROUND_PHASES
 # parent (kv_move = the cache-reorganization dispatch inside verify_dispatch
-# / reroot_grow / draft_lookahead / reconcile; see docs/kernels.md)
+# / draft_lookahead / reconcile; see docs/kernels.md)
 DETAIL_PHASES = ("kv_move",)
 
 
@@ -163,9 +164,9 @@ def phase_breakdown(tracer) -> dict:
         },
         "coverage_mean": sum(coverages) / len(coverages) if coverages else nan,
         "coverage_min": min(coverages) if coverages else nan,
-        # async-pipeline evidence: draft wall time hidden under the verify
-        # window vs. still serialized on the critical path (union-based, so
-        # lockstep traces report overlap == 0.0 exactly)
+        # pipeline evidence: draft wall time hidden under the verify window
+        # vs. still serialized on the critical path (union-based, so a trace
+        # whose draft never runs inside a verify window reports 0.0 exactly)
         "overlap_draft_verify_s": overlap_s,
         "draft_serialized_s": draft_union_s - overlap_s,
         "draft_serialized_frac": (

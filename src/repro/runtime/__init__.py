@@ -1,4 +1,4 @@
-from repro.runtime.fault import FaultConfig, retry_step, StragglerPolicy
+from repro.runtime.fault import FaultConfig, retry_step
 from repro.runtime.elastic import reshard_engine, replan_split
 
-__all__ = ["FaultConfig", "retry_step", "StragglerPolicy", "reshard_engine", "replan_split"]
+__all__ = ["FaultConfig", "retry_step", "reshard_engine", "replan_split"]

@@ -1,16 +1,9 @@
-"""Fault tolerance: step retry with backoff, failure domains, straggler policy.
+"""Fault tolerance: step retry with backoff.
 
 At 1000+ nodes, the relevant failures are (a) transient device/runtime errors
-(retry the step — state is functional, so a retry is safe by construction),
-(b) lost nodes (restore from the last checkpoint onto the surviving mesh —
-ckpt/manager.py + runtime/elastic.py), and (c) stragglers.
-
-Straggler mitigation for the serving engine is *draft-bypass* (DESIGN.md §5):
-the asynchronous design means the target never waits on a slow draft group —
-if the draft misses its deadline, verification proceeds on the best
-already-available subtree and the engine degenerates gracefully toward
-autoregressive decoding instead of stalling.  For training, the mitigation is
-deterministic-data restart: any rank can be reconstructed from (seed, step).
+(retry the step — state is functional, so a retry is safe by construction)
+and (b) lost nodes (restore from the last checkpoint onto the surviving mesh —
+ckpt/manager.py + runtime/elastic.py).
 """
 
 from __future__ import annotations
@@ -55,35 +48,3 @@ def retry_step(fn: Callable[[], T], cfg: FaultConfig = FaultConfig(),
             time.sleep(delay)
             delay *= cfg.backoff_mult
     raise AssertionError("unreachable")
-
-
-@dataclasses.dataclass
-class StragglerPolicy:
-    """Deadline-based draft-bypass decision for the serving engine.
-
-    ``deadline_ratio``: the draft group must deliver within ratio × its
-    profiled time; beyond that the engine verifies the best available subtree
-    (SpecConfig.draft_bypass path).
-    """
-
-    t_draft_profiled_s: float
-    deadline_ratio: float = 3.0
-    window: int = 16  # sliding window of recent draft times
-
-    def __post_init__(self):
-        self._recent: list[float] = []
-
-    def observe(self, t_draft_s: float) -> None:
-        self._recent.append(t_draft_s)
-        if len(self._recent) > self.window:
-            self._recent.pop(0)
-
-    @property
-    def deadline_s(self) -> float:
-        return self.t_draft_profiled_s * self.deadline_ratio
-
-    def should_bypass(self) -> bool:
-        """True when the recent draft latency trend blows the deadline."""
-        if not self._recent:
-            return False
-        return self._recent[-1] > self.deadline_s

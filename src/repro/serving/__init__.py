@@ -4,7 +4,7 @@ speculative engine, from one engine to a sharded fleet.
 The paper's headline number is an end-to-end *serving* result: the
 disaggregated draft/target pipeline only pays off when it is kept full —
 and at scale, when many such pipelines are kept full at once.  This package
-turns the repo's one-shot ``SpecEngine.generate()`` into request runtimes
+turns the repo's one-shot ``EngineSession.generate()`` into request runtimes
 that multiplex many independent requests through per-slot lifecycles, on
 one engine or across N engine replicas on disjoint device groups.
 
@@ -28,8 +28,9 @@ Modules
 ``runtime``
     ``EngineStepper`` — the per-engine admit/absorb/retire loop over one
     ``SpecEngine`` state: solo prefill installed into a free slot's KV rows
-    + per-slot tree re-seed on admit, mixed-progress decode rounds through
-    ``SpecEngine.step`` with streaming, slot release + backfill on retire.
+    + per-slot tree re-seed on admit, mixed-progress pipelined decode
+    rounds (``EngineSession.begin_round``/``reconcile``) with streaming,
+    slot release + backfill on retire.
     ``ContinuousBatchingRuntime`` — one stepper over one queue (the single-
     engine serving loop).  ``WallClock`` / ``VirtualClock`` make trace
     replay real or deterministic.

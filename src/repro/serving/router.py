@@ -20,7 +20,7 @@ Per-replica admission: ``EngineStepper.admit`` dispatches the solo prefill
 onto the OWNING replica's device groups only.  JAX's asynchronous dispatch
 means the host enqueues replica A's (possibly long) prefill and moves
 straight on to replica B's decode round — the only host sync is each
-replica's own verified-token transfer inside ``SpecEngine.step`` — so a
+replica's own verified-token transfer inside ``EngineSession.reconcile`` — so a
 long prompt admitted on A never stalls decode progress on B.
 
 One global round of the fleet loop = every busy replica steps once (those

@@ -1,7 +1,8 @@
 """Continuous-batching serving: per-slot request lifecycles over SpecEngine.
 
-The engine's jitted round (``SpecEngine.step``) always advances all B batch
-rows; ``EngineStepper`` gives each row (a *slot*) its own request lifecycle:
+The engine's round (``EngineSession.begin_round``/``reconcile``) always
+advances all B batch rows; ``EngineStepper`` gives each row (a *slot*) its
+own request lifecycle:
 
   admit   — install an arrived request into a free slot (solo prefill into
             the slot's cache rows, per-slot tree re-seed) — neighbors keep
@@ -121,7 +122,7 @@ class EngineStepper:
     engine's own mesh pair, so in a sharded fleet one replica's admission
     prefill is enqueued asynchronously on its device groups and never
     blocks another replica's decode round (the host only syncs inside
-    ``SpecEngine.step``'s verified-token transfer).
+    ``EngineSession.reconcile``'s verified-token transfer).
     """
 
     def __init__(self, engine, tparams, dparams, n_slots: int, *,
@@ -235,13 +236,12 @@ class EngineStepper:
         self._m_admitted.inc()
         return slot
 
-    def step(self):
-        """Dispatch one engine round for every slot.  Lockstep: runs the full
-        round and returns its StepResult.  Async (``cfg.async_rounds``):
-        dispatches verify + the speculative next-round draft and returns the
-        ``RoundInFlight`` WITHOUT syncing — the host is free to step the
-        other replicas (the two-stage pipeline: one verify and one draft
-        outstanding per replica) until ``absorb_round`` reconciles it.
+    def step(self) -> RoundInFlight:
+        """Dispatch one engine round for every slot: verify + the
+        speculative next-round draft (``EngineSession.begin_round``).
+        Returns the ``RoundInFlight`` WITHOUT syncing — the host is free to
+        step the other replicas (the two-stage pipeline: one verify and one
+        draft outstanding per replica) until ``absorb_round`` reconciles it.
 
         With an adaptive-depth scheduler bound, the round's effective depth
         is the controller's decision for the CURRENT occupancy (max depth
@@ -252,8 +252,9 @@ class EngineStepper:
         Opens this replica's ``round`` span; ``absorb_round`` closes it (or
         ``abort_round`` on a failed fleet turn), so the span brackets
         dispatch through absorption — the engine's phase spans
-        (verify/draft/sync/reroot) plus ``absorb`` are its children.  The
-        same interval is timed, tracer or not, into ``ServerStats.round_s``."""
+        (verify_dispatch/draft_lookahead/sync_emitted/reconcile) plus
+        ``absorb`` are its children.  The same interval is timed, tracer or
+        not, into ``ServerStats.round_s``."""
         self._round_t0 = monotonic()
         self._round_sync0 = self.spec_stats.sync_s
         self._round_span = self.tracer.begin("round", self.track)
@@ -264,33 +265,29 @@ class EngineStepper:
                     [s is not None for s in self.slots])
             self.last_round_depth = self.engine.cfg.d if depth is None else depth
             self._round_span.set("depth", self.last_round_depth)
-            if self.engine.cfg.async_rounds:
-                return self.session.begin_round(depth=depth)
-            return self.session.step(stats=self.spec_stats, depth=depth)
+            return self.session.begin_round(depth=depth)
         except BaseException:
             # a failed dispatch must not leak the open round span
             self._round_span.end()
             self._round_span = NOOP_SPAN
             raise
 
-    def absorb_round(self, res, now: float) -> None:
-        """Fold one round's outcome into every occupied slot, retiring the
-        rows that finished (EOS / max_new / cache budget).  An in-flight
-        async round is reconciled here — prediction mismatches on
-        unoccupied rows are ignored (``live`` mask), since parked trees
-        never reach verification and admission overwrites the row.
+    def absorb_round(self, rif: RoundInFlight, now: float) -> None:
+        """Reconcile the in-flight round and fold its outcome into every
+        occupied slot, retiring the rows that finished (EOS / max_new /
+        cache budget).  Prediction mismatches on unoccupied rows are
+        ignored (``live`` mask), since parked trees never reach
+        verification and admission overwrites the row.
 
         The round span closes via try/finally: an absorb that raises (a
         failing stream callback, a poisoned record) must leave the tracer
         balanced, not with this replica's round span open forever."""
         try:
-            if isinstance(res, RoundInFlight):
-                pre = self.spec_stats.spec_commits
-                res = self.session.reconcile(
-                    res, stats=self.spec_stats,
-                    live=[s is not None for s in self.slots])
-                if self.spec_stats.spec_commits > pre:
-                    self._m_spec_commits.inc()
+            pre = self.spec_stats.spec_commits
+            res = self.session.reconcile(
+                rif, stats=self.spec_stats, live=[s is not None for s in self.slots])
+            if self.spec_stats.spec_commits > pre:
+                self._m_spec_commits.inc()
             self._m_occupancy.append(now, self.occupied)  # pre-retire, as stats does
             self._m_depth.append(now, self.last_round_depth)
             with self.tracer.span("absorb", self.track):
@@ -307,17 +304,15 @@ class EngineStepper:
             self._round_span.end()
             self._round_span = NOOP_SPAN
 
-    def abort_round(self, res) -> None:
+    def abort_round(self, rif: RoundInFlight) -> None:
         """Abandon a dispatched round whose ``absorb_round`` will never run
         (another replica's absorb raised and the fleet loop is unwinding).
-        An in-flight async round is reconciled and its result discarded —
-        the session's buffers were donated into the round, so dropping the
+        The round is reconciled and its result discarded — the session's
+        buffers were donated into the round, so dropping the
         ``RoundInFlight`` on the floor would orphan the session — and the
         open round span is closed so the tracer stays balanced."""
         try:
-            if isinstance(res, RoundInFlight):
-                self.session.reconcile(
-                    res, live=[s is not None for s in self.slots])
+            self.session.reconcile(rif, live=[s is not None for s in self.slots])
         finally:
             self._round_span.end()
             self._round_span = NOOP_SPAN
@@ -540,12 +535,12 @@ class ServingRuntimeBase:
                 self.tracer.counter("queue_depth", qdepth)
                 self.tracer.counter("occupied", self.occupied)
                 while stepped:
-                    st, res = stepped.pop(0)
+                    st, rif = stepped.pop(0)
                     st.stats.on_round(st.occupied, qdepth)
-                    st.absorb_round(res, now)
+                    st.absorb_round(rif, now)
             except BaseException:
-                for st, res in stepped:
-                    st.abort_round(res)
+                for st, rif in stepped:
+                    st.abort_round(rif)
                 raise
         t1 = self.clock.now()
         for st in self.steppers:

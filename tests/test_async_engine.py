@@ -1,22 +1,21 @@
-"""Async round disaggregation (``SpecConfig.async_rounds``).
+"""The pipelined round (``EngineSession.dispatch_verify`` /
+``draft_next_tree`` / ``reconcile``).
 
-The contract under test: the pipelined dispatch_verify / draft_next_tree /
-reconcile path changes WHEN draft work happens (round N+1's tree is drafted
-while round N verifies), never which tokens verify emits — so at temperature
-0 every surface (solo generate, continuous batching, the 2-replica sharded
-fleet) is byte-identical to the lockstep path, whether the lookahead seed
-commits or is rolled back.  Plus the reconcile rollback itself: a forced
-rejected seed must take the snapshot + re-root path and still emit the
-lockstep bytes, and the traced async run must show draft work genuinely
-overlapping the open verify window (lockstep shows exactly zero).
+The contract under test: the pipeline changes WHEN draft work happens (round
+N+1's tree is drafted while round N verifies), never which tokens verify
+emits — so at temperature 0 every surface (solo generate, continuous
+batching, the 2-replica sharded fleet) equals target-only greedy decoding,
+whether the lookahead seed commits or is rolled back.  Plus the reconcile
+rollback itself: a forced rejected seed must take the snapshot + re-root
+path and emit what an untampered session emits, and the traced run must
+show draft work genuinely overlapping the open verify window.
 """
-
-import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import greedy_reference
 from repro.core.engine import SpecConfig, SpecEngine, SpecStats
 from repro.obs import Tracer, phase_breakdown
 from repro.serving import (
@@ -26,7 +25,7 @@ from repro.serving import (
     VirtualClock,
 )
 
-CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
 
 
 def _prompt(k, P=8):
@@ -35,73 +34,64 @@ def _prompt(k, P=8):
 
 @pytest.fixture(scope="module")
 def engines(dense_pair):
-    """Lockstep/async engine pairs: self-draft (draft == target, so the
-    lookahead seed should usually commit) and independent-draft (tiny random
-    draft disagrees with the target, so reconcile runs every round)."""
+    """Self-draft (draft == target, so the lookahead seed should usually
+    commit) and independent-draft (tiny random draft disagrees with the
+    target, so reconcile rolls back nearly every round) engines."""
     T, D, tp, dp = dense_pair
 
-    def mk(tgt, dr, **kw):
-        return SpecEngine(tgt, dr, SpecConfig(**CFG, **kw),
-                          S_max_t=256, S_max_d=256)
+    def mk(tgt, dr):
+        return SpecEngine(tgt, dr, SpecConfig(**CFG), S_max_t=256, S_max_d=256)
 
-    return {
-        "lock_self": mk(T, T), "async_self": mk(T, T, async_rounds=True),
-        "lock_td": mk(T, D), "async_td": mk(T, D, async_rounds=True),
-    }, tp, dp
+    return {"self": mk(T, T), "td": mk(T, D)}, tp, dp
 
 
-def test_async_requires_parallel_mode(dense_pair):
-    T, D, *_ = dense_pair
-    with pytest.raises(ValueError, match="async_rounds"):
-        SpecEngine(T, D, SpecConfig(**{**CFG, "mode": "serial"},
-                                    async_rounds=True),
-                   S_max_t=256, S_max_d=256)
+def _greedy(eng, tp, prompt, n):
+    return greedy_reference(eng.target, tp, prompt, n)
 
 
 # ---------------------------------------------------------------------------
-# solo generate: commit path and fallback path both byte-identical
+# solo generate: commit path and fallback path both exact
 # ---------------------------------------------------------------------------
 
 
 def test_solo_self_draft_identical_and_commits(engines):
     """Self-draft: predictions hold, so the pre-drafted lookahead tree is
-    adopted (spec_commits > 0) and outputs still equal lockstep exactly."""
+    adopted (spec_commits > 0) and outputs still equal greedy decoding."""
     e, tp, dp = engines
     prompt = _prompt(3).reshape(1, -1)
-    out_lock, _ = e["lock_self"].session(tp, tp).generate(prompt)
-    out_async, st = e["async_self"].session(tp, tp).generate(prompt)
-    assert out_async == out_lock
+    out, st = e["self"].session(tp, tp).generate(prompt)
+    assert out == _greedy(e["self"], tp, prompt, CFG["max_new"])
     assert st.spec_rounds == st.rounds > 0
     assert st.spec_commits > 0, "self-draft lookahead seed never committed"
 
 
 def test_solo_independent_draft_identical(engines):
     """Independent tiny draft: the target disagrees, the seed is rejected,
-    reconcile rolls back every round — bytes still equal lockstep."""
+    reconcile rolls back nearly every round — bytes still equal greedy."""
     e, tp, dp = engines
     prompt = _prompt(5).reshape(1, -1)
-    out_lock, _ = e["lock_td"].session(tp, dp).generate(prompt)
-    out_async, st = e["async_td"].session(tp, dp).generate(prompt)
-    assert out_async == out_lock
+    out, st = e["td"].session(tp, dp).generate(prompt)
+    assert out == _greedy(e["td"], tp, prompt, CFG["max_new"])
+    assert st.spec_commits < st.rounds
     assert st.spec_rounds == st.rounds > 0
 
 
-def test_forced_rejection_every_round_still_identical(engines):
+@pytest.mark.parametrize("B", [1, 2], ids=["B1", "B2"])
+def test_forced_rejection_every_round_still_identical(engines, B):
     """Sabotage the predictor so the seed can never match (a real bonus
     token is always >= 0): every round must take the rollback path and the
-    output must not change by a byte."""
+    output must not change by a byte, for one row and for two."""
     e, tp, dp = engines
-    eng = e["async_self"]
-    prompt = _prompt(7).reshape(1, -1)
-    out_lock, _ = e["lock_self"].session(tp, tp).generate(prompt)
+    eng = e["self"]
+    prompt = np.stack([_prompt(7 + b) for b in range(B)])
 
     real = eng._predict
     try:
         eng._predict = lambda *a: (lambda p: (p[0], p[1], jnp.full_like(p[2], -1)))(real(*a))
-        out_async, st = eng.session(tp, tp).generate(prompt)
+        out, st = eng.session(tp, tp).generate(prompt)
     finally:
         eng._predict = real
-    assert out_async == out_lock
+    assert out == _greedy(eng, tp, prompt, CFG["max_new"])
     assert st.spec_rounds > 0 and st.spec_commits == 0
 
 
@@ -111,16 +101,17 @@ def test_forced_rejection_every_round_still_identical(engines):
 
 
 def test_reconcile_rolls_back_rejected_seed(engines):
-    """Drive the phase API by hand against a lockstep twin: tamper each
-    round's prediction so reconcile MUST reject the lookahead and re-root
-    from the retained snapshot — per-round results stay identical."""
+    """Drive the phase API by hand beside an untampered session: tamper
+    each round's prediction so reconcile MUST reject the lookahead and
+    re-root from the retained snapshot — per-round results stay
+    identical."""
     e, tp, dp = engines
-    lock, asyn = e["lock_self"], e["async_self"]
+    eng = e["self"]
     prompt = _prompt(4).reshape(1, -1)
-    ref = lock.session(tp, tp)
-    ref.state = lock._prefill_state(tp, tp, prompt)
-    sess = asyn.session(tp, tp)
-    sess.state = asyn._prefill_state(tp, tp, prompt)
+    ref = eng.session(tp, tp)
+    ref.state = eng._prefill_state(tp, tp, prompt)
+    sess = eng.session(tp, tp)
+    sess.state = eng._prefill_state(tp, tp, prompt)
 
     for _ in range(3):
         rif = sess.begin_round()
@@ -139,7 +130,7 @@ def test_dispatch_while_in_flight_is_an_error(engines):
     """The donated-state discipline: a second dispatch (or admit/release)
     before reconcile must fail loudly, not corrupt the round."""
     e, tp, dp = engines
-    sess = e["async_self"].session(tp, tp, n_slots=1)
+    sess = e["self"].session(tp, tp, n_slots=1)
     sess.admit_slot(0, _prompt(2))
     rif = sess.begin_round()
     with pytest.raises(RuntimeError, match="in flight"):
@@ -150,7 +141,7 @@ def test_dispatch_while_in_flight_is_an_error(engines):
 
 
 # ---------------------------------------------------------------------------
-# serving: continuous and 2-replica sharded, byte-identical to lockstep
+# serving: continuous and 2-replica sharded, byte-identical to greedy
 # ---------------------------------------------------------------------------
 
 
@@ -160,26 +151,30 @@ def _serve(rt, reqs):
     return rt.run()
 
 
+def _greedy_each(eng, tp, reqs):
+    return {r.rid: _greedy(eng, tp, r.prompt.reshape(1, -1), r.max_new)[0] for r in reqs}
+
+
 def test_continuous_async_matches_lockstep(engines):
+    """Continuous batching over a self-draft engine (lookaheads commit):
+    every request emits its target-only greedy stream."""
     e, tp, dp = engines
     reqs = [Request(rid=i, prompt=_prompt(i + 1, P=8 + 4 * (i % 2)),
                     arrival_s=0.5 * i, max_new=12) for i in range(4)]
-    lock = _serve(ContinuousBatchingRuntime(
-        e["lock_self"], tp, tp, n_slots=2, clock=VirtualClock()), reqs)
-    asy = _serve(ContinuousBatchingRuntime(
-        e["async_self"], tp, tp, n_slots=2, clock=VirtualClock()), reqs)
-    assert asy == lock and sorted(asy) == [0, 1, 2, 3]
+    got = _serve(ContinuousBatchingRuntime(
+        e["self"], tp, tp, n_slots=2, clock=VirtualClock()), reqs)
+    assert got == _greedy_each(e["self"], tp, reqs)
 
 
 def test_sharded_async_matches_lockstep(engines):
+    """Two replicas over an independent draft (lookaheads roll back):
+    every request emits its target-only greedy stream."""
     e, tp, dp = engines
     reqs = [Request(rid=i, prompt=_prompt(i + 2), arrival_s=0.4 * i, max_new=10)
             for i in range(4)]
-    lock = _serve(ShardedServingRuntime(
-        [e["lock_td"]] * 2, tp, dp, n_slots=2, clock=VirtualClock()), reqs)
-    asy = _serve(ShardedServingRuntime(
-        [e["async_td"]] * 2, tp, dp, n_slots=2, clock=VirtualClock()), reqs)
-    assert asy == lock and sorted(asy) == [0, 1, 2, 3]
+    got = _serve(ShardedServingRuntime(
+        [e["td"]] * 2, tp, dp, n_slots=2, clock=VirtualClock()), reqs)
+    assert got == _greedy_each(e["td"], tp, reqs)
 
 
 # ---------------------------------------------------------------------------
@@ -191,18 +186,14 @@ def test_traced_overlap_async_nonzero_lockstep_zero(engines):
     e, tp, dp = engines
     reqs = [Request(rid=i, prompt=_prompt(i + 1), arrival_s=0.0, max_new=10)
             for i in range(2)]
-    bds = {}
-    for key in ("lock_self", "async_self"):
-        tracer = Tracer()
-        _serve(ContinuousBatchingRuntime(
-            e[key], tp, tp, n_slots=2, clock=VirtualClock(), tracer=tracer), reqs)
-        bds[key] = phase_breakdown(tracer)
-    lock, asy = bds["lock_self"], bds["async_self"]
-    assert lock["overlap_draft_verify_s"] == 0.0
+    tracer = Tracer()
+    _serve(ContinuousBatchingRuntime(
+        e["self"], tp, tp, n_slots=2, clock=VirtualClock(), tracer=tracer), reqs)
+    asy = phase_breakdown(tracer)
     # structural overlap assertions are deterministic; the hard >=0.95
     # coverage gate lives in test_obs + the CI smoke, where rounds are long
     # enough not to flake under CPU contention — here just sanity-check it
-    assert lock["coverage_mean"] > 0.5 and asy["coverage_mean"] > 0.5
+    assert asy["coverage_mean"] > 0.5
     assert asy["overlap_draft_verify_s"] > 0.0
     assert asy["phase_s"]["draft_lookahead"] > 0.0
     # the whole point: less draft time serialized on the critical path

@@ -26,7 +26,7 @@ def test_chain_greedy_equality_self_draft(arch, mode):
     prompt = (np.arange(1, 9, dtype=np.int32) % cfg.vocab_size).reshape(1, 8)
     ref = greedy_reference(T, tp, prompt, 24)
     eng = ChainSpecEngine(T, T, ChainConfig(k=4, mode=mode, max_new=24), 256, 256)
-    out, stats = eng.generate(tp, tp, prompt)
+    out, stats = eng.session(tp, tp).generate(prompt)
     assert out[0] == ref[0]
     # self-draft on peaked logits accepts aggressively
     assert stats.compression_ratio > 1.5
@@ -43,7 +43,7 @@ def test_chain_greedy_equality_independent_draft(arch):
     ref = greedy_reference(T, tp, prompt, 20)
     for mode in ("serial", "parallel"):
         eng = ChainSpecEngine(T, T, ChainConfig(k=4, mode=mode, max_new=20), 256, 256)
-        out, _ = eng.generate(tp, dp, prompt)
+        out, _ = eng.session(tp, dp).generate(prompt)
         assert out[0] == ref[0], mode
 
 

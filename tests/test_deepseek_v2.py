@@ -174,8 +174,7 @@ def test_direct_query_mla_has_no_query_latent():
 
 def test_verify_counts_experts_on_the_device():
     cfgT, cfgD = serving_configs("deepseek-v2-lite", "deepseek-v2-lite-draft")
-    eng, tp, dp, _ = build_engine(cfgT, cfgD, bs=8, w=4, c=2, d=2, max_new=16, S_max=96,
-                                  async_rounds=True)
+    eng, tp, dp, _ = build_engine(cfgT, cfgD, bs=8, w=4, c=2, d=2, max_new=16, S_max=96)
     sess = eng.session(tp, dp, n_slots=1)
     sess.admit_slot(0, (np.arange(1, 13) * 7 % 256).astype(np.int32))
     for _ in range(3):

@@ -21,18 +21,17 @@ from repro.core.tree import select_leaves
 from repro.core.engine import SpecConfig, SpecEngine
 from repro.models.api import make_model
 
-CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
 PROMPT = ((np.arange(1, 9) * 5 + 3) % 128).astype(np.int32).reshape(1, -1)
 
 
 @pytest.fixture(scope="module")
 def engines(dense_pair):
-    """A lockstep engine over the independent draft, and an async one whose
-    draft is the target itself, so its rounds accept tokens."""
+    """An engine over the independent draft, and one whose draft is the
+    target itself, so its rounds accept tokens."""
     T, D, tp, dp = dense_pair
-    return {"lockstep": (SpecEngine(T, D, SpecConfig(**CFG), S_max_t=256, S_max_d=256), D, dp),
-            "async": (SpecEngine(T, T, SpecConfig(**CFG, async_rounds=True),
-                                 S_max_t=256, S_max_d=256), T, tp)}, tp
+    return {"independent": (SpecEngine(T, D, SpecConfig(**CFG), S_max_t=256, S_max_d=256), D, dp),
+            "self": (SpecEngine(T, T, SpecConfig(**CFG), S_max_t=256, S_max_d=256), T, tp)}, tp
 
 
 def _copy(tree):
@@ -130,7 +129,7 @@ def _assert_same_growth(tr, ref, got):
 @pytest.mark.parametrize("case", ["empty_fill", "several_filled", "reconcile_rollback"])
 def test_fused_fill_grows_what_fill_then_expand_grows(engines, case):
     engines, tp = engines
-    eng, D, dp = engines["async" if case == "reconcile_rollback" else "lockstep"]
+    eng, D, dp = engines["self" if case == "reconcile_rollback" else "independent"]
     if case == "reconcile_rollback":
         tr, dcache, fill = _rolled_back(eng, tp, dp)
     else:

@@ -3,10 +3,11 @@ reference (kernels/ref.kv_move_rows_ref) vs a numpy loop oracle.
 
 The contract: byte-identical moves under parallel-assignment semantics for
 overlapping src/dst windows, ``-1`` sources, duplicate masked destinations,
-and empty plans; the non-donating variant never mutates its input (the async
-snapshot/rollback contract of core/kv.py); and the whole engine — lockstep,
-async commit AND async rollback, and 2-replica sharded serving — emits the
-same bytes with the fused kernels enabled as the reference path does.
+and empty plans; the non-donating variant never mutates its input (the
+lookahead's snapshot/rollback contract of core/kv.py); and the whole engine
+— solo, the lookahead's commit AND rollback, and 2-replica sharded serving —
+emits the same bytes with the fused kernels enabled as the reference path
+(or target-only greedy decoding) does.
 """
 
 import dataclasses
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from conftest import greedy_reference
 from repro.core import kv as kvm
 from repro.core.engine import SpecConfig, SpecEngine
 from repro.flags import override_flags
@@ -201,7 +203,7 @@ def test_slot_write_rows_pallas_rejects_bad_leaves():
 # engine surfaces: fused path byte-identical to the reference path
 # ---------------------------------------------------------------------------
 
-ECFG = dict(bs=4, w=2, c=2, d=1, n_cap=16, mode="parallel", max_new=8)
+ECFG = dict(bs=4, w=2, c=2, d=1, n_cap=16, max_new=8)
 
 
 def _prompt(k, P=8):
@@ -215,8 +217,7 @@ def fused_engines(dense_pair):
     def mk(tgt, dr, **kw):
         return SpecEngine(tgt, dr, SpecConfig(**ECFG, **kw), S_max_t=256, S_max_d=256)
 
-    return {"ref": mk(T, D), "fused": mk(T, D),
-            "fused_self": mk(T, T), "async_self": mk(T, T, async_rounds=True),
+    return {"ref": mk(T, D), "fused": mk(T, D), "fused_self": mk(T, T),
             "sharded_ref": mk(T, D), "sharded_fused": mk(T, D)}, tp, dp
 
 
@@ -230,28 +231,28 @@ def test_solo_generate_fused_identical(fused_engines):
 
 
 def test_async_commit_and_rollback_fused_identical(fused_engines):
-    """The satellite regression: with the fused kernels on, the async
-    pipeline's commit path (self-draft, lookahead adopted) AND the rollback
-    path (sabotaged predictor, reconcile re-roots the retained snapshot)
-    both stay byte-identical to lockstep — i.e. the copy-through kernel
-    really preserved the snapshot and the donating kernel really moved the
-    rows the reference would have."""
+    """With the fused kernels on, the round's commit path (self-draft,
+    lookahead adopted) AND the rollback path (sabotaged predictor, reconcile
+    re-roots the retained snapshot) both stay byte-identical to target-only
+    greedy decoding — i.e. the copy-through kernel really preserved the
+    snapshot and the donating kernel really moved the rows the reference
+    would have."""
     e, tp, dp = fused_engines
     prompt = _prompt(5).reshape(1, -1)
+    eng = e["fused_self"]
+    out_ref = greedy_reference(eng.target, tp, prompt, ECFG["max_new"])
     with override_flags(use_pallas_kv_moves=True):
-        out_lock, _ = e["fused_self"].session(tp, tp).generate(prompt)
-        asyn = e["async_self"]
-        out_commit, st = asyn.session(tp, tp).generate(prompt)
-        assert out_commit == out_lock
+        out_commit, st = eng.session(tp, tp).generate(prompt)
+        assert out_commit == out_ref
         assert st.spec_commits > 0, "commit path never exercised"
-        real = asyn._predict
+        real = eng._predict
         try:  # force the rollback branch every round
-            asyn._predict = lambda *a: (
+            eng._predict = lambda *a: (
                 lambda p: (p[0], p[1], jnp.full_like(p[2], -1)))(real(*a))
-            out_rb, st = asyn.session(tp, tp).generate(prompt)
+            out_rb, st = eng.session(tp, tp).generate(prompt)
         finally:
-            asyn._predict = real
-        assert out_rb == out_lock
+            eng._predict = real
+        assert out_rb == out_ref
         assert st.spec_rounds > 0 and st.spec_commits == 0
 
 

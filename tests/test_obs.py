@@ -319,7 +319,7 @@ def test_merge_summary_no_replicas_is_nan_marked():
 @pytest.fixture(scope="module")
 def obs_engine(dense_pair):
     T, D, tp, dp = dense_pair
-    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
     return SpecEngine(T, D, cfg, S_max_t=256, S_max_d=256), tp, dp
 
 
@@ -348,8 +348,9 @@ def test_traced_continuous_run_covers_rounds(obs_engine):
     bd = phase_breakdown(tracer)
     assert bd["n_rounds"] == rt.stats.rounds
     assert bd["coverage_min"] >= 0.95, breakdown_report(bd)
-    for phase in ("verify_dispatch", "draft_expand", "sync_emitted",
-                  "reroot_grow", "absorb"):
+    # an independent draft: the lookahead is rolled back, so reconcile runs
+    for phase in ("verify_dispatch", "draft_lookahead", "sync_emitted",
+                  "reconcile", "absorb"):
         assert bd["phase_s"][phase] > 0.0, f"phase {phase} never recorded"
     # admission + routing instrumented too
     assert len(tracer.spans("admit_prefill")) == 4
@@ -382,7 +383,7 @@ def test_untraced_run_is_unchanged(obs_engine):
     rt = ContinuousBatchingRuntime(eng, tp, dp, n_slots=1, clock=VirtualClock())
     rt.submit(Request(rid=0, prompt=_prompt(5), max_new=8))
     results = rt.run()
-    solo, _ = eng.generate(tp, dp, _prompt(5).reshape(1, -1), max_new=8)
+    solo, _ = eng.session(tp, dp).generate(_prompt(5).reshape(1, -1), max_new=8)
     assert results[0] == solo[0]
     assert rt.tracer is NULL_TRACER and len(NULL_TRACER.spans()) == 0
     assert rt.metrics.counter("serving_finished_total", replica="0").value == 1

@@ -11,30 +11,32 @@ from repro.core.engine import SpecConfig, SpecEngine
 from repro.obs import NULL_TRACER, Tracer, phase_breakdown
 from repro.serving import ContinuousBatchingRuntime, Request, VirtualClock
 
-CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+CFG = dict(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
 
 
 def _prompt(k, P=8):
     return ((np.arange(1, P + 1) * k + 3) % 128).astype(np.int32)
 
 
-# engine variants: the two round paths, one whose re-root grows two levels
-# (grow_per_round 2) and the serial mode, whose re-root grows all d levels
-VARIANTS = {"lockstep": {}, "async": dict(async_rounds=True),
-            "async-bs16": dict(async_rounds=True, bs=16), "serial": dict(mode="serial")}
+# engine variants: an independent draft, so the lookahead is rolled back,
+# and the target as its own draft, so the lookahead is adopted; each also
+# at bs=16, whose re-root grows two levels (grow_per_round 2)
+VARIANTS = {"rollback": ("independent", {}), "rollback-bs16": ("independent", dict(bs=16)),
+            "commit": ("self", {}), "commit-bs16": ("self", dict(bs=16))}
 
 
 @pytest.fixture(scope="module")
 def engines(dense_pair):
-    """The engine variants over an independent draft, so the async lookahead
-    is rolled back."""
+    """The engine variants, with the draft params each one runs on."""
     T, D, tp, dp = dense_pair
-    return {name: SpecEngine(T, D, SpecConfig(**{**CFG, **kw}), S_max_t=256, S_max_d=256)
-            for name, kw in VARIANTS.items()}, tp, dp
+    drafts = {"independent": (D, dp), "self": (T, tp)}
+    return {name: (SpecEngine(T, drafts[draft][0], SpecConfig(**{**CFG, **kw}),
+                              S_max_t=256, S_max_d=256), drafts[draft][1])
+            for name, (draft, kw) in VARIANTS.items()}, tp
 
 
 def _serve(engines, mode, tracer=None):
-    eng, tp, dp = engines[0][mode], engines[1], engines[2]
+    (eng, dp), tp = engines[0][mode], engines[1]
     rt = ContinuousBatchingRuntime(eng, tp, dp, n_slots=2, clock=VirtualClock(),
                                    tracer=tracer)
     rt.submit_trace([Request(rid=i, prompt=_prompt(i + 1, P=8 + 4 * (i % 2)),
@@ -53,28 +55,23 @@ def test_every_round_dispatches_one_verify(engines, mode):
     # four admissions, four retirements
     assert spec.dispatches["jit_target_prefill"] == spec.dispatches["jit_draft_prefill"] == 4
     assert spec.dispatches["jit_install_slot"] == spec.dispatches["jit_zero_slot"] == 8
-    eng = engines[0][mode]
-    if eng.cfg.async_rounds:
-        # a speculative re-root every round, a second on each rolled-back one
-        rollbacks = spec.spec_rounds - spec.spec_commits
-        assert rollbacks > 0
-        assert spec.dispatches["jit_predict_accept"] == n
-        reroots = n + rollbacks
-    else:
-        reroots = n
+    eng = engines[0][mode][0]
+    # a speculative re-root every round, a second on each rolled-back one
+    rollbacks = spec.spec_rounds - spec.spec_commits
+    assert spec.spec_rounds == n
+    assert (rollbacks if mode.startswith("rollback") else spec.spec_commits) > 0
+    assert spec.dispatches["jit_predict_accept"] == n
+    reroots = n + rollbacks
     # each re-root fills the prefix and grows the first level in one program
     assert (spec.dispatches["jit_reroot"] == spec.dispatches["jit__unknown"]
             == spec.dispatches["jit_fill_prefix"] == reroots)
-    # d per round in parallel mode, the rest of each re-root's growth (all d
-    # levels in serial mode), and each admission's
+    # d per round, the rest of each re-root's growth, and each admission's
     grow, d = eng.grow_per_round, eng.cfg.d
-    parallel = eng.cfg.mode == "parallel"
-    n_grow = grow if parallel else d
     assert grow == (2 if eng.cfg.bs == 16 else 1)
-    assert spec.dispatches["jit_expand"] == (d * n if parallel else 0) + (n_grow - 1) * reroots + grow * 4
+    assert spec.dispatches["jit_expand"] == d * n + (grow - 1) * reroots + grow * 4
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("mode", ["rollback", "commit"])
 def test_round_time_is_the_round_spans(engines, mode):
     """``round_s`` covers the ``round`` spans' interval and ``sync_s`` the
     ``sync_emitted`` spans', so each agrees with the recording tracer's
@@ -93,7 +90,7 @@ def test_round_time_is_the_round_spans(engines, mode):
     assert 0 < server.round_max_s <= longest + 1e-3
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("mode", ["rollback", "commit"])
 def test_counters_do_not_depend_on_the_tracer(engines, mode):
     """The same run counts the same dispatches and rounds untraced, under a
     recording tracer and under the benchmark's profiler tracer."""
@@ -115,7 +112,7 @@ def test_counters_do_not_depend_on_the_tracer(engines, mode):
 
 
 def test_generate_counts_into_its_stats(engines):
-    eng, tp, dp = engines[0]["async"], engines[1], engines[2]
+    (eng, dp), tp = engines[0]["rollback"], engines[1]
     session = eng.session(tp, dp)
     out, stats = session.generate(_prompt(3).reshape(1, -1), max_new=12)
     assert session.stats is stats and len(out[0]) == 12

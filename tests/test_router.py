@@ -26,7 +26,7 @@ from repro.serving import (
 @pytest.fixture(scope="module")
 def sharded_engine(dense_pair):
     T, D, tp, dp = dense_pair
-    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
     return SpecEngine(T, D, cfg, S_max_t=256, S_max_d=256), tp, dp
 
 
@@ -145,7 +145,7 @@ def test_sharded_byte_identical_to_solo_generate(sharded_engine):
     assert sorted(results) == list(range(6))
     assert {rt.replica_of(i) for i in range(6)} == {0, 1}
     for r in reqs:
-        solo, _ = eng.generate(tp, dp, r.prompt.reshape(1, -1), max_new=r.max_new)
+        solo, _ = eng.session(tp, dp).generate(r.prompt.reshape(1, -1), max_new=r.max_new)
         assert results[r.rid] == solo[0], (
             f"request {r.rid} on replica {rt.replica_of(r.rid)} diverged")
     for st in rt.stats:

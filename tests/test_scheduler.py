@@ -5,7 +5,8 @@ The contracts under test:
 
 * per-slot adaptive draft depth never changes WHICH tokens a request emits
   — any depth schedule is byte-identical to solo ``generate()`` on every
-  serving surface (direct session, continuous, sharded, async) — and adds
+  serving surface (direct session, continuous, sharded, and with the
+  lookahead adopted) — and adds
   ZERO jit traces (depth is a host loop count over the one jitted expand
   program);
 * the queue's deadline-aware pop: EDF within a priority class, FIFO
@@ -37,17 +38,18 @@ from repro.serving.stats import RequestRecord, ServerStats
 
 @pytest.fixture(scope="module")
 def sched_engine(dense_pair):
+    """An independent draft: the round's lookahead is mostly rolled back."""
     T, D, tp, dp = dense_pair
-    cfg = SpecConfig(bs=8, w=4, c=2, d=4, n_cap=64, mode="parallel", max_new=24)
+    cfg = SpecConfig(bs=8, w=4, c=2, d=4, n_cap=64, max_new=24)
     return SpecEngine(T, D, cfg, S_max_t=256, S_max_d=256), tp, dp
 
 
 @pytest.fixture(scope="module")
-def async_sched_engine(dense_pair):
+def commit_sched_engine(dense_pair):
+    """The target as its own draft: the round's lookahead is mostly adopted."""
     T, D, tp, dp = dense_pair
-    cfg = SpecConfig(bs=8, w=4, c=2, d=4, n_cap=64, mode="parallel", max_new=24,
-                     async_rounds=True)
-    return SpecEngine(T, D, cfg, S_max_t=256, S_max_d=256), tp, dp
+    cfg = SpecConfig(bs=8, w=4, c=2, d=4, n_cap=64, max_new=24)
+    return SpecEngine(T, T, cfg, S_max_t=256, S_max_d=256), tp, tp
 
 
 def _prompt(k, P=8):
@@ -201,7 +203,7 @@ def test_depth_schedule_byte_identity_direct_session(sched_engine):
     exact solo-generate stream (greedy verification pins it)."""
     eng, tp, dp = sched_engine
     prompt = _prompt(3).reshape(1, -1)
-    solo, _ = eng.generate(tp, dp, prompt, max_new=16)
+    solo, _ = eng.session(tp, dp).generate(prompt, max_new=16)
 
     ses = eng.session(tp, dp)
     ses.state = eng._prefill_state(tp, dp, prompt)
@@ -228,12 +230,13 @@ def test_depth_variation_adds_no_jit_traces(sched_engine):
     assert eng._expand._cache_size() == baseline
 
 
-@pytest.mark.parametrize("surface", ["continuous", "sharded", "async"])
+@pytest.mark.parametrize("surface", ["continuous", "sharded", "commit"])
 def test_adaptive_depth_byte_identity_serving(surface, sched_engine,
-                                              async_sched_engine):
+                                              commit_sched_engine):
     """Adaptive scheduling on: staggered deadlined+best-effort traffic over
-    recycled slots still emits solo-identical streams on every surface."""
-    eng, tp, dp = async_sched_engine if surface == "async" else sched_engine
+    recycled slots still emits solo-identical streams on every surface
+    (``commit``: continuous, with a draft whose lookaheads are adopted)."""
+    eng, tp, dp = commit_sched_engine if surface == "commit" else sched_engine
     sched = SchedulerConfig(ema_alpha=0.5)
     reqs = [Request(rid=i, prompt=_prompt(i + 1, P=8 + 4 * (i % 2)),
                     arrival_s=0.7 * i, max_new=16,
@@ -249,7 +252,7 @@ def test_adaptive_depth_byte_identity_serving(surface, sched_engine,
     results = rt.run()
     assert sorted(results) == [0, 1, 2, 3, 4]
     for r in reqs:
-        solo, _ = eng.generate(tp, dp, r.prompt.reshape(1, -1), max_new=16)
+        solo, _ = eng.session(tp, dp).generate(r.prompt.reshape(1, -1), max_new=16)
         assert results[r.rid] == solo[0], \
             f"request {r.rid} diverged from solo generate() on {surface}"
     # the controller actually adapted: the round-depth series exists and
@@ -366,13 +369,13 @@ def test_zero_round_ratios_are_nan_and_render_as_dash():
     assert st.summary()["mean_acceptance"] == pytest.approx(6 / 4)
 
 
-@pytest.mark.parametrize("mode", ["lockstep", "async"])
+@pytest.mark.parametrize("mode", ["rollback", "commit"])
 def test_failing_absorb_leaves_tracer_balanced_and_session_quiescent(
-        mode, sched_engine, async_sched_engine):
+        mode, sched_engine, commit_sched_engine):
     """An absorb that raises (poisoned stream callback) must end the round
     span (tracer balanced) and leave no RoundInFlight orphaned — the fleet
     loop aborts the round on the way out and the session stays usable."""
-    eng, tp, dp = async_sched_engine if mode == "async" else sched_engine
+    eng, tp, dp = commit_sched_engine if mode == "commit" else sched_engine
     tracer = Tracer(clock=lambda: 0.0)
 
     def bad_stream(rid, toks, done):

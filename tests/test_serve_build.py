@@ -43,6 +43,22 @@ def test_build_engine_refuses_a_split_that_does_not_fit():
         serve.build_engine(cfgT, cfgD, n_target=len(jax.devices()), n_draft=1)
 
 
+@pytest.mark.parametrize("kw", [dict(mode="serial"), dict(async_rounds=False)],
+                         ids=["mode-serial", "async-false"])
+def test_build_engine_accepts_only_the_pipelined_round(monkeypatch, kw):
+    """The benchmark harness still names the round schedule; any schedule
+    but the pipelined one is refused before a weight is drawn."""
+    cfgT, cfgD = serve.serving_configs("deepseek-coder-33b", "deepseek-coder-1.3b")
+
+    def drawn(*a, **k):
+        raise AssertionError("weights drawn before the schedule was checked")
+
+    monkeypatch.setattr(serve, "init_params", drawn)
+    monkeypatch.setattr(serve, "make_model", drawn)
+    with pytest.raises(ValueError, match="round schedule"):
+        serve.build_engine(cfgT, cfgD, **kw)
+
+
 def test_enable_compile_cache_env_or_checkout(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX to use it;
     without, the cache goes to the fixed directory inside the checkout."""

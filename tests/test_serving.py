@@ -19,7 +19,7 @@ from repro.serving import ContinuousBatchingRuntime, Request, RequestQueue, Virt
 @pytest.fixture(scope="module")
 def serving_engine(dense_pair):
     T, D, tp, dp = dense_pair
-    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, mode="parallel", max_new=24)
+    cfg = SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, max_new=24)
     return SpecEngine(T, D, cfg, S_max_t=256, S_max_d=256), tp, dp
 
 
@@ -44,7 +44,7 @@ def test_continuous_matches_solo_generate(serving_engine):
 
     assert sorted(results) == [0, 1, 2, 3, 4]
     for r in reqs:
-        solo, _ = eng.generate(tp, dp, r.prompt.reshape(1, -1), max_new=r.max_new)
+        solo, _ = eng.session(tp, dp).generate(r.prompt.reshape(1, -1), max_new=r.max_new)
         assert results[r.rid] == solo[0], f"request {r.rid} diverged from solo generate()"
 
     # continuous batching actually happened: some request was admitted while
@@ -83,7 +83,7 @@ def test_live_submit_after_trace_run(serving_engine):
     assert rt.submit(Request(rid=1, prompt=_prompt(6), max_new=8))  # arrival in the past
     results = rt.run()
     assert sorted(results) == [0, 1]
-    solo, _ = eng.generate(tp, dp, _prompt(6).reshape(1, -1), max_new=8)
+    solo, _ = eng.session(tp, dp).generate(_prompt(6).reshape(1, -1), max_new=8)
     assert results[1] == solo[0]
     assert rt.stats.summary()["n_finished"] == 2
 
@@ -107,7 +107,7 @@ def test_live_submit_mid_run_does_not_break_trace_feed(serving_engine):
     rt.submit(Request(rid=1, prompt=_prompt(5), arrival_s=2.5, max_new=4))
     results = rt.run()
     assert sorted(results) == [0, 1, 2]
-    solo, _ = eng.generate(tp, dp, _prompt(8).reshape(1, -1), max_new=4)
+    solo, _ = eng.session(tp, dp).generate(_prompt(8).reshape(1, -1), max_new=4)
     assert results[2] == solo[0]
 
 
@@ -117,11 +117,11 @@ def test_eos_inherited_from_engine(dense_pair, serving_engine):
     T, D, tp, dp = dense_pair
     base, _, _ = serving_engine
     prompt = _prompt(9)
-    probe, _ = base.generate(tp, dp, prompt.reshape(1, -1), max_new=20)
+    probe, _ = base.session(tp, dp).generate(prompt.reshape(1, -1), max_new=20)
     eos = probe[0][10]  # a token the greedy stream provably reaches
     eng = SpecEngine(T, D, SpecConfig(bs=8, w=4, c=2, d=2, n_cap=64, max_new=20,
                                       eos_id=eos), S_max_t=256, S_max_d=256)
-    solo, _ = eng.generate(tp, dp, prompt.reshape(1, -1), max_new=20)
+    solo, _ = eng.session(tp, dp).generate(prompt.reshape(1, -1), max_new=20)
     assert eos in solo[0]
     rt = ContinuousBatchingRuntime(eng, tp, dp, n_slots=1, clock=VirtualClock())
     rt.submit(Request(rid=0, prompt=prompt, max_new=20))
@@ -134,7 +134,7 @@ def test_immediate_eos_request_record_shape(serving_engine):
     fully formed (TTFT present, finish stamped, one-round lifetime)."""
     eng, tp, dp = serving_engine
     prompt = _prompt(13)
-    probe, _ = eng.generate(tp, dp, prompt.reshape(1, -1), max_new=4)
+    probe, _ = eng.session(tp, dp).generate(prompt.reshape(1, -1), max_new=4)
     eos = probe[0][0]  # the first greedy token
     rt = ContinuousBatchingRuntime(eng, tp, dp, n_slots=2, clock=VirtualClock())
     rt.submit(Request(rid=0, prompt=prompt, max_new=16, eos_id=eos))
@@ -177,7 +177,7 @@ def test_slot_recycling_no_leakage(serving_engine):
     rt.submit(Request(rid=1, prompt=b, arrival_s=0.0, max_new=16))
     results = rt.run()
 
-    solo_b, _ = eng.generate(tp, dp, b.reshape(1, -1), max_new=16)
+    solo_b, _ = eng.session(tp, dp).generate(b.reshape(1, -1), max_new=16)
     assert results[1] == solo_b[0], "retired slot state leaked into its successor"
 
     # after the final release, every cache row of the slot is physically zero
@@ -189,17 +189,17 @@ def test_slot_recycling_no_leakage(serving_engine):
 def test_release_slot_targets_one_row(serving_engine):
     """zero_slot/reset_slot touch exactly the released row."""
     eng, tp, dp = serving_engine
-    state = eng.init_state(2)
-    state = eng.admit_slot(tp, dp, state, 0, _prompt(3))
-    state = eng.admit_slot(tp, dp, state, 1, _prompt(4))
-    before = [np.asarray(x) for x in jax.tree.leaves(state.tcache["groups"])]
-    state = eng.release_slot(state, 0)
-    after = [np.asarray(x) for x in jax.tree.leaves(state.tcache["groups"])]
+    sess = eng.session(tp, dp, n_slots=2)
+    sess.admit_slot(0, _prompt(3))
+    sess.admit_slot(1, _prompt(4))
+    before = [np.asarray(x) for x in jax.tree.leaves(sess.state.tcache["groups"])]
+    sess.release_slot(0)
+    after = [np.asarray(x) for x in jax.tree.leaves(sess.state.tcache["groups"])]
     for b4, af in zip(before, after):
         assert not af[:, 0].any(), "released row not cleared"
         np.testing.assert_array_equal(af[:, 1], b4[:, 1])  # neighbor untouched
-    assert not np.asarray(state.tr.valid[0]).any()
-    assert np.asarray(state.tr.valid[1]).any()
+    assert not np.asarray(sess.state.tr.valid[0]).any()
+    assert np.asarray(sess.state.tr.valid[1]).any()
 
 
 def test_install_zero_slot_roundtrip():
@@ -360,7 +360,7 @@ def test_specstats_per_row_exact(dense_pair):
     eng = SpecEngine(T, D, SpecConfig(bs=8, w=4, c=2, d=2, max_new=12),
                      S_max_t=256, S_max_d=256)
     prompt = (np.arange(16, dtype=np.int32).reshape(2, 8) * 3 + 1) % 128
-    out, stats = eng.generate(tp, dp, prompt, max_new=12)
+    out, stats = eng.session(tp, dp).generate(prompt, max_new=12)
     assert stats.emitted_rows.shape == (2,)
     np.testing.assert_array_equal(stats.emitted_rows, stats.accepted_rows + stats.rounds)
     assert all(er >= len(o) for er, o in zip(stats.emitted_rows, out))

@@ -16,7 +16,7 @@ from repro.core.scheduler import (AllocationResult, ProfileResult, candidate_dep
 from repro.data import DataConfig, SyntheticLMDataset
 from repro.optim import (adamw_init, adamw_update, compress_int8, decompress_int8,
                          warmup_cosine)
-from repro.runtime import FaultConfig, StragglerPolicy, retry_step
+from repro.runtime import FaultConfig, retry_step
 
 
 # -------------------------------------------------------------- checkpoints
@@ -175,14 +175,6 @@ def test_retry_step_gives_up():
 
     with pytest.raises(RuntimeError):
         retry_step(always, FaultConfig(max_retries=2, backoff_s=0.001))
-
-
-def test_straggler_policy():
-    sp = StragglerPolicy(t_draft_profiled_s=0.01, deadline_ratio=2.0)
-    sp.observe(0.015)
-    assert not sp.should_bypass()
-    sp.observe(0.05)
-    assert sp.should_bypass()
 
 
 def test_candidate_depths_and_allocation():
